@@ -37,8 +37,10 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--no-shell", action="store_true",
                     help="run headless (no interactive shell)")
     ap.add_argument("--cpu", action="store_true",
-                    help="force the engine onto CPU (ops testing; several "
-                         "local nodes can't share one TPU chip)")
+                    help="run the engine on the CPU on purpose (ops "
+                         "testing; several local nodes can't share one "
+                         "TPU chip). Without it a node that finds no "
+                         "accelerator refuses to start")
     ap.add_argument("--jax-coordinator", default=None,
                     help="ip:port for jax.distributed bring-up (multi-host "
                          "mesh over DCN); all nodes must pass the same value")
@@ -58,6 +60,14 @@ def main(argv: list[str] | None = None) -> int:
         initialize_distributed(args.jax_coordinator,
                                num_processes=args.jax_num_processes,
                                process_id=args.jax_process_id)
+
+    if not args.cpu:
+        import jax
+        if jax.default_backend() == "cpu":
+            # a deployment that meant the chip must not serve from the
+            # host in silence: the CPU is only ever chosen, never found
+            ap.error("no accelerator found (JAX backend is 'cpu'); pass "
+                     "--cpu to run the engine on the CPU on purpose")
 
     from idunno_tpu.cli.shell import Shell
     from idunno_tpu.comm.net import NetTransport

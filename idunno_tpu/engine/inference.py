@@ -94,7 +94,6 @@ class InferenceEngine:
         self._models: dict[str, _LoadedModel] = {}
         self._store_datasets: dict[str, Any] = {}
         self._load_lock = threading.Lock()
-        self._pallas_ok: bool | None = None   # resolved on first load
         self.categories = imagenet_categories()
 
     # -- loading ----------------------------------------------------------
@@ -375,27 +374,7 @@ class InferenceEngine:
         vsharding = vsharding if vsharding is not None else rsharding
 
         folded = getattr(module, "fold_preprocess", False)
-        if not folded and self._pallas_ok is None:
-            use_pallas = self._use_pallas()
-            if use_pallas and self.config.preprocess == "auto":
-                # auto mode must never take the engine down: smoke-compile
-                # the kernel once per engine and fall back to the XLA path
-                # if Mosaic rejects it.
-                try:
-                    from idunno_tpu.ops.pallas_preprocess import (
-                        preprocess_batch_pallas)
-                    n_data = self.mesh.shape[DATA_AXIS]
-                    probe = jnp.zeros((n_data, self.config.resize_size,
-                                       self.config.resize_size, 3), jnp.uint8)
-                    jax.block_until_ready(preprocess_batch_pallas(
-                        probe, crop=self.config.image_size))
-                except Exception as e:  # pragma: no cover - TPU-compile only
-                    import logging
-                    logging.getLogger("idunno.engine").warning(
-                        "pallas preprocess unavailable (%s); using XLA path",
-                        e)
-                    use_pallas = False
-            self._pallas_ok = use_pallas
+        use_pallas = not folded and self._use_pallas()
 
         if folded:
             # the stem consumes RAW cropped 0..255 values (stem_fold.py);
@@ -405,8 +384,8 @@ class InferenceEngine:
 
             def preprocess(u8):
                 return center_crop(u8, self.config.image_size)
-        elif self._pallas_ok:
-            from idunno_tpu.parallel._compat import shard_map
+        elif use_pallas:
+            from jax import shard_map
             from idunno_tpu.ops.pallas_preprocess import preprocess_batch_pallas
 
             # pallas_call is a custom call XLA can't auto-partition; run it

@@ -3,17 +3,20 @@
 One shared object holds every native component — image staging
 (`stage.cc`, the data-loader hot path) and the log-scan engine
 (`grepscan.cc`, the distributed-grep hot path). Built with
-``g++ -O3 -march=native -fopenmp`` at first use (cached next to the
-sources, keyed by their joint hash); every entry point has a pure-Python
-fallback so the framework works without a toolchain — native is an
-accelerator, not a dependency (the environment provides g++ but no
-pybind11, hence ctypes).
+``g++ -O3 -march=native -fopenmp`` at first use and cached next to the
+sources, keyed by the sources, the flags AND the host CPU: the object is
+specialised to the machine that built it, so a copy of the tree carried
+to another CPU rebuilds instead of loading foreign code. Every entry
+point has a pure-Python twin so the framework works without a toolchain
+— native is an accelerator, not a dependency (the environment provides
+g++ but no pybind11, hence ctypes); `describe()` says which one runs.
 """
 from __future__ import annotations
 
 import ctypes
 import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -22,13 +25,32 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = [os.path.join(_DIR, "stage.cc"),
             os.path.join(_DIR, "grepscan.cc")]
+_FLAGS = ["-O3", "-march=native", "-fopenmp", "-shared", "-fPIC"]
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
+_why_not = ""           # why the numpy twins run, when they do
+
+
+def _host_cpu() -> bytes:
+    """What ``-march=native`` resolves against: the first processor's
+    model and feature flags."""
+    keep = []
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith((b"model name", b"flags", b"Features")):
+                    keep.append(line)
+                elif not line.strip() and keep:
+                    break
+    except OSError:
+        pass
+    return platform.machine().encode() + b"".join(keep)
 
 
 def _build() -> ctypes.CDLL | None:
-    h = hashlib.sha256()
+    global _why_not
+    h = hashlib.sha256(" ".join(_FLAGS).encode() + _host_cpu())
     for src in _SOURCES:
         with open(src, "rb") as f:
             h.update(f.read())
@@ -38,16 +60,17 @@ def _build() -> ctypes.CDLL | None:
         # pid-unique temp so concurrent builds from several local node
         # processes can't interleave writes; os.replace publishes atomically
         tmp = f"{so_path}.{os.getpid()}.tmp"
-        cmd = ["g++", "-O3", "-march=native", "-fopenmp", "-shared",
-               "-fPIC", *_SOURCES, "-o", tmp]
+        cmd = ["g++", *_FLAGS, *_SOURCES, "-o", tmp]
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
             os.replace(tmp, so_path)
-        except (subprocess.SubprocessError, OSError, FileNotFoundError):
+        except (subprocess.SubprocessError, OSError) as e:
+            _why_not = f"build failed: {type(e).__name__}: {e}"
             return None
     try:
         lib = ctypes.CDLL(so_path)
-    except OSError:
+    except OSError as e:
+        _why_not = f"load failed: {e}"
         return None
     lib.resize_bilinear_u8.argtypes = [
         ctypes.POINTER(ctypes.c_uint8), ctypes.c_int, ctypes.c_int,
@@ -76,6 +99,13 @@ def get_lib() -> ctypes.CDLL | None:
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def describe() -> str:
+    """One line for the node's start-up log: which implementation runs."""
+    if available():
+        return f"native library loaded ({os.path.basename(_lib._name)})"
+    return f"numpy twins ({_why_not})"
 
 
 def _as_u8_ptr(a: np.ndarray):

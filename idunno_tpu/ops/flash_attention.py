@@ -184,13 +184,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _out_struct(shape, dtype, like):
-    """ShapeDtypeStruct carrying the input's varying axes when running under
-    shard_map (newer jax tracks vma on avals)."""
-    try:
-        vma = jax.typeof(like).vma
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):     # pragma: no cover - older jax
-        return jax.ShapeDtypeStruct(shape, dtype)
+    """ShapeDtypeStruct carrying the input's varying axes, so the kernel
+    can run under shard_map with check_vma on."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _flash_core(qb, kb, vb, causal, block_q, block_k, seq_len, interpret):
@@ -205,8 +201,7 @@ def _flash_core(qb, kb, vb, causal, block_q, block_k, seq_len, interpret):
     # either a multiple of 8 (block_q=256 default) or equal to t_pad
     # (ragged short sequences, where block_q == t == t_pad). The natural
     # (1, block_q) block over [G, T_pad] violates the (8, 128)
-    # minimum-tile rule and fails to lower on real TPU (observed live:
-    # BENCH_LAST_GOOD_lm.json 2026-07-31 capture).
+    # minimum-tile rule and fails to lower on real TPU.
     out, lse = pl.pallas_call(
         kernel,
         out_shape=(_out_struct((g, t_pad, d), qb.dtype, qb),

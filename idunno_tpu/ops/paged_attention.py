@@ -8,7 +8,8 @@ PAPERS.md). Two interchangeable backends behind the same signature:
   block chain; the block table rides in as a *scalar-prefetch* operand
   (`pltpu.PrefetchScalarGridSpec`) so the K/V BlockSpec index_map can
   address physical block ``tables[b, j]`` directly — the DMA engine
-  does the "gather", one block at a time, overlapped with compute.
+  does the "gather", one whole page (every kv-head, contiguous in the
+  pool) at a time, overlapped with compute.
   Online softmax (running max/denominator) is structurally the same as
   `ops/flash_attention.py:_flash_kernel`, including the (rows, 128)
   broadcast-scratch trick for m/l and the `_out_struct` vma convention.
@@ -49,8 +50,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from idunno_tpu.ops.flash_attention import _NEG_INF, _out_struct
 
-# "auto" resolves here until the paged_suite capture blesses the kernel
-# on the real chip (RESULTS.md staleness ledger tracks this).
+# "auto" resolves here until an on-chip measurement blesses the kernel
+# (it compiles and agrees with this path on the chip since PR 22; its
+# speed against the gathered path is not measured).
 AUTO_KERNEL = "xla"
 
 
@@ -115,13 +117,21 @@ class PagedContext:
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
+# query rows one program holds across all its kv-heads: bounds the q/out
+# blocks and the acc/m/l scratch to a few MiB of VMEM at head dim 128
+_MAX_ROWS = 1024
+
+
 def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
                   *refs, scale: float, block_size: int,
                   quantized: bool):
-    """Grid (B, KVH, C), C innermost sequential: one program per
-    (row, kv-head, chain position). The K/V BlockSpec index_map already
-    resolved ``tables[b, j]`` — this body only decides liveness and
-    runs one online-softmax step over the block.
+    """Grid (B, R, C), C innermost sequential: one program per (batch
+    row, query-row tile, chain position). The K/V BlockSpec index_map
+    already resolved ``tables[b, j]`` and the block carries the page's
+    WHOLE kv-head extent (Mosaic only takes a block whose last two dims
+    are the array's own or (8, 128)-aligned, so a single head cannot be
+    cut out of ``[bs, KVH, D]`` by the BlockSpec) — this body decides
+    liveness and runs one online-softmax step per kv-head over the block.
 
     ``quantized=True`` threads two extra per-token scale tiles
     (``ks_ref``/``vs_ref``, one f32 scale per (token, kv-head)) into
@@ -131,13 +141,14 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
 
     No causal/position masking: the paged region wholly precedes the
     queries and ``lengths`` are block-aligned, so a live block is live
-    in full. m/l live as (rows, 128) broadcast scratch (min-tile rule,
-    same trick as `_flash_kernel`)."""
+    in full. m/l live as (rows, 128) broadcast scratch per head
+    (min-tile rule, same trick as `_flash_kernel`)."""
     if quantized:
         ks_ref, vs_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     else:
         ks_ref = vs_ref = None
         o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    kvh = q_ref.shape[1]
     b = pl.program_id(0)
     j = pl.program_id(2)
     nc = pl.num_programs(2)
@@ -150,35 +161,36 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j * block_size < lengths_ref[b])
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)          # [rows, d]
-        k = k_ref[0, :, 0].astype(jnp.float32)       # [bs, d]
-        v = v_ref[0, :, 0].astype(jnp.float32)       # [bs, d]
-        if quantized:
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [rows, bs]
-        m_prev = m_ref[...].max(axis=-1, keepdims=True)   # [rows, 1]
-        l_prev = l_ref[...].max(axis=-1, keepdims=True)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        for h in range(kvh):
+            q = q_ref[0, h].astype(jnp.float32)          # [rows, d]
+            k = k_ref[0, :, h, :].astype(jnp.float32)    # [bs, d]
+            v = v_ref[0, :, h, :].astype(jnp.float32)    # [bs, d]
+            if quantized:
+                k = k * ks_ref[0, :, h:h + 1].astype(jnp.float32)
+                v = v * vs_ref[0, :, h:h + 1].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [rows, bs]
+            m_prev = m_ref[h].max(axis=-1, keepdims=True)     # [rows, 1]
+            l_prev = l_ref[h].max(axis=-1, keepdims=True)
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(j == nc - 1)
     def _finalize():
         m = m_ref[...].max(axis=-1, keepdims=True)
         l = l_ref[...].max(axis=-1, keepdims=True)
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m + jnp.log(l_safe)).astype(lse_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        lse_ref[0] = (m + jnp.log(l_safe)).astype(lse_ref.dtype)
 
 
 def _paged_pallas(q5, k_pages, v_pages, tables, lengths, *,
@@ -187,54 +199,56 @@ def _paged_pallas(q5, k_pages, v_pages, tables, lengths, *,
     """q5 [B,T,KVH,G,D] against pages [N,bs,KVH,D] via the block table.
 
     Rows = T*G query vectors per (batch, kv-head), padded to a multiple
-    of 8 for the f32 min tile. The table is flattened and handed to the
-    grid as a scalar-prefetch operand so the K/V index_map can read it.
-    Quantized pools add two ``[N, bs, KVH]`` scale-page operands that
-    ride the SAME index_map as their pages (one (bs, 1) scale column
-    per program, the last-dim-1 block shape the lse out_spec already
-    uses), so the dequant multiply happens in VMEM per block.
+    of 8 for the f32 min tile and cut into row tiles so one program
+    holds at most `_MAX_ROWS` query rows across its kv-heads (decode is
+    one tile; a long prefill suffix re-walks the chain once per tile
+    instead of outgrowing VMEM). The table is flattened and handed to
+    the grid as a scalar-prefetch operand so the K/V index_map can read
+    it. Each program DMAs one whole page (``[bs, KVH, D]``, contiguous in
+    the pool) and walks its kv-heads in the body. Quantized pools add
+    two ``[N, bs, KVH]`` scale-page operands that ride the SAME
+    index_map as their pages, so the dequant multiply happens in VMEM
+    per block.
     """
     b, t, kvh, g, d = q5.shape
     n, bs, _, _ = k_pages.shape
     c = tables.shape[1]
     r = t * g
-    rp = max(8, ((r + 7) // 8) * 8)
+    tr = min(((r + 7) // 8) * 8, max(8, _MAX_ROWS // kvh // 8 * 8))
+    rp = ((r + tr - 1) // tr) * tr
     qz = jnp.transpose(q5, (0, 2, 1, 3, 4)).reshape(b, kvh, r, d)
     if rp != r:
         qz = jnp.pad(qz, ((0, 0), (0, 0), (0, rp - r), (0, 0)))
     quantized = k_scale_pages is not None
 
-    page_spec = pl.BlockSpec((1, bs, 1, d),
-                             lambda bi, hi, ji, tbl, lens:
-                             (tbl[bi * c + ji], 0, hi, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, rp, d),
-                     lambda bi, hi, ji, tbl, lens: (bi, hi, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
+    def row_map(bi, ri, ji, tbl, lens):
+        return (bi, 0, ri, 0)
+
+    page_spec = pl.BlockSpec((1, bs, kvh, d),
+                             lambda bi, ri, ji, tbl, lens:
+                             (tbl[bi * c + ji], 0, 0, 0))
+    in_specs = [pl.BlockSpec((1, kvh, tr, d), row_map), page_spec,
+                page_spec]
     operands = [qz, k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, bs, 1),
-                                  lambda bi, hi, ji, tbl, lens:
-                                  (tbl[bi * c + ji], 0, hi))
+        scale_spec = pl.BlockSpec((1, bs, kvh),
+                                  lambda bi, ri, ji, tbl, lens:
+                                  (tbl[bi * c + ji], 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale_pages, v_scale_pages]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, c),
+        grid=(b, rp // tr, c),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, rp, d),
-                         lambda bi, hi, ji, tbl, lens: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, rp, 1),
-                         lambda bi, hi, ji, tbl, lens: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, kvh, tr, d), row_map),
+            pl.BlockSpec((1, kvh, tr, 1), row_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((rp, d), jnp.float32),
-            pltpu.VMEM((rp, 128), jnp.float32),
-            pltpu.VMEM((rp, 128), jnp.float32),
+            pltpu.VMEM((kvh, tr, d), jnp.float32),
+            pltpu.VMEM((kvh, tr, 128), jnp.float32),
+            pltpu.VMEM((kvh, tr, 128), jnp.float32),
         ],
     )
     out, lse = pl.pallas_call(

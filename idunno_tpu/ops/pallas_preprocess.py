@@ -49,12 +49,9 @@ def preprocess_batch_pallas(images_u8: jnp.ndarray, *, crop: int = 224,
     inv_std = 1.0 / jnp.asarray([IMAGENET_STD], dtype=jnp.float32)  # [1, 3]
 
     # carry the input's varying mesh axes on the out aval so the kernel can
-    # run inside shard_map with check_vma on (newer jax tracks vma)
-    try:
-        out_shape = jax.ShapeDtypeStruct((rows, w * ch), jnp.bfloat16,
-                                         vma=jax.typeof(flat).vma)
-    except (AttributeError, TypeError):      # pragma: no cover - older jax
-        out_shape = jax.ShapeDtypeStruct((rows, w * ch), jnp.bfloat16)
+    # run inside shard_map with check_vma on
+    out_shape = jax.ShapeDtypeStruct((rows, w * ch), jnp.bfloat16,
+                                     vma=jax.typeof(flat).vma)
 
     block_rows = min(_ROWS_PER_BLOCK, rows)
     grid = (pl.cdiv(rows, block_rows),)

@@ -21,9 +21,9 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from idunno_tpu.parallel._compat import shard_map
 
 EXPERT_AXIS = "expert"
 

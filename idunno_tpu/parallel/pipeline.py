@@ -21,9 +21,9 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from idunno_tpu.parallel._compat import pvary as _pvary, shard_map
 
 STAGE_AXIS = "stage"
 
@@ -73,9 +73,9 @@ def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         params = jax.tree.map(lambda a: a[0], params_sh)
         s = jax.lax.axis_index(axis)
         perm = [(j, (j + 1) % p) for j in range(p)]
-        state0 = _pvary(jnp.zeros_like(x[0]), axis)
-        out0 = _pvary(jnp.zeros_like(x), axis)
-        xv = _pvary(x, axis)
+        state0, out0, xv = (
+            jax.lax.pcast(a, (axis,), to="varying")
+            for a in (jnp.zeros_like(x[0]), jnp.zeros_like(x), x))
 
         def slot(t, carry):
             state, outputs = carry

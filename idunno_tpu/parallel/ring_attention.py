@@ -19,18 +19,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from idunno_tpu.parallel.mesh import DATA_AXIS
-from idunno_tpu.parallel._compat import pvary, shard_map
 
 
 def _ring_attention_shard(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           *, axis_name: str, p: int, causal: bool,
                           scale: float) -> jnp.ndarray:
     """Per-shard body. q/k/v: [B, T_local, H, D]. ``p`` is the concrete
-    ring size (= mesh.shape[axis_name]; jax.lax.axis_size is not available
-    on every supported jax)."""
+    ring size (= mesh.shape[axis_name])."""
     my = jax.lax.axis_index(axis_name)
     b, t_q, h, d = q.shape
     t_k = k.shape[1]
@@ -66,7 +65,8 @@ def _ring_attention_shard(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     l0 = jnp.zeros((b, h, t_q), jnp.float32)
     # mark the replicated initial carry as device-varying so the loop
     # carry type matches its output (shard_map vma typing)
-    o0, m0, l0 = (pvary(x, axis_name) for x in (o0, m0, l0))
+    o0, m0, l0 = (jax.lax.pcast(x, (axis_name,), to="varying")
+                  for x in (o0, m0, l0))
     o, m, l, _, _ = jax.lax.fori_loop(
         0, p, step, (o0, m0, l0, k.astype(jnp.float32),
                      v.astype(jnp.float32)))

@@ -26,10 +26,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from idunno_tpu.parallel.mesh import DATA_AXIS
-from idunno_tpu.parallel._compat import shard_map
 from idunno_tpu.parallel.ring_attention import full_attention
 
 

@@ -7,6 +7,8 @@ over the typed transport, so deployment tooling, integration tests and a
 remote CLI can run the shell's commands against any node:
 
   status                     — membership view, acting master, loaded models
+  device                     — platform/kind/count, JAX version, per-device
+                               memory stats, compile-cache hit counters
   put/get/ls/store/delete    — the SDFS verbs (C4) executed by this node
   inference                  — submit a query range (paced chunking like the
                                shell's `inference` verb, C11)
@@ -155,8 +157,23 @@ class ControlService:
                     "fence": list(node.membership.epoch.view()),
                     "counters": node.metrics.counters(),
                     "members": members,
+                    "warmup": dict(getattr(node, "warmup_report", {})),
                     "models": node.engine.loaded_models()
                     if hasattr(node.engine, "loaded_models") else []}
+        if verb == "device":
+            # what this node's engine actually runs on, as JAX reports it
+            # — the chip belongs to this process, so nobody else can ask
+            import jax
+
+            from idunno_tpu import native
+            from idunno_tpu.utils.compile_cache import cache_counters
+            devs = jax.devices()
+            return {"platform": devs[0].platform,
+                    "kind": devs[0].device_kind, "count": len(devs),
+                    "jax": jax.__version__,
+                    "memory_stats": [d.memory_stats() for d in devs],
+                    "native": native.describe(),
+                    "compile_cache": cache_counters()}
         if verb == "put":
             version = node.store.put(p["local"], p["name"])
             return {"version": version}

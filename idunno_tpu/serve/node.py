@@ -77,6 +77,10 @@ class Node:
         self.grep = LogGrepService(host, config, transport, self.membership,
                                    log_dir or data_dir)
         self.control = ControlService(self)
+        # model → seconds its warm-up compile took, or "error: ..." —
+        # the status verb carries it, so a configured model the device's
+        # compiler refuses is visible to whoever started the node
+        self.warmup_report: dict[str, float | str] = {}
         self._stop = threading.Event()
         self._threads: list[threading.Thread] = []
 
@@ -99,7 +103,9 @@ class Node:
                                  name=f"{self.host}-{name}")
             t.start()
             self._threads.append(t)
-        self.log.info("node %s started", self.host)
+        from idunno_tpu import native
+        self.log.info("node %s started; staging/grep: %s", self.host,
+                      native.describe())
 
     def _warmup(self, models) -> None:
         """Compile the configured models before the first job arrives (the
@@ -110,9 +116,11 @@ class Node:
                 return
             try:
                 secs = self.engine.warmup(name)
+                self.warmup_report[name] = secs
                 self.log.info("warmed %s in %.1fs", name, secs)
-            except Exception as e:  # noqa: BLE001 - warmup must not kill node
-                self.log.warning("warmup %s failed: %s", name, e)
+            except Exception as e:  # noqa: BLE001 - reported, not swallowed
+                self.warmup_report[name] = f"error: {type(e).__name__}: {e}"
+                self.log.exception("warmup %s failed", name)
 
     def stop(self) -> None:
         self._stop.set()

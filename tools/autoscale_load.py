@@ -17,7 +17,7 @@ deadline slack.
 Two consumers:
 
 - `utils/lm_bench.py:run_lm_autoscale_bench` (``BENCH_SUITE=
-  lm_autoscale``, capture-loop step ``autoscale_suite``) imports
+  lm_autoscale``) imports
   `run_phases` / `probe_decisions` / `ReplicaRouter` for the live
   backend record.
 - Standalone CLI for a quick CPU demo:

@@ -14,8 +14,7 @@ Two consumers:
 
 - `utils/lm_bench.py:run_lm_gateway_bench` (``BENCH_SUITE=lm_gateway``)
   imports `poisson_schedule` / `run_open_loop` to measure goodput vs
-  offered load and shed rate on the live backend (capture-loop step
-  ``gateway_suite``).
+  offered load and shed rate on the live backend.
 - Standalone CLI for a quick CPU-mesh overload demo:
 
       python tools/gateway_load.py --load 2.0 --requests 48

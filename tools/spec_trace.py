@@ -22,8 +22,8 @@ live (the runtime cond takes the full sampling branch), so one window
 apportions both branches and the greedy-vs-sampled delta IS the cost of
 the machinery the fast path skips.
 
-Writes SPEC_TRACE.json (+ raw .trace/lm_spec{,_plain,_sampled}); wired
-into tools/capture_loop.py. Smoke-testable off-TPU: --cpu runs tiny
+Writes SPEC_TRACE.json (+ raw .trace/lm_spec{,_plain,_sampled}).
+Smoke-testable off-TPU: --cpu runs tiny
 shapes with the same pool wiring but skips the profiler and artifact.
 """
 from __future__ import annotations
