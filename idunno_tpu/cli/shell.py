@@ -91,7 +91,11 @@ HELP = """\
        autoscale.<key>=v for inline policy)
   trace <trace-id> | trace <pool> <req-id> | trace <model> <qnum>
        cluster-wide span waterfall of one request (collected from every
-       alive node; one line per span: offset, duration, node, name, attrs)
+       alive node; one line per span: offset, duration, node, name, attrs).
+       An LM request reads lm.admit, lm.queue_wait, lm.slot_wait,
+       lm.prefill (kv.lookup, kv.gather, kv.insert), lm.decode, lm.finish;
+       the trace id t:<node>:loop:<pool> (lm-stats: loop_trace) is the
+       pool loop's own timeline: loop.iter > lm.step > lm.step.sync ...
   metrics [host]          Prometheus text exposition of a node's counters,
        rates, LM/gateway gauges and span-store depth"""
 

@@ -185,6 +185,9 @@ class KVBlockPool:
         self._refs: dict[int, int] = {}       # allocated block → refcount
         # eval_shape templates for gather output trees, keyed by length
         self._tree_templates: dict[int, Any] = {}
+        # blocks moved, counted where they move (`prefix_cache_stats`)
+        self.blocks_written = 0
+        self.blocks_gathered = 0
 
     def _alloc_store(self, shape: tuple, dtype, name: str) -> jnp.ndarray:
         """Zeroed store, head-sharded over the model axis under TP. The
@@ -271,6 +274,7 @@ class KVBlockPool:
         for key, store in self._stores.items():
             self._stores[key] = _write_block(store, src[key], b, off,
                                              stacked=self._stacked)
+        self.blocks_written += 1
 
     def read_block(self, bid: int) -> dict[str, Any]:
         """One block's raw per-leaf content as HOST numpy arrays, keyed
@@ -313,6 +317,7 @@ class KVBlockPool:
                 self._stores[key] = store.at[:, bid].set(staged[key])
             else:
                 self._stores[key] = store.at[bid].set(staged[key])
+        self.blocks_written += 1
 
     def kv_pages(self) -> dict[str, jnp.ndarray]:
         """Raw page stores by leaf name ({"cached_k", "cached_v"} plus
@@ -355,6 +360,7 @@ class KVBlockPool:
             template = jax.eval_shape(
                 lambda: init_cache(self.model, 1, total))
             self._tree_templates[total] = template
+        self.blocks_gathered += n
         bids = jnp.asarray(blocks, jnp.int32)
         parts = {key: _gather_blocks(store, bids, n,
                                      stacked=self._stacked)

@@ -34,6 +34,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from functools import partial
 from typing import Any
@@ -66,6 +67,28 @@ from idunno_tpu.ops.sampling import (filter_on as _filter_on,
 # throughput pass slots=64 explicitly (tests pin their own sizes).
 DEFAULT_SLOTS = 16
 
+# the id lane (`utils/spans.py`) of a pool's own timeline: how many loop
+# iterations run between two requests depends on thread timing, and must
+# neither shift the ids of a request's trace nor crowd its spans out
+LOOP_LANE = "loop"
+NO_SPAN = nullcontext()
+
+
+@contextmanager
+def loop_span(spans, name: str, trace: str, parent: str | None, **attrs):
+    """One span of a pool's own timeline (trace ``t:<node>:loop:<pool>``:
+    `loop.iter` and its children, `lm.step` and its children), also
+    entered as a profiler annotation of the same name, so a device profile
+    taken meanwhile shows the host's phases above the device's operations
+    on the profiler's own clock. Called only with a store wired."""
+    with jax.profiler.TraceAnnotation(name):
+        sp = spans.start(name, trace=trace, parent=parent, attrs=attrs,
+                         lane=LOOP_LANE)
+        try:
+            yield sp
+        finally:
+            spans.finish(sp)
+
 
 @dataclass
 class Request:
@@ -88,7 +111,19 @@ class Request:
     # up to decode_steps-1 overshoot tokens are computed then discarded)
     stop: list[list[int]] | None = None
     seed: int | None = None
-    t_admit: float = 0.0       # monotonic stamp set at slot admission
+    # stamps on the pool's clock (`DecodeServer.clock`), copied onto the
+    # `Completion`: entry into the serving loop's (or, driven bare, the
+    # server's) submit, entry into the server's queue, slot admission, the
+    # end of the first step that showed tokens of this request to the host
+    # with how many it showed, the end of the last step that showed more
+    t_submit: float = 0.0
+    t_queued: float = 0.0
+    t_admit: float = 0.0
+    t_first: float | None = None
+    n_first: int = 0
+    t_last: float | None = None
+    dispatch0: int = 0         # the pool's dispatch count at admission
+    t_decode0: float | None = None   # traced only: its first dispatch
     # admitted before the server's FIRST decode dispatch: this request's
     # service time funds the one-time XLA compiles (prefill bucket +
     # decode program), not steady-state work — flagged so downstream
@@ -96,7 +131,7 @@ class Request:
     cold: bool = False
     # (trace_id, parent_span_id) from the submitting hop (utils/spans.py);
     # None = untraced. _admit re-points the parent at its prefill span so
-    # decode-step spans chain under the prefill in the waterfall.
+    # the `lm.decode` span chains under the prefill in the waterfall.
     trace: tuple | None = None
 
 
@@ -129,6 +164,31 @@ class Completion:
     # capacity planning, not per-request cost (VERDICT item 4). A
     # `warmup()`-ed pool never produces one.
     cold_start: bool = False
+    # the pool's own stamps (one clock, `DecodeServer.clock`): submit, slot
+    # admission, the end of the step that first showed the host tokens of
+    # this request (``n_first`` of them: the prefill's and one dispatch's),
+    # the end of the step that showed its last. A step ends once the rows'
+    # cursors are read back, the moment a streaming client could see the
+    # tokens. None where the request never got that far.
+    t_submit: float | None = None
+    t_admit: float | None = None
+    t_first: float | None = None
+    n_first: int = 0
+    t_last: float | None = None
+
+    def ttft_s(self) -> float | None:
+        """Submit to the first visible token(s)."""
+        if self.t_first is None or self.t_submit is None:
+            return None
+        return self.t_first - self.t_submit
+
+    def tpot_s(self) -> float | None:
+        """Seconds a token after the first stamp; None where no token
+        came after it."""
+        later = len(self.tokens) - self.prompt_len - self.n_first
+        if self.t_first is None or self.t_last is None or later <= 0:
+            return None
+        return (self.t_last - self.t_first) / later
 
 
 def _set_cursors(cache: Any, cursors: jnp.ndarray) -> Any:
@@ -575,6 +635,15 @@ class DecodeServer:
         # optional per-node span recorder (utils/spans.py), set by the
         # serving layer after construction; None = tracing off, zero cost
         self.spans = None
+        # the pool's one clock: every stamp on `Request`/`Completion`.
+        # Whoever wires `spans` points it at the store's clock, so stamps
+        # and spans share a timeline (and fake-clock tests stay exact)
+        self.clock = time.monotonic
+        # (trace_id, parent_span_id) the step's own spans go under: the
+        # serving loop points it at its `loop.iter` span each iteration;
+        # driven bare, the steps root a trace of their own
+        self.step_ctx: tuple | None = None
+        self._step_span = None            # the running step's `lm.step`
         # optional cluster prefix cache (serve/cluster_prefix.py), set by
         # the serving layer after construction like `spans` — the engine
         # layer stays free of store/transport dependencies. None = local-
@@ -878,6 +947,11 @@ class DecodeServer:
         self._queue: deque[Request] = deque()
         self._live: dict[int, Request] = {}       # slot → request
         self._done: list[Completion] = []
+        # (completion, request) of the rows the running step retired,
+        # until its end stamps them; traced admissions awaiting the
+        # stamp of their first dispatch
+        self._retired: list[tuple[Completion, Request]] = []
+        self._new_traced: list[Request] = []
         self._next_id = 0
         self._cancelled: set[int] = set()     # ids cancelled while live
         self._stats = {"dispatches": 0, "admitted": 0, "completed": 0,
@@ -1293,7 +1367,8 @@ class DecodeServer:
                frequency_penalty: float = 0.0,
                stop: list[list[int]] | None = None,
                seed: int | None = None,
-               trace: tuple | None = None) -> int:
+               trace: tuple | None = None,
+               t_submit: float | None = None) -> int:
         """Queue a prompt; returns the request id. ``temperature`` 0 =
         greedy; > 0 samples with a per-request stream seeded by ``seed``
         (default: the request id); ``top_p`` < 1 restricts sampling to
@@ -1301,11 +1376,14 @@ class DecodeServer:
         (k-filter first, then nucleus), exactly as in `engine.generate`.
         ``trace`` is an optional (trace_id, parent_span_id) context —
         prefill/decode spans are recorded under it when `self.spans` is
-        wired (utils/spans.py)."""
+        wired (utils/spans.py). ``t_submit`` is the serving loop's own
+        stamp of the request's arrival (on `self.clock`); left out, the
+        request arrives now."""
         self.validate(tokens, max_new, temperature, top_p, top_k,
                       presence_penalty, frequency_penalty, stop)
         rid = self._next_id
         self._next_id += 1
+        now = self.clock()
         self._queue.append(Request(id=rid, tokens=list(tokens),
                                    max_new=max_new,
                                    temperature=temperature, top_p=top_p,
@@ -1315,6 +1393,9 @@ class DecodeServer:
                                    stop=([list(q) for q in stop]
                                          if stop else None),
                                    seed=seed,
+                                   t_submit=(now if t_submit is None
+                                             else t_submit),
+                                   t_queued=now,
                                    trace=(tuple(trace) if trace else None)))
         return rid
 
@@ -1343,7 +1424,8 @@ class DecodeServer:
             self._done.append(Completion(
                 id=rid, tokens=full, prompt_len=len(full),
                 cancelled=True,
-                logprobs=[] if self.track_logprobs else None))
+                logprobs=[] if self.track_logprobs else None,
+                t_submit=p["req"].t_submit, t_admit=p["req"].t_admit))
             self._stats["cancelled"] += 1
             return "queued"
         for i, req in enumerate(self._queue):
@@ -1357,7 +1439,8 @@ class DecodeServer:
                 self._done.append(Completion(
                     id=rid, tokens=full, prompt_len=len(full),
                     cancelled=True,
-                    logprobs=[] if self.track_logprobs else None))
+                    logprobs=[] if self.track_logprobs else None,
+                    t_submit=req.t_submit))
                 self._stats["cancelled"] += 1
                 return "queued"
         for slot, req in self._live.items():
@@ -1461,6 +1544,9 @@ class DecodeServer:
             "evictions": self._radix.evictions,
             "insert_skips": self._radix.insert_skips,
             "inserted_blocks": self._radix.inserted_blocks,
+            "evict_nodes_walked": self._radix.evict_nodes_walked,
+            "blocks_written": self._block_pool.blocks_written,
+            "blocks_gathered": self._block_pool.blocks_gathered,
             "nodes": self._radix.num_nodes(),
             "prefix_remote_hits": 0,
             "prefix_published_chains": 0,
@@ -1806,12 +1892,16 @@ class DecodeServer:
             if self.track_logprobs:
                 lp_row = np.asarray(self._logprobs[slot])[:total]
                 lps = [float(x) for x in lp_row[len(req.tokens):]]
-            self._done.append(Completion(
+            done = Completion(
                 id=req.id, tokens=[int(t) for t in row],
                 prompt_len=len(req.tokens),
-                service_s=time.monotonic() - req.t_admit,
+                service_s=self.clock() - req.t_admit,
                 cancelled=was_cancelled, logprobs=lps,
-                cold_start=req.cold))
+                cold_start=req.cold, t_submit=req.t_submit,
+                t_admit=req.t_admit, t_first=req.t_first,
+                n_first=req.n_first, t_last=req.t_last)
+            self._done.append(done)
+            self._retired.append((done, req))   # `_stamp` ends the step
             if not was_cancelled:
                 self._stats["completed"] += 1
             self._stats["tokens_generated"] += total - len(req.tokens)
@@ -1819,6 +1909,22 @@ class DecodeServer:
                 chain = self._held.pop(req.id, None)
                 if chain:
                     self._radix.release(chain)
+
+    def _child_span(self, parent, name: str, t_start, **attrs) -> None:
+        """A child of a traced admission's `lm.prefill`, from ``t_start``
+        to now; nothing for an untraced one (``parent`` None)."""
+        if parent is not None:
+            self.spans.record(name, trace=parent.trace_id,
+                              parent=parent.span_id, t_start=t_start,
+                              attrs=attrs)
+
+    def _gather_hit(self, hit_chain: list, span) -> Any:
+        """The radix hit's blocks as one contiguous prefix cache (the
+        gathered path's copy; paged pools read through the table)."""
+        t0 = span and self.spans.clock()
+        gathered = self._block_pool.gather([nd.block for nd in hit_chain])
+        self._child_span(span, "kv.gather", t0, blocks=len(hit_chain))
+        return gathered
 
     def _admit(self) -> None:
         if self._pending is not None:
@@ -1830,14 +1936,23 @@ class DecodeServer:
         while free and self._queue:
             slot = free.pop(0)
             req = self._queue.popleft()
-            req.t_admit = time.monotonic()
+            req.t_admit = self.clock()
             req.cold = not self._dispatched_ever
-            # prefill span opens here (store clock, not monotonic: fake-
-            # clock tests need assertable timelines); closed after insert
-            t_prefill0 = (self.spans.clock()
-                          if self.spans is not None and req.trace else None)
             per_req = list(req.tokens)      # pre-prefix request tokens
             suffix_true = len(per_req)
+            # `lm.prefill` is the admission's HOST time, from here (the
+            # radix lookup) to the slot splice in `_finish_admission`:
+            # what the host spends enqueueing the work. The device's time
+            # for the same work is the prefill programs' in a profile.
+            sp = None
+            if self.spans is not None and req.trace:
+                self.spans.record(
+                    "lm.slot_wait", trace=req.trace[0], parent=req.trace[1],
+                    t_start=req.t_queued, t_end=req.t_admit,
+                    attrs={"id": req.id})
+                sp = self.spans.start(
+                    "lm.prefill", trace=req.trace[0], parent=req.trace[1],
+                    attrs={"id": req.id, "prompt_len": suffix_true})
             pl = len(self.prefix) if self.prefix else 0
             # radix prefix cache: longest block-aligned cached chain for
             # this prompt. The hit is capped one block short of the full
@@ -1848,6 +1963,7 @@ class DecodeServer:
             hit, hit_chain = 0, []
             if self._radix is not None:
                 self._pc_lookups += 1
+                t_lookup = sp and self.spans.clock()
                 hit_chain = self._radix.lookup(per_req)
                 bs = self.kv_block_size
                 want = (suffix_true - 1) // bs   # usable depth in blocks
@@ -1861,6 +1977,8 @@ class DecodeServer:
                         and len(hit_chain) < want):
                     if self._cluster_fetch(per_req, len(hit_chain), want):
                         hit_chain = self._radix.lookup(per_req)
+                self._child_span(sp, "kv.lookup", t_lookup,
+                                 blocks_hit=len(hit_chain))
                 hit = min(len(hit_chain) * bs,
                           ((suffix_true - 1) // bs) * bs)
             while True:
@@ -1883,6 +2001,10 @@ class DecodeServer:
                 self._pc_tokens_saved += hit
             elif hit_chain:
                 hit_chain = []
+            if sp is not None:
+                sp.attrs.update(prefix_hit=hit, bucket=suffix_bucket)
+                if self._step_span is not None:   # the step that ran it
+                    sp.attrs["step"] = self._step_span.span_id
             suffix = np.zeros((1, suffix_bucket), np.int32)
             suffix[0, :suffix_true - hit] = per_req[hit:]
             self._stats["prefill_tokens"] += suffix_bucket
@@ -1905,23 +2027,15 @@ class DecodeServer:
                 # cache and last-token logits as the one-shot apply.
                 total = pl + hit + suffix_bucket
                 if hit and tab_np is None:
-                    gathered = self._block_pool.gather(
-                        [nd.block for nd in hit_chain])
+                    gathered = self._gather_hit(hit_chain, sp)
                     pre = (concat_kv_prefix(
                         self._prefix_cache, gathered,
                         token_axis=2 if self._scan else 1)
                         if self.prefix else gathered)
                 else:   # paged hit (hit region stays zero) or no hit
                     pre = self._prefix_cache if self.prefix else None
-                sp = None
-                if t_prefill0 is not None:
-                    sp = self.spans.start(
-                        "lm.prefill", trace=req.trace[0],
-                        parent=req.trace[1],
-                        attrs={"id": req.id, "prompt_len": suffix_true,
-                               "prefix_hit": hit,
-                               "bucket": suffix_bucket, "chunked": True})
-                    sp.t_start = t_prefill0
+                if sp is not None:
+                    sp.attrs["chunked"] = True
                 self._pending = {
                     "req": req, "slot": slot,
                     "cache": _chunk_init(self._prefill_model, pre, total),
@@ -1942,8 +2056,7 @@ class DecodeServer:
                     start=pl, kernel=self.paged_kernel,
                     interpret=self._paged_interpret)
             elif hit:
-                gathered = self._block_pool.gather(
-                    [nd.block for nd in hit_chain])
+                gathered = self._gather_hit(hit_chain, sp)
                 # stacked caches carry the token axis at 2 (depth, batch,
                 # token, ...) instead of the per-block layout's 1
                 pre = (concat_kv_prefix(self._prefix_cache, gathered,
@@ -1966,7 +2079,7 @@ class DecodeServer:
                 req, slot, row_cache, last_logits, hit=hit,
                 hit_chain=hit_chain, per_req=per_req, pl=pl,
                 suffix_true=suffix_true, suffix_bucket=suffix_bucket,
-                suffix=suffix, t_prefill0=t_prefill0)
+                suffix=suffix, span=sp)
             # max_new == 1: the prefill's token was the only one; the next
             # _retire_finished pass (step() runs one post-admission)
             # retires the row before any decode dispatch
@@ -2013,13 +2126,13 @@ class DecodeServer:
                 hit_chain=p["hit_chain"], per_req=p["per_req"],
                 pl=p["pl"], suffix_true=p["suffix_true"],
                 suffix_bucket=p["bucket"], suffix=p["suffix"],
-                open_span=p["span"], chunks=p["chunks"])
+                span=p["span"], chunks=p["chunks"])
 
     def _finish_admission(self, req, slot: int, row_cache, last_logits, *,
                           hit: int, hit_chain: list, per_req: list,
                           pl: int, suffix_true: int, suffix_bucket: int,
-                          suffix: np.ndarray, t_prefill0=None,
-                          open_span=None, chunks: int = 0) -> None:
+                          suffix: np.ndarray, span=None,
+                          chunks: int = 0) -> None:
         """Everything after the row cache exists: radix insert + pinning,
         paged table install, slot splice, per-slot sampler state, spans.
         Shared verbatim by the one-shot (`_admit`) and chunked
@@ -2032,7 +2145,16 @@ class DecodeServer:
             # walks the existing (hit) nodes without writing them, so
             # zeros never reach the blocks, and the returned chain keeps
             # the table's blocks pinned in `_held`.
-            chain = self._radix.insert(per_req, row_cache, pl)
+            rx, bp = self._radix, self._block_pool
+            t_insert = span and self.spans.clock()
+            before = (bp.blocks_written, rx.evictions,
+                      rx.evict_nodes_walked)
+            chain = rx.insert(per_req, row_cache, pl)
+            self._child_span(
+                span, "kv.insert", t_insert,
+                blocks_written=bp.blocks_written - before[0],
+                evicted=rx.evictions - before[1],
+                nodes_walked=rx.evict_nodes_walked - before[2])
             if hit_chain:
                 self._radix.release(hit_chain)
             if chain:
@@ -2130,21 +2252,15 @@ class DecodeServer:
             rem = 0                   # the prompt's very next token
         self._remaining = self._remaining.at[slot].set(rem)
         self._rc_invalidate()
-        if open_span is not None:
-            # chunked path: close the span opened at admission (its
-            # children are the per-chunk records)
-            sp = self.spans.finish(open_span, chunks=chunks)
+        req.dispatch0 = self._stats["dispatches"]
+        if span is not None:
+            # the span opened at admission closes here; a chunked one has
+            # the per-chunk records as children besides
+            self.spans.finish(span, **({"chunks": chunks} if chunks else {}))
+            # `lm.decode` chains under the prefill
             req = dataclasses.replace(
-                req, trace=(req.trace[0], sp.span_id))
-        elif t_prefill0 is not None:
-            sp = self.spans.record(
-                "lm.prefill", trace=req.trace[0], parent=req.trace[1],
-                t_start=t_prefill0,
-                attrs={"id": req.id, "prompt_len": suffix_true,
-                       "prefix_hit": hit, "bucket": suffix_bucket})
-            # decode-step spans chain under the prefill
-            req = dataclasses.replace(
-                req, trace=(req.trace[0], sp.span_id))
+                req, trace=(req.trace[0], span.span_id))
+            self._new_traced.append(req)
         self._live[slot] = req
         self._stats["admitted"] += 1
             # max_new == 1: the prefill's token was the only one; the next
@@ -2203,50 +2319,120 @@ class DecodeServer:
         Returns live rows + still-queued requests — 0 means drained (a
         max_new=1 admission can retire instantly, leaving 0 live rows with
         the queue non-empty, so live alone would end a client loop early)."""
-        self._retire_finished()
+        with self._span("lm.step") as st:
+            self._step_span = st
+            try:
+                return self._step(st)
+            finally:
+                self._step_span = None
+
+    def _span(self, name: str, **attrs):
+        """A span of the pool's own timeline, under the running `lm.step`
+        (`lm.step` itself: under the serving loop's iteration); nothing
+        and no profiler annotation where no store is wired."""
+        if self.spans is None:
+            return NO_SPAN
+        trace, parent = self.step_ctx or (
+            f"t:{self.spans.node}:loop:bare", None)
+        if self._step_span is not None:
+            parent = self._step_span.span_id
+        return loop_span(self.spans, name, trace, parent, **attrs)
+
+    def _retire_synced(self, after: str, stops: bool = False) -> None:
+        """`_retire_finished`, after `_apply_stops` where a dispatch ran.
+        With the cursors' host copy stale, the read-back returns only once
+        the chip has finished everything enqueued before it: the one place
+        in a step where the host waits for the device, and an
+        `lm.step.sync` span (``after``: what made the copy stale)."""
+        blocks = self._rc_cache is None and bool(self._live)
+        with self._span("lm.step.sync", after=after) if blocks else NO_SPAN:
+            if stops:
+                self._apply_stops()
+            self._retire_finished()
+
+    def _step(self, st) -> int:
+        admitted0 = self._stats["admitted"]
+        self._retire_synced("cancel")
         if self._pending is not None:
             # one chunk of the in-flight long admission, THEN the decode
             # dispatch below — resident rows advance between chunks
             self._advance_prefill()
         self._admit()
-        self._retire_finished()           # max_new == 1 admissions
+        self._retire_synced("admit")      # max_new == 1 admissions
+        # rows retired so far got no token from this step's dispatch
+        early = len(self._retired)
+        rows = len(self._live)
         if self._live:
-            t_step0 = (self.spans.clock() if self.spans is not None
-                       and any(r.trace for r in self._live.values())
-                       else None)
             pg = ((self._tables, self._plens,
                    self._block_pool.kv_pages()) if self._paged else ())
-            if self._draft_model is not None:
-                (self._tokens, self._cache, self._draft_cache,
-                 self._cursors, self._remaining,
-                 self._keys, self._logprobs) = self._decode_spec(
-                    self.params, self._draft_params, self._tokens,
-                    self._cache, self._draft_cache, self._cursors,
-                    self._remaining, self._temps, self._top_ps,
-                    self._top_ks, self._keys, self._logprobs, *pg)
-            else:
-                (self._tokens, self._cache, self._cursors,
-                 self._remaining, self._keys, self._logprobs,
-                 self._counts) = self._decode(
-                    self.params, self._tokens, self._cache, self._cursors,
-                    self._remaining, self._temps, self._top_ps,
-                    self._top_ks, self._keys, self._logprobs,
-                    self._pres, self._freq, self._counts, *pg)
+            with self._span("lm.decode_step", rows=rows) as sp:
+                for req in self._new_traced:
+                    req.t_decode0 = sp.t_start
+                if self._draft_model is not None:
+                    (self._tokens, self._cache, self._draft_cache,
+                     self._cursors, self._remaining,
+                     self._keys, self._logprobs) = self._decode_spec(
+                        self.params, self._draft_params, self._tokens,
+                        self._cache, self._draft_cache, self._cursors,
+                        self._remaining, self._temps, self._top_ps,
+                        self._top_ks, self._keys, self._logprobs, *pg)
+                else:
+                    (self._tokens, self._cache, self._cursors,
+                     self._remaining, self._keys, self._logprobs,
+                     self._counts) = self._decode(
+                        self.params, self._tokens, self._cache,
+                        self._cursors, self._remaining, self._temps,
+                        self._top_ps, self._top_ks, self._keys,
+                        self._logprobs, self._pres, self._freq,
+                        self._counts, *pg)
             self._stats["dispatches"] += 1
             self._dispatched_ever = True
-            if t_step0 is not None:
-                batch = len(self._live)
-                for req in self._live.values():
-                    if req.trace:
-                        self.spans.record(
-                            "lm.decode_step", trace=req.trace[0],
-                            parent=req.trace[1], t_start=t_step0,
-                            attrs={"id": req.id, "batch": batch})
             self._rc_invalidate()         # the dispatch advanced the rows
-            self._apply_stops()
-            self._retire_finished()
+            self._retire_synced("dispatch", stops=True)
+        self._new_traced.clear()
+        if st is not None:
+            st.attrs.update(
+                rows=rows, queued=len(self._queue),
+                admitted=self._stats["admitted"] - admitted0,
+                retired=len(self._retired))
+        self._stamp(early)
         return (len(self._live) + len(self._queue)
                 + (1 if self._pending is not None else 0))
+
+    def _stamp(self, early: int) -> None:
+        """The end of a step: the cursors are on the host, a streaming
+        client could see every token they cover. One clock read stamps the
+        first sight of each live request and the last of each request the
+        step retired; of those, the first ``early`` retired before the
+        dispatch (cancelled, or whole after the prefill's one token) and
+        keep the last stamp they had. No device read: the cursors are the
+        copy the last `_retire_finished` fetched."""
+        now = self.clock()
+        if self._live:
+            remaining = self._remaining_cursors()[0]
+            for slot, req in self._live.items():
+                req.t_last = now
+                if req.t_first is None:
+                    req.t_first = now
+                    req.n_first = req.max_new - int(remaining[slot])
+        for i, (done, req) in enumerate(self._retired):
+            generated = len(done.tokens) - done.prompt_len
+            if req.t_first is None:       # admitted and retired in one step
+                req.t_first = req.t_last = now
+                req.n_first = generated
+            elif i >= early:
+                req.t_last = now
+            done.t_first, done.n_first = req.t_first, req.n_first
+            done.t_last = req.t_last
+            if req.t_decode0 is not None:     # traced, and it was dispatched
+                self.spans.record(
+                    "lm.decode", trace=req.trace[0], parent=req.trace[1],
+                    t_start=req.t_decode0, t_end=req.t_last,
+                    attrs={"id": req.id, "tokens": generated,
+                           "steps": self._stats["dispatches"]
+                           - req.dispatch0,
+                           "t_first": req.t_first, "n_first": req.n_first})
+        self._retired.clear()
 
     def run_until_drained(self, max_steps: int = 10_000) -> list[Completion]:
         """Drive `step` until queue and slots are empty; returns every
@@ -2289,6 +2475,10 @@ class DecodeServer:
         for k in self._stats:
             self._stats[k] = 0
         self._pc_lookups = self._pc_hits = self._pc_tokens_saved = 0
+        if self._radix is not None:
+            self._radix.evict_nodes_walked = 0
+            self._block_pool.blocks_written = 0
+            self._block_pool.blocks_gathered = 0
         if self.cluster_prefix is not None:
             self.cluster_prefix.reset_counters()
         return warm_s

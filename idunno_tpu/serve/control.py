@@ -54,6 +54,10 @@ if TYPE_CHECKING:                                    # pragma: no cover
 SERVICE = "control"
 
 
+def _round6(x: float | None) -> float | None:
+    return None if x is None else round(x, 6)
+
+
 class RelayedError(Exception):
     """An ERROR reply from a forwarded owner hop, relayed VERBATIM (ISSUE
     16): the payload keeps its typed markers (``stale_epoch``, ``scope``,
@@ -518,6 +522,12 @@ class ControlService:
             out = {"completions": [
                 {"id": c.id, "tokens": c.tokens, "prompt_len": c.prompt_len,
                  "service_s": round(c.service_s, 6),
+                 # the pool's own stamps as durations (absolute times
+                 # stay off the wire): submit to the first visible
+                 # token(s), and seconds a token after them — null
+                 # where the request never got that far
+                 "ttft_s": _round6(c.ttft_s()),
+                 "tpot_s": _round6(c.tpot_s()),
                  "cold_start": c.cold_start,
                  "cancelled": c.cancelled,
                  **({"rejected": c.rejected}

@@ -21,9 +21,11 @@ as ``rejected="expired"`` without ever decoding them.
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 
-from idunno_tpu.engine.serve_lm import Completion, DecodeServer
+from idunno_tpu.engine.serve_lm import (NO_SPAN, Completion, DecodeServer,
+                                        loop_span)
 from idunno_tpu.serve.admission import PRIORITIES, AdmissionShed
 from idunno_tpu.serve.gateway import AdmissionGateway
 
@@ -38,10 +40,19 @@ class LMServingLoop:
         self.server = server
         self.gateway = gateway
         # per-node span recorder (utils/spans.SpanStore | None); wiring it
-        # here also hands it to the server for prefill/decode-step spans
+        # here also hands it to the server, with its clock: the server's
+        # stamps and every span then share one timeline. The loop's own
+        # spans (`loop.iter` and its children, the server's `lm.step`)
+        # live in one trace per pool, which `dump(trace_id)` returns.
         self.spans = spans
+        self.loop_trace = None
+        self._iter_end: float | None = None   # where the last iter ended
         if spans is not None:
             server.spans = spans
+            server.clock = spans.clock
+            # `lm_serve` names the loop "<node>-<pool>" for its thread
+            pool = name.removeprefix(f"{spans.node}-")
+            self.loop_trace = f"t:{spans.node}:loop:{pool}"
         # rid → (trace_id, admit_span_id, t_enq) while in flight;
         # rid → trace_id survives completion so the `trace` verb can
         # resolve a finished request's trace (bounded, insertion-ordered)
@@ -49,7 +60,7 @@ class LMServingLoop:
         self._trace_ids: dict[int, str] = {}
         self._lock = threading.Lock()
         # (id, toks, max_new, temperature, top_p, top_k, pres, freq,
-        #  stop, seed)
+        #  stop, seed, t_submit)
         self._inbox: list[tuple] = []
         self._outbox: list[Completion] = []
         self._next_id = 0
@@ -99,6 +110,7 @@ class LMServingLoop:
         before any id is queued. ``readmit=True`` is the manager's replay
         path — an already-admitted request being re-forwarded after node
         death bypasses admission checks (but still queues by class/ft)."""
+        t_submit = self.server.clock()
         # validate eagerly on the caller's thread so the RPC gets the error
         # (the loop thread has nowhere to raise to)
         self.server.validate(tokens, max_new, temperature, top_p, top_k,
@@ -116,16 +128,18 @@ class LMServingLoop:
             rid = self._next_id
             self._next_id += 1
             entry = (rid, list(tokens), max_new, temperature, top_p, top_k,
-                     presence_penalty, frequency_penalty, stop, seed)
+                     presence_penalty, frequency_penalty, stop, seed,
+                     t_submit)
             if self.gateway is None:
+                if tr is not None:
+                    # booked BEFORE the loop thread can drain the entry,
+                    # or its queue-wait span finds no trace to go under
+                    sp = self.spans.record(
+                        "lm.admit", trace=tr[0], parent=tr[1],
+                        attrs={"rid": rid, "tenant": tenant,
+                               "priority": priority, "gateway": False})
+                    self._book_trace(rid, tr[0], sp.span_id, sp.t_end)
                 self._inbox.append(entry)
-        if self.gateway is None:
-            if tr is not None:   # outside the lock: _book_trace takes it
-                sp = self.spans.record(
-                    "lm.admit", trace=tr[0], parent=tr[1],
-                    attrs={"rid": rid, "tenant": tenant,
-                           "priority": priority, "gateway": False})
-                self._book_trace(rid, tr[0], sp.span_id, sp.t_end)
         if self.gateway is not None:
             # outside self._lock: the gateway has its own lock, and a shed
             # must not leave loop state half-mutated (rid gaps are fine)
@@ -149,7 +163,8 @@ class LMServingLoop:
                     attrs={"rid": rid, "tenant": tenant,
                            "priority": priority, "gateway": True,
                            "readmit": bool(readmit)})
-                self._book_trace(rid, tr[0], sp.span_id, sp.t_end)
+                with self._lock:
+                    self._book_trace(rid, tr[0], sp.span_id, sp.t_end)
             # a stop() racing in between admit and here has already drained
             # the gateway; pull our entry back out and error like any other
             # post-stop submit (cancel() returning None = stop drained it,
@@ -166,12 +181,12 @@ class LMServingLoop:
                     t_enq: float) -> None:
         """Remember an admitted request's trace: in-flight tuple for the
         queue-wait/finish spans, plus the rid → trace_id map the `trace`
-        verb resolves after completion (bounded FIFO)."""
-        with self._lock:
-            self._traces[rid] = (tid, sid, t_enq)
-            self._trace_ids[rid] = tid
-            while len(self._trace_ids) > 4096:
-                self._trace_ids.pop(next(iter(self._trace_ids)))
+        verb resolves after completion (bounded FIFO). The caller holds
+        `self._lock`."""
+        self._traces[rid] = (tid, sid, t_enq)
+        self._trace_ids[rid] = tid
+        while len(self._trace_ids) > 4096:
+            self._trace_ids.pop(next(iter(self._trace_ids)))
 
     def _trace_done(self, rid: int, name: str, **attrs) -> None:
         """Record the terminal span (finish/cancel/expire) for ``rid`` and
@@ -180,6 +195,15 @@ class LMServingLoop:
         if tr is not None and self.spans is not None:
             self.spans.record(name, trace=tr[0], parent=tr[1],
                               attrs={"rid": rid, **attrs})
+
+    def _dropped(self, entry: tuple, **kw) -> Completion:
+        """The completion of a request that never reached a slot
+        (cancelled or expired while it waited): its prompt alone."""
+        full = (self.server.prefix or []) + list(entry[1])
+        return Completion(
+            id=entry[0], tokens=full, prompt_len=len(full),
+            logprobs=[] if self.server.track_logprobs else None,
+            t_submit=entry[10], **kw)
 
     def trace_of(self, rid: int) -> str | None:
         """Trace id of a public request id (live or recently finished);
@@ -216,25 +240,17 @@ class LMServingLoop:
         if self.gateway is not None:
             e = self.gateway.cancel(rid)
             if e is not None:
-                full = (self.server.prefix or []) + list(e.payload[1])
                 with self._lock:
-                    self._outbox.append(Completion(
-                        id=rid, tokens=full,
-                        prompt_len=len(full), cancelled=True,
-                        logprobs=([] if self.server.track_logprobs
-                                  else None)))
+                    self._outbox.append(
+                        self._dropped(e.payload, cancelled=True))
                 self._trace_done(rid, "lm.cancel", where="gateway")
                 return True
         with self._lock:
             for i, entry in enumerate(self._inbox):
                 if entry[0] == rid:
                     del self._inbox[i]
-                    full = (self.server.prefix or []) + list(entry[1])
-                    self._outbox.append(Completion(
-                        id=rid, tokens=full,
-                        prompt_len=len(full), cancelled=True,
-                        logprobs=([] if self.server.track_logprobs
-                                  else None)))
+                    self._outbox.append(
+                        self._dropped(entry, cancelled=True))
                     self._trace_done(rid, "lm.cancel", where="inbox")
                     return True
             sid = next((s for s, r in self._id_map.items() if r == rid),
@@ -325,6 +341,8 @@ class LMServingLoop:
         with self._lock:
             out["inbox"] = len(self._inbox)
             out["unpolled"] = len(self._outbox)
+        if self.loop_trace is not None:   # `trace <id>` shows the loop
+            out["loop_trace"] = self.loop_trace
         if self.gateway is not None:
             out["gateway"] = self.gateway.stats()
         return out
@@ -351,21 +369,27 @@ class LMServingLoop:
 
     # -- loop thread ------------------------------------------------------
 
-    def _drain_inbox(self) -> None:
+    def _drain_inbox(self) -> int:
         with self._lock:
             batch, self._inbox = self._inbox, []
-        for (rid, tokens, max_new, temperature, top_p, top_k, pres,
-             freq, stop, seed) in batch:
-            ctx = self._queue_wait_span(rid)
-            sid = self.server.submit(tokens, max_new,
-                                     temperature=temperature, top_p=top_p,
-                                     top_k=top_k, presence_penalty=pres,
-                                     frequency_penalty=freq, stop=stop,
-                                     seed=rid if seed is None else seed,
-                                     trace=ctx)
-            # under the lock: cancel() iterates this map from RPC threads
-            with self._lock:
-                self._id_map[sid] = rid
+        for entry in batch:
+            self._hand_over(entry)
+        return len(batch)
+
+    def _hand_over(self, entry: tuple, t_enq: float | None = None) -> None:
+        """Give one admitted request to the server's own queue."""
+        (rid, tokens, max_new, temperature, top_p, top_k, pres, freq,
+         stop, seed, t_submit) = entry
+        ctx = self._queue_wait_span(rid, t_enq=t_enq)
+        sid = self.server.submit(tokens, max_new,
+                                 temperature=temperature, top_p=top_p,
+                                 top_k=top_k, presence_penalty=pres,
+                                 frequency_penalty=freq, stop=stop,
+                                 seed=rid if seed is None else seed,
+                                 trace=ctx, t_submit=t_submit)
+        # under the lock: cancel() iterates this map from RPC threads
+        with self._lock:
+            self._id_map[sid] = rid
 
     def _queue_wait_span(self, rid: int,
                          t_enq: float | None = None) -> tuple | None:
@@ -383,35 +407,23 @@ class LMServingLoop:
             attrs={"rid": rid})
         return tr[0], tr[1]
 
-    def _drain_gateway(self) -> None:
+    def _drain_gateway(self) -> int:
         """Pull admitted work from the gateway under a dispatch budget
         that keeps the server queue ~2 batches deep (dispatching later
         keeps EDF/expiry decisions informed by the freshest deadlines),
         and retire expired entries as rejected completions."""
         if self.gateway is None:
-            return
+            return 0
         budget = max(0, 2 * self.server.slots - self.server.pending())
         ready, expired = self.gateway.take(budget)
         for e in expired:
-            full = (self.server.prefix or []) + list(e.payload[1])
             with self._lock:
-                self._outbox.append(Completion(
-                    id=e.rid, tokens=full, prompt_len=len(full),
-                    rejected="expired",
-                    logprobs=([] if self.server.track_logprobs else None)))
+                self._outbox.append(
+                    self._dropped(e.payload, rejected="expired"))
             self._trace_done(e.rid, "lm.expire", reason="expired")
         for e in ready:
-            (rid, tokens, max_new, temperature, top_p, top_k, pres,
-             freq, stop, seed) = e.payload
-            ctx = self._queue_wait_span(rid, t_enq=e.t_enq)
-            sid = self.server.submit(tokens, max_new,
-                                     temperature=temperature, top_p=top_p,
-                                     top_k=top_k, presence_penalty=pres,
-                                     frequency_penalty=freq, stop=stop,
-                                     seed=rid if seed is None else seed,
-                                     trace=ctx)
-            with self._lock:
-                self._id_map[sid] = rid
+            self._hand_over(e.payload, t_enq=e.t_enq)
+        return len(ready)
 
     def _drain_cancels(self) -> None:
         with self._lock:
@@ -484,36 +496,65 @@ class LMServingLoop:
         self._snap_want.clear()
         self._snap_done.set()
 
+    def _span(self, name: str, parent, **attrs):
+        """A child of the running `loop.iter` (``parent``); nothing where
+        no store is wired."""
+        if parent is None:
+            return NO_SPAN
+        return loop_span(self.spans, name, self.loop_trace,
+                         parent.span_id, **attrs)
+
     def _run(self) -> None:
         while not self._stop.is_set():
-            try:
+            if self.spans is None:
+                self._iterate(None)
+                continue
+            # the iterations tile the thread's time: each starts where
+            # the last one ended, whatever ran in between
+            with loop_span(self.spans, "loop.iter", self.loop_trace,
+                           None) as it:
+                if self._iter_end is not None:
+                    it.t_start = self._iter_end
+                self.server.step_ctx = (self.loop_trace, it.span_id)
+                self._iterate(it)
+            self._iter_end = it.t_end
+
+    def _iterate(self, it) -> None:
+        """One turn of the loop; ``it`` is its `loop.iter` span, or None
+        where no store is wired (nothing is then recorded, and no
+        profiler annotation entered)."""
+        try:
+            with self._span("loop.drain", it) as sp:
                 self._drain_cancels()
                 self._drain_notes()
-                self._drain_inbox()
-                self._drain_gateway()
-                live = self.server.step()
-                done = self.server.poll()
-            except Exception as e:  # noqa: BLE001 - loop must stay alive
-                with self._lock:
-                    if len(self._errors) < 100:   # bounded between drains
-                        self._errors.append(f"{type(e).__name__}: {e}")
-                live, done = 0, []
+                taken = self._drain_inbox() + self._drain_gateway()
+                if sp is not None:
+                    sp.attrs["taken"] = taken
+            live = self.server.step()
+            done = self.server.poll()
+        except Exception as e:  # noqa: BLE001 - loop must stay alive
+            with self._lock:
+                if len(self._errors) < 100:   # bounded between drains
+                    self._errors.append(f"{type(e).__name__}: {e}")
+            live, done = 0, []
+        with self._span("loop.publish", it, done=len(done)):
             self._fulfill_prefix()
             self._fulfill_snapshot()
             if done:
                 with self._lock:
                     for c in done:
                         rid = self._id_map.pop(c.id, c.id)
-                        self._outbox.append(Completion(
-                            id=rid,
-                            tokens=c.tokens, prompt_len=c.prompt_len,
-                            service_s=c.service_s, cancelled=c.cancelled,
-                            logprobs=c.logprobs,
-                            cold_start=c.cold_start))
+                        self._outbox.append(dataclasses.replace(c, id=rid))
                         self._trace_done(
                             rid,
                             "lm.cancel" if c.cancelled else "lm.finish",
-                            tokens=len(c.tokens))
-            if live == 0:
+                            tokens=len(c.tokens), prompt_len=c.prompt_len,
+                            t_submit=c.t_submit,
+                            t_admit=c.t_admit, t_first=c.t_first,
+                            n_first=c.n_first, t_last=c.t_last)
+        if it is not None:
+            it.attrs.update(live=live, done=len(done))
+        if live == 0:
+            with self._span("loop.idle_wait", it):
                 self._wake.wait(timeout=0.5)
                 self._wake.clear()

@@ -56,6 +56,9 @@ class RadixPrefixCache:
         self.evictions = 0
         self.insert_skips = 0
         self.inserted_blocks = 0
+        # nodes `_evict_one` visited, over all its calls: the price of
+        # choosing a victim by walking the tree
+        self.evict_nodes_walked = 0
 
     def _tick(self) -> int:
         self._clock += 1
@@ -192,16 +195,18 @@ class RadixPrefixCache:
     def _evict_one(self) -> bool:
         """Free the least-recently-used refcount-0 LEAF node's block.
         False when no node is evictable (every leaf pinned)."""
-        best = None
+        best, walked = None, 0
         stack = list(self._root.children.values())
         while stack:
             nd = stack.pop()
+            walked += 1
             if nd.children:
                 stack.extend(nd.children.values())
                 continue
             if self.pool.refcount(nd.block) == 0 and (
                     best is None or nd.stamp < best.stamp):
                 best = nd
+        self.evict_nodes_walked += walked
         if best is None:
             return False
         del best.parent.children[best.chunk]

@@ -22,6 +22,11 @@ Design rules, mirrored from the fence helpers:
   chaos, TimedFakeEngine clusters) get exact, assertable timelines.
 - **Bounded**: a deque(maxlen) ring — tracing a busy node costs a dict
   append, never unbounded memory; `dump()` is the observation window.
+- **Lanes**: a recorder whose span count depends on thread timing (the
+  decode pool's loop: one `loop.iter` per iteration, busy or idle) passes
+  ``lane=``. A lane has an id sequence and a ring of its own, so it can
+  neither shift the ids of a request's trace nor push request spans out
+  of the window.
 
 The thread-local *current context* (`current()`) lets the JSON-lines log
 formatter (`utils/logging.py`) tag records with the active trace/span so
@@ -31,7 +36,7 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import deque
+from collections import defaultdict, deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -94,6 +99,7 @@ class Span:
     t_start: float
     t_end: float | None = None
     attrs: dict = field(default_factory=dict)
+    lane: str = ""             # which ring holds it; never on the wire
 
     @property
     def ctx(self) -> tuple[str, str]:
@@ -136,58 +142,67 @@ class SpanStore:
         self.node = node
         self.clock = clock
         self._lock = threading.Lock()
-        self._buf: deque[Span] = deque(maxlen=int(capacity))
-        self._seq = 0
-        self._recorded = 0            # lifetime total (ring may evict)
+        # lane -> ring and id sequence; "" is the request lane every
+        # unlabelled span uses, made first so that `dump` lists it first
+        self._bufs: dict[str, deque[Span]] = defaultdict(
+            lambda: deque(maxlen=int(capacity)))
+        self._bufs[""]
+        self._seqs: dict[str, int] = defaultdict(int)
+        self._recorded = 0            # lifetime total (rings may evict)
 
     # -- id minting -------------------------------------------------------
 
-    def _next(self) -> int:
+    def _next(self, lane: str = "") -> str:
+        """The next id of ``lane``'s sequence: ``7`` on the request lane,
+        ``loop.7`` on the lane "loop"."""
         with self._lock:
-            self._seq += 1
-            return self._seq
+            self._seqs[lane] += 1
+            n = self._seqs[lane]
+        return f"{lane}.{n}" if lane else str(n)
 
     def new_trace(self) -> str:
         return f"t:{self.node}:{self._next()}"
 
+    def _append(self, span: Span) -> None:
+        with self._lock:
+            self._bufs[span.lane].append(span)
+            self._recorded += 1
+
     # -- recording --------------------------------------------------------
 
     def start(self, name: str, *, trace: str | None = None,
-              parent: str | None = None,
-              attrs: dict | None = None) -> Span:
+              parent: str | None = None, attrs: dict | None = None,
+              lane: str = "") -> Span:
         """Open a span (not yet in the buffer — `finish` appends it).
         ``trace=None`` mints a fresh trace rooted at this span."""
         return Span(trace_id=trace or self.new_trace(),
-                    span_id=f"{self.node}:{self._next()}", parent=parent,
-                    name=name, node=self.node, t_start=self.clock(),
-                    attrs=dict(attrs or {}))
+                    span_id=f"{self.node}:{self._next(lane)}",
+                    parent=parent, name=name, node=self.node,
+                    t_start=self.clock(), attrs=dict(attrs or {}),
+                    lane=lane)
 
     def finish(self, span: Span, **attrs: Any) -> Span:
         span.t_end = self.clock()
         if attrs:
             span.attrs.update(attrs)
-        with self._lock:
-            self._buf.append(span)
-            self._recorded += 1
+        self._append(span)
         return span
 
     def record(self, name: str, *, trace: str | None = None,
                parent: str | None = None, t_start: float | None = None,
-               t_end: float | None = None,
-               attrs: dict | None = None) -> Span:
+               t_end: float | None = None, attrs: dict | None = None,
+               lane: str = "") -> Span:
         """One-shot span, appended immediately. Explicit ``t_start``/
         ``t_end`` let callers time against a different clock they own
         (e.g. the gateway's queue-enter timestamp)."""
         now = self.clock()
         span = Span(trace_id=trace or self.new_trace(),
-                    span_id=f"{self.node}:{self._next()}", parent=parent,
-                    name=name, node=self.node,
+                    span_id=f"{self.node}:{self._next(lane)}",
+                    parent=parent, name=name, node=self.node,
                     t_start=now if t_start is None else float(t_start),
                     t_end=now if t_end is None else float(t_end),
-                    attrs=dict(attrs or {}))
-        with self._lock:
-            self._buf.append(span)
-            self._recorded += 1
+                    attrs=dict(attrs or {}), lane=lane)
+        self._append(span)
         return span
 
     @contextmanager
@@ -208,10 +223,11 @@ class SpanStore:
 
     def dump(self, trace_id: str | None = None,
              limit: int | None = None) -> list[dict]:
-        """Wire dicts of the buffered window, oldest first; filtered to
-        one trace when ``trace_id`` is given, last ``limit`` otherwise."""
+        """Wire dicts of the buffered window, each lane oldest first and
+        the request lane before the others; filtered to one trace when
+        ``trace_id`` is given, last ``limit`` otherwise."""
         with self._lock:
-            spans = list(self._buf)
+            spans = [s for buf in self._bufs.values() for s in buf]
         if trace_id is not None:
             spans = [s for s in spans if s.trace_id == trace_id]
         if limit is not None and limit > 0:
@@ -224,8 +240,9 @@ class SpanStore:
 
     def depth(self) -> int:
         with self._lock:
-            return len(self._buf)
+            return sum(len(buf) for buf in self._bufs.values())
 
     def clear(self) -> None:
         with self._lock:
-            self._buf.clear()
+            for buf in self._bufs.values():
+                buf.clear()

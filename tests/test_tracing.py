@@ -508,8 +508,8 @@ def test_two_node_cluster_collects_lm_trace(tmp_path):
         for s in spans:
             by_name.setdefault(s["name"], []).append(s)
         for want in ("client.lm_submit", "lm.submit", "lm.admit",
-                     "lm.queue_wait", "lm.prefill", "lm.decode_step",
-                     "lm.finish"):
+                     "lm.queue_wait", "lm.slot_wait", "lm.prefill",
+                     "kv.lookup", "kv.insert", "lm.decode", "lm.finish"):
             assert want in by_name, f"missing {want}: {sorted(by_name)}"
         sub = by_name["lm.submit"][0]
         admit = by_name["lm.admit"][0]
@@ -519,11 +519,33 @@ def test_two_node_cluster_collects_lm_trace(tmp_path):
         assert sub["parent"] == root.span_id and sub["node"] == "n0"
         assert admit["parent"] == sub["span_id"]
         assert by_name["lm.queue_wait"][0]["parent"] == admit["span_id"]
+        assert by_name["lm.slot_wait"][0]["parent"] == admit["span_id"]
         assert prefill["parent"] == admit["span_id"]
-        assert len(by_name["lm.decode_step"]) >= 1
-        assert all(d["parent"] == prefill["span_id"]
-                   for d in by_name["lm.decode_step"])
-        assert by_name["lm.finish"][0]["parent"] == admit["span_id"]
+        for kv in ("kv.lookup", "kv.insert"):
+            assert by_name[kv][0]["parent"] == prefill["span_id"]
+        # one decode span a request (not one a row a dispatch): 6 tokens
+        # are the prefill's one and 5 dispatches' (decode_steps 1)
+        (decode,) = by_name["lm.decode"]
+        assert decode["parent"] == prefill["span_id"]
+        assert decode["attrs"]["steps"] == 5
+        assert decode["attrs"]["tokens"] == 6
+        assert decode["attrs"]["n_first"] == 2
+        finish = by_name["lm.finish"][0]
+        assert finish["parent"] == admit["span_id"]
+        # the stamps ride the finish span, on the same injected clock
+        for k in ("t_submit", "t_admit", "t_first", "t_last"):
+            assert finish["attrs"][k] in clk.seen, k
+        assert finish["attrs"]["t_first"] == decode["attrs"]["t_first"]
+        # the pool's own timeline is one trace, whatever requests ran
+        loop_tid = "t:n0:loop:tlm"
+        ltl = _call(nodes["n0"], {"verb": "trace",
+                                  "trace_id": loop_tid})["spans"]
+        lnames = {s["name"] for s in ltl}
+        assert {"loop.iter", "loop.drain", "lm.step", "lm.decode_step",
+                "lm.step.sync", "loop.publish"} <= lnames, lnames
+        assert prefill["attrs"]["step"] in {
+            s["span_id"] for s in ltl if s["name"] == "lm.step"}
+        assert not any(s["trace_id"] == loop_tid for s in spans)
         # fake-clock exactness: every timestamp is a value the injected
         # clock actually produced, and every closed span is well-ordered
         for s in spans:

@@ -196,8 +196,8 @@ def capture(out_path: str = "TRACE_WATERFALL.json",
         commit = ""
     rec = {"provenance": {"recorded_at": time.time(),
                           "git_commit": commit, "platform": platform},
-           "decode_steps": sum(1 for s in spans
-                               if s["name"] == "lm.decode_step"),
+           "decode_steps": sum(s["attrs"]["steps"] for s in spans
+                               if s["name"] == "lm.decode"),
            "waterfall": wf,
            "chrome": to_chrome(spans, trace_id=root.trace_id)}
     with open(os.path.join(REPO, out_path), "w") as f:
