@@ -20,10 +20,15 @@ absolute positions) can splice into their own prefill via the existing
   incref/decref  — per-block reference counts: a block is pinned while
                    any admitted request's chain holds it, so the tree
                    can only evict refcount-0 chains
-  write_block    — copy one block's worth of a prefill row cache's K/V
-                   into a block (one compiled scatter per leaf shape;
-                   the block id and token offset are traced, so block
-                   churn never recompiles)
+  write_blocks   — write blocks' worth of a prefill row cache's K/V
+                   into the stores IN PLACE: the store is donated to
+                   one compiled program per (store shape, row length)
+                   that updates the live buffer, so a write moves a
+                   block's bytes and never a copy of the pool (the
+                   block ids and token offsets are traced, so block
+                   churn never recompiles). A store handle therefore
+                   does NOT survive a write: fetch `kv_pages()` afresh
+                   for every dispatch, never cache it
   gather         — assemble a chain back into a batch-1, length-n·bs
                    cache tree whose leaf paths match `init_cache`'s, so
                    `_prefill_suffix` can splice it verbatim
@@ -69,28 +74,45 @@ def _is_kv(path) -> bool:
     return bool(path) and getattr(path[-1], "key", None) in KV_LEAF_KEYS
 
 
-@partial(jax.jit, static_argnames=("stacked",))
+# blocks one `_write_block` dispatch writes: a prompt's new blocks go out
+# in groups of this many per leaf instead of one dispatch each. A constant
+# of the program, not of the row: the compile count stays one per (store
+# shape, row length), and the unrolled program stays small to compile
+_WRITE_GROUP = 16
+
+
+@partial(jax.jit, static_argnames=("stacked",), donate_argnums=(0,))
 def _write_block(store: jnp.ndarray, row_leaf: jnp.ndarray,
-                 bid: jnp.ndarray, off: jnp.ndarray,
-                 stacked: bool = False) -> jnp.ndarray:
-    """store[bid] = row_leaf[0, off:off+block_size]. bid/off are traced:
-    one compile per (store shape, row length), not per block or offset.
-    ``stacked`` is a STATIC flag, not rank-inferred: a scanned-cache
-    k_scale leaf [L, 1, T, kvh] has the same rank as an unscanned
-    cached_k [1, T, h, d], so only the caller knows the layout."""
-    if stacked:
-        # row_leaf is [L, 1, T, ...]; block slivers keep the depth axis.
-        # Stacked stores are [L, N, bs, ...] (depth LEADS, block second)
-        # so the paged decode path can hand `store[l]` — a ready-made
-        # [N, bs, ...] page array — to the per-layer scan body with no
-        # moveaxis/copy (ops/paged_attention.py).
-        bs = store.shape[2]
-        chunk = jax.lax.dynamic_slice_in_dim(row_leaf[:, 0], off, bs,
-                                             axis=1)
-        return store.at[:, bid].set(chunk.astype(store.dtype))
-    bs = store.shape[1]
-    chunk = jax.lax.dynamic_slice_in_dim(row_leaf[0], off, bs, axis=0)
-    return store.at[bid].set(chunk.astype(store.dtype))
+                 plan: jnp.ndarray, stacked: bool = False) -> jnp.ndarray:
+    """store[bid] = row_leaf[0, off:off+block_size] for every (bid, off)
+    column of ``plan`` (int32 [2, n]; a short group repeats its last
+    column, which rewrites the same bytes). The store is DONATED and
+    each block lands as a dynamic-update-slice into the live buffer: the
+    output aliases the input and no copy of the store is made. That is
+    why the columns are unrolled: around a scatter, and around a loop
+    wherever it lays the block axis out minor (scale leaves, head size
+    64), the chip's compiler re-lays the whole store out, a copy in and
+    one out (`tests/test_chip_compile.py` holds the shapes). The plan is
+    traced: one compile per (store shape, row length), not per block or
+    offset. ``stacked`` is a STATIC flag, not rank-inferred: a
+    scanned-cache k_scale leaf [L, 1, T, kvh] has the same rank as an
+    unscanned cached_k [1, T, h, d], so only the caller knows the
+    layout."""
+    # Stacked stores are [L, N, bs, ...] (depth LEADS, block second) so
+    # the paged decode path can hand `store[l]` — a ready-made
+    # [N, bs, ...] page array — to the per-layer scan body with no
+    # moveaxis/copy (ops/paged_attention.py); row_leaf is [L, 1, T, ...]
+    # and block slivers keep the depth axis.
+    axis = 1 if stacked else 0            # block axis; the row's token axis
+    bs = store.shape[axis + 1]
+    row = row_leaf[:, 0] if stacked else row_leaf[0]
+    for i in range(plan.shape[1]):
+        chunk = jax.lax.dynamic_slice_in_dim(row, plan[1, i], bs, axis=axis)
+        start = [0] * store.ndim
+        start[axis] = plan[0, i]
+        store = jax.lax.dynamic_update_slice(
+            store, jnp.expand_dims(chunk.astype(store.dtype), axis), start)
+    return store
 
 
 @partial(jax.jit, static_argnames=("n", "stacked"))
@@ -248,33 +270,58 @@ class KVBlockPool:
 
     # -- data movement ----------------------------------------------------
 
-    def write_block(self, bid: int, row_cache: Any, offset: int) -> None:
+    def write_blocks(self, bids: list[int], row_cache: Any,
+                     offsets: list[int]) -> None:
         """Copy token positions [offset, offset+block_size) of a batch-1
-        prefill cache's K/V leaves into block ``bid``. The offset is an
-        ABSOLUTE cache position — with a pool-level static prefix ahead
-        of the request tokens, the caller passes prefix_len + i.
+        prefill cache's K/V leaves into block ``bid``, for every pair of
+        ``bids`` and ``offsets``, `_WRITE_GROUP` blocks to a dispatch
+        per leaf. An offset is an ABSOLUTE cache position — with a
+        pool-level static prefix ahead of the request tokens, the caller
+        passes prefix_len + i.
 
-        The window must lie inside the row cache: `dynamic_slice` clamps
-        out-of-range starts SILENTLY, which would duplicate the tail
-        block's tokens into the next block and poison every later prefix
-        hit — so out-of-range offsets raise here instead."""
+        The write is IN PLACE: each store is donated to `_write_block`
+        and rebound from its result, so the pool is the only owner of a
+        live store and any handle taken before the write is dead after
+        it (see `kv_pages`).
+
+        Every window must lie inside the row cache: `dynamic_slice`
+        clamps out-of-range starts SILENTLY, which would duplicate the
+        tail block's tokens into the next block and poison every later
+        prefix hit — so out-of-range offsets raise here instead, before
+        any store is touched. Likewise an unallocated block id raises:
+        the in-place update would clamp it onto a neighbour's block."""
         src = {jax.tree_util.keystr(p): leaf for p, leaf
                in jax.tree_util.tree_flatten_with_path(row_cache)[0]
                if _is_kv(p)}
         tok_axis = 2 if self._stacked else 1
         row_len = next(iter(src.values())).shape[tok_axis]
-        if offset < 0 or offset + self.block_size > row_len:
-            raise ValueError(
-                f"write_block offset {offset} + block_size "
-                f"{self.block_size} outside row cache of {row_len} "
-                f"tokens (offset is an ABSOLUTE cache position — did the "
-                f"caller forget/double-count the static prefix length?)")
-        b = jnp.int32(bid)
-        off = jnp.int32(offset)
+        for offset in offsets:
+            if offset < 0 or offset + self.block_size > row_len:
+                raise ValueError(
+                    f"write_block offset {offset} + block_size "
+                    f"{self.block_size} outside row cache of {row_len} "
+                    f"tokens (offset is an ABSOLUTE cache position — did "
+                    f"the caller forget/double-count the static prefix "
+                    f"length?)")
+        for bid in bids:
+            if bid not in self._refs:
+                raise ValueError(f"block {bid} is not allocated")
+        for g in range(0, len(bids), _WRITE_GROUP):
+            cols = list(zip(bids[g:g + _WRITE_GROUP],
+                            offsets[g:g + _WRITE_GROUP]))
+            cols += cols[-1:] * (_WRITE_GROUP - len(cols))
+            self._write(src, jnp.asarray(np.asarray(cols, np.int32).T))
+        self.blocks_written += len(bids)
+
+    def _write(self, rows: dict[str, Any], plan: jnp.ndarray) -> None:
+        """The one place a store changes: donated, rebound from the result."""
         for key, store in self._stores.items():
-            self._stores[key] = _write_block(store, src[key], b, off,
+            self._stores[key] = _write_block(store, rows[key], plan,
                                              stacked=self._stacked)
-        self.blocks_written += 1
+
+    def write_block(self, bid: int, row_cache: Any, offset: int) -> None:
+        """`write_blocks` for one block."""
+        self.write_blocks([bid], row_cache, [offset])
 
     def read_block(self, bid: int) -> dict[str, Any]:
         """One block's raw per-leaf content as HOST numpy arrays, keyed
@@ -297,7 +344,8 @@ class KVBlockPool:
         block ``bid``. Every store leaf must be present with its exact
         per-block shape — a partial or mis-shaped payload raises before
         any store is touched (a half-written block would poison every
-        later prefix hit on its chain)."""
+        later prefix hit on its chain). In place, through the same
+        donated writer as `write_blocks`."""
         if bid not in self._refs:
             raise ValueError(f"block {bid} is not allocated")
         staged = {}
@@ -311,22 +359,25 @@ class KVBlockPool:
                 raise ValueError(
                     f"write_raw_block leaf {key!r} shape {arr.shape} != "
                     f"store block shape {want}")
-            staged[key] = jnp.asarray(arr, store.dtype)
-        for key, store in self._stores.items():
-            if self._stacked:
-                self._stores[key] = store.at[:, bid].set(staged[key])
-            else:
-                self._stores[key] = store.at[bid].set(staged[key])
+            # a sliver is a batch-1 row of exactly one block: the same
+            # donated in-place writer as `write_blocks`, offset 0
+            staged[key] = jnp.expand_dims(jnp.asarray(arr, store.dtype),
+                                          1 if self._stacked else 0)
+        self._write(staged, jnp.asarray([[bid], [0]], jnp.int32))
         self.blocks_written += 1
 
     def kv_pages(self) -> dict[str, jnp.ndarray]:
         """Raw page stores by leaf name ({"cached_k", "cached_v"} plus
         {"k_scale", "v_scale"} on int8 pools), each ``[L, N, bs, ...]``
         — the arrays the paged decode path (`ops/paged_attention.py`)
-        reads THROUGH the block table instead of gathering. Stacked
-        (scanned) pools only: an unscanned multi-layer pool has one
-        store per layer under the same leaf name, which has no single
-        per-name page array to hand out."""
+        reads THROUGH the block table instead of gathering. These are
+        the LIVE stores, and a block write donates them: fetch them per
+        dispatch and hand them straight to the program (a dispatch
+        already enqueued keeps its buffer until it has run); a dict held
+        across a write holds deleted arrays. Stacked (scanned) pools
+        only: an unscanned multi-layer pool has one store per layer
+        under the same leaf name, which has no single per-name page
+        array to hand out."""
         if not self._stacked:
             raise ValueError(
                 "kv_pages() requires a depth-stacked (scanned) pool; "

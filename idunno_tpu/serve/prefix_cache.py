@@ -113,6 +113,7 @@ class RadixPrefixCache:
         one reference per node and must `release` it at retirement."""
         stamp = self._tick()
         node, chain = self._root, []
+        fresh: list[tuple[_Node, int]] = []       # new node, its row offset
         for j, chunk in enumerate(self._chunks(tokens)):
             child = node.children.get(chunk)
             if child is None:
@@ -120,15 +121,27 @@ class RadixPrefixCache:
                 if bid is None:
                     self.insert_skips += 1
                     break
-                self.pool.write_block(bid, row_cache,
-                                      pos_offset + j * self.block_size)
                 child = _Node(chunk, bid, node, stamp)
                 node.children[chunk] = child
-                self.inserted_blocks += 1
+                fresh.append((child, pos_offset + j * self.block_size))
             child.stamp = stamp
             self.pool.incref(child.block)
             chain.append(child)
             node = child
+        if fresh:
+            # every new block of the prompt in one call: the pool writes
+            # them a group to a dispatch, not one dispatch each
+            try:
+                self.pool.write_blocks([nd.block for nd, _ in fresh],
+                                       row_cache, [off for _, off in fresh])
+            except Exception:
+                # a refused write leaves no node over an unwritten block
+                self.release(chain)
+                for nd, _ in reversed(fresh):
+                    del nd.parent.children[nd.chunk]
+                    self.pool.free(nd.block)
+                raise
+            self.inserted_blocks += len(fresh)
         return chain
 
     def graft(self, tokens: list[int],

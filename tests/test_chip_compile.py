@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from idunno_tpu.engine.kv_blocks import _WRITE_GROUP, _write_block
 from idunno_tpu.ops.flash_attention import flash_attention
 from idunno_tpu.ops.paged_attention import paged_attention_grouped
 from idunno_tpu.ops.pallas_preprocess import preprocess_batch_pallas
@@ -130,3 +131,36 @@ def test_paged_prefill_suffix_rows_fit_vmem(one_chip):
         one_chip, ((1, t, kvh, 1, d), jnp.float32), pages, pages,
         ((1, c), jnp.int32), ((1,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("store,row,dtype,stacked,n", [
+    ((30, 3584, 16, 2, 128), (30, 1, 1024, 2, 128), jnp.bfloat16, True,
+     _WRITE_GROUP),
+    ((16, 1024, 16, 4, 128), (16, 1, 64, 4, 128), jnp.bfloat16, True,
+     _WRITE_GROUP),
+    ((16, 1024, 16, 4, 128), (16, 1, 1024, 4, 128), jnp.int8, True,
+     _WRITE_GROUP),
+    ((16, 1024, 16, 4), (16, 1, 1024, 4), jnp.float32, True, _WRITE_GROUP),
+    ((12, 2048, 16, 16, 64), (12, 1, 512, 16, 64), jnp.bfloat16, True,
+     _WRITE_GROUP),
+    ((1024, 16, 4, 128), (1, 1024, 4, 128), jnp.bfloat16, False,
+     _WRITE_GROUP),
+    ((16, 1024, 16, 4, 128), (16, 1, 16, 4, 128), jnp.bfloat16, True, 1),
+], ids=["starcoder2-3b", "starcoder2-7b-row64", "int8", "int8-scales",
+        "mha-d64", "unstacked", "raw-sliver"])
+def test_block_write_updates_the_store_in_place(one_chip, store, row, dtype,
+                                                stacked, n):
+    """`_write_block` at the benchmark's pools (and the shapes whose block
+    axis the chip lays out minor: scale leaves, head size 64), ``n`` blocks
+    a dispatch: the output aliases the donated store and the program's own
+    memory stays far under one store — no copy of the pool in, out or
+    beside it. A loop or a scatter over the blocks fails this for the
+    last-named shapes."""
+    compiled = _write_block.lower(
+        jax.ShapeDtypeStruct(store, dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct(row, dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((2, n), jnp.int32, sharding=one_chip),
+        stacked=stacked).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == mem.output_size_in_bytes
+    assert mem.temp_size_in_bytes < mem.output_size_in_bytes // 4
