@@ -13,8 +13,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmark import reference
-
 
 def pick_sample(finished: list[dict], n: int, seed: int) -> list[dict]:
     """The longest finished request and ``n - 1`` others drawn from the
@@ -32,22 +30,23 @@ def pick_sample(finished: list[dict], n: int, seed: int) -> list[dict]:
     return [finished[i] for i in chosen]
 
 
-def served_gaps(w: dict, cfg: dict, sample: list[dict],
+def served_gaps(logits_at, w: dict, cfg: dict, sample: list[dict],
                 quant: str | None = None) -> dict:
-    """Gaps of the served tokens under the reference; with ``quant`` the
-    gaps of the tokens that the lower-precision pass puts first at the same
-    positions of the same histories (the control)."""
+    """Gaps of the served tokens under the reference ``logits_at`` (the
+    family's `reference.logits_at`); with ``quant`` the gaps of the tokens
+    that the lower-precision pass puts first at the same positions of the
+    same histories (the control)."""
     gaps: list[np.ndarray] = []
     for req in sample:
         toks, pl = req["tokens"], req["prompt_len"]
         where = list(range(pl - 1, len(toks) - 1))
         if not where:
             continue
-        ref = reference.logits_at(w, cfg, toks, where)
+        ref = logits_at(w, cfg, toks, where)
         if quant is None:
             picked = np.asarray(toks[pl:], np.int64)
         else:
-            low = reference.logits_at(w, cfg, toks, where, quant=quant)
+            low = logits_at(w, cfg, toks, where, quant=quant)
             picked = low.argmax(axis=-1)
         best = ref.max(axis=-1)
         gaps.append(best - ref[np.arange(len(where)), picked])

@@ -25,6 +25,7 @@ class RunData:
     """What the per-layer readers read (see `benchmark/readers`)."""
     cfg: dict
     device: dict
+    family: object = None    # `Manifest.family`: counts, reference, ...
     w0: float = 0.0
     w1: float = 0.0
     tw0: float = 0.0
@@ -34,7 +35,8 @@ class RunData:
     spans: list = field(default_factory=list)
     stats0: dict = field(default_factory=dict)
     stats1: dict = field(default_factory=dict)
-    modules: dict = field(default_factory=dict)
+    modules: dict = field(default_factory=dict)   # program -> [s, runs]
+    ops: dict = field(default_factory=dict)       # '<program>/<op>' -> s
     busy_s: float = 0.0
     trace_window_s: float = 0.0
     compiles_in_window: int = 0
@@ -136,15 +138,16 @@ class Session:
     the pool as `lm_serve` builds it, the step stamps, every shape the
     traffic will meet. `window` then measures one window of a stream."""
 
-    def __init__(self, cfg: dict, mix: dict, seed: int, seconds: float, *,
-                 trace_on: bool, rehearse: bool, device: dict):
+    def __init__(self, cfg: dict, family, mix: dict, seed: int,
+                 seconds: float, *, trace_on: bool, rehearse: bool,
+                 device: dict):
         import jax
         import numpy as np
 
-        from benchmark import system, weights
-        from idunno_tpu.utils.spans import SpanStore
+        from benchmark import system
 
-        self.cfg, self.mix, self.device = cfg, mix, device
+        self.cfg, self.family, self.mix = cfg, family, mix
+        self.device = device
         self.trace_on = trace_on
         self.compiles = CompileCounter()
         self.parts = {"imports_and_chip_s": clock()}   # since process start
@@ -154,12 +157,13 @@ class Session:
         self.tr = traffic.generate(mix, cfg["serving"], cfg["vocab_size"],
                                    seed, seconds, shrink=self.shrink)
         t = clock()
-        self.w = jax.block_until_ready(weights.make_weights(cfg, seed))
+        self.w = jax.block_until_ready(
+            family.weights.make_weights(cfg, seed))
         self.parts["weights_s"] = clock() - t
         t = clock()
-        self.spans = (SpanStore("bench", clock=clock, capacity=1 << 20)
-                      if trace_on else None)
-        self.loop, self.server = system.build(cfg, self.w, spans=self.spans)
+        self.spans = system.span_store(clock) if trace_on else None
+        self.loop, self.server = system.build(cfg, self.w, family,
+                                              spans=self.spans)
         self._gen: list = []
         self.sc = timing.StepClock(
             self.server,
@@ -192,7 +196,8 @@ class Session:
         gen = Generator(loop, tr, t_start=t_start, t_end=w1,
                         trace_stamp=stamp)
         self._gen[:] = [gen]
-        run = RunData(cfg=self.cfg, device=self.device, w0=w0, w1=w1)
+        run = RunData(cfg=self.cfg, device=self.device, family=self.family,
+                      w0=w0, w1=w1)
         first_step = len(self.sc.steps)
         gen.start()
         time.sleep(max(0.0, w0 - clock()))
@@ -301,14 +306,16 @@ def run(args, t_proc: float) -> tuple[dict, dict]:
     man = (manifest.Manifest(root, os.path.join(root, "benchmark"))
            if root else manifest.Manifest())
     cell = man.cell(args.workload)
-    cfg = system.model_config(man.config(cell), args.rehearse)
+    cfg = man.config(cell)
+    family = man.family(cfg)
+    cfg = system.model_config(cfg, args.rehearse, family)
     mix = man.mix(cell)
     device = system.require_chips(cell["chips"], args.rehearse)
     if not args.rehearse:
         cache_dir()
     seconds = float(args.seconds)
     trace_on = bool(args.trace)
-    ses = Session(cfg, mix, args.seed, seconds, trace_on=trace_on,
+    ses = Session(cfg, family, mix, args.seed, seconds, trace_on=trace_on,
                   rehearse=args.rehearse, device=device)
     ses.parts["imports_and_chip_s"] -= t_proc
     run, gen, completions, marks, trace_dir = ses.window(ses.tr, seconds)
@@ -344,7 +351,7 @@ def run(args, t_proc: float) -> tuple[dict, dict]:
     sample = check.pick_sample(finished, int(chk["sample_requests"]),
                                args.seed)
     t_chk = clock()
-    gaps = check.served_gaps(w, cfg, sample)
+    gaps = check.served_gaps(family.reference.logits_at, w, cfg, sample)
     limit = (cfg["rehearse"]["check_limit"] if args.rehearse
              else chk["limit"])
     limit = float("nan") if limit is None else limit
@@ -357,7 +364,8 @@ def run(args, t_proc: float) -> tuple[dict, dict]:
     compared["check_s"] = {"value": clock() - t_chk, "limit": None}
     if args.control:
         # the control, for setting the limit; a measured run never asks
-        ctl = check.served_gaps(w, cfg, sample, quant=args.control)
+        ctl = check.served_gaps(family.reference.logits_at, w, cfg, sample,
+                                quant=args.control)
         print(json.dumps({"control": args.control, **_finite(ctl)}),
               flush=True)
 
@@ -398,7 +406,8 @@ def run(args, t_proc: float) -> tuple[dict, dict]:
         result["rehearse"] = True
         result["verdict_at_toy_size"] = correct
     result["compared"] = compared
-    summary.update(_sample=sample, _weights=w, _cfg=cfg, _limit=limit)
+    summary.update(_sample=sample, _weights=w, _cfg=cfg, _limit=limit,
+                   _family=family)
     return _finite(result), summary
 
 
@@ -463,7 +472,8 @@ def fill_trace(run: RunData, tracemod, trace_dir: str, marks: list,
              "step.other": 2, "loop.between_steps": 3, "loop.idle_wait": 3}
     host.sort(key=lambda s: order[s[0]])
     gaps = tracemod.idle_gaps(tr, a, b, host)
-    run.breakdown = {"device_ops": tracemod.top(tracemod.op_times(tr)),
+    run.ops = tracemod.op_times(tr)
+    run.breakdown = {"device_ops": tracemod.top(run.ops),
                      "idle_gaps": tracemod.top(gaps)}
     run.step_contexts = [(t0, t1, live, ctx) for t0, t1, live, _p, ctx
                          in run.steps if run.tw0 <= t1 < run.tw1]

@@ -1,5 +1,6 @@
-"""Readers of the device trace and of JAX's own counters."""
-from benchmark import counts, peaks
+"""Readers of the device trace and of JAX's own counters. The operations
+and bytes a step needs are the family's (`run.family.counts`)."""
+from benchmark import peaks
 
 _PREFILL = ("jit__prefill", "jit__prefill_suffix", "jit__prefill_suffix_paged",
             "jit__prefill_chunk")
@@ -56,7 +57,8 @@ def decode_step_roofline(run):
         if not ctx:
             continue
         # the k steps of a dispatch see the rows grow by one token each
-        f, b = counts.decode_step_work(run.cfg, [max(1.0, c - (k - 1) / 2) for c in ctx])
+        f, b = run.family.counts.decode_step_work(
+            run.cfg, [max(1.0, c - (k - 1) / 2) for c in ctx])
         p = peaks.peaks_for(run.device["kind"])
         least += k * max(f / p["flops_bf16"], b / p["hbm_bytes_per_s"])
     if not least:
@@ -74,7 +76,7 @@ def prefill_roofline(run):
     p = peaks.peaks_for(run.device["kind"])
     least = 0.0
     for new, cached in run.traced_prefills:
-        f, b = counts.prefill_work(run.cfg, new, cached)
+        f, b = run.family.counts.prefill_work(run.cfg, new, cached)
         least += max(f / p["flops_bf16"], b / p["hbm_bytes_per_s"])
     return 100.0 * least / t if least else None
 
@@ -84,11 +86,11 @@ def step_mfu(run):
     output, plus attention's operations, over window seconds x peak."""
     flops = 0.0
     for new, cached in run.window_prefills:
-        flops += counts.prefill_work(run.cfg, new, cached)[0]
+        flops += run.family.counts.prefill_work(run.cfg, new, cached)[0]
     k = run.cfg["serving"]["decode_steps"]
     for _t0, t1, _live, ctx in run.window_step_contexts:
         if ctx:
-            flops += k * counts.decode_step_work(
+            flops += k * run.family.counts.decode_step_work(
                 run.cfg, [max(1.0, c - (k - 1) / 2) for c in ctx])[0]
     if not flops:
         return None

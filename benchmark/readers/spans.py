@@ -1,4 +1,4 @@
-"""Readers of what the program records of itself (`idunno_tpu/utils/spans.py`
+"""Readers of what the program records of itself (its `utils/spans.py`
 and `DecodeServer.stats()`): the stamps the pool puts on `lm.finish`, the
 spans of an admission, the pool loop's own timeline (`loop.iter`, `lm.step`
 and their children) and the eviction counters. A span counts where it ends

@@ -39,15 +39,18 @@ def main() -> int:
 
     man = manifest.Manifest()
     cell = man.cell(args.workload)
-    cfg = system.model_config(man.config(cell), args.rehearse)
+    cfg = man.config(cell)
+    family = man.family(cfg)
+    cfg = system.model_config(cfg, args.rehearse, family)
     cfg["serving"] = dict(cfg["serving"], **json.loads(args.serving))
     mix = dict(man.mix(cell), **json.loads(args.mix))
     device = system.require_chips(cell["chips"], args.rehearse)
     if not args.rehearse:
         harness.cache_dir()
     t0 = timing.clock()
-    ses = harness.Session(cfg, mix, args.seed, args.seconds, trace_on=False,
-                          rehearse=args.rehearse, device=device)
+    ses = harness.Session(cfg, family, mix, args.seed, args.seconds,
+                          trace_on=False, rehearse=args.rehearse,
+                          device=device)
     print(json.dumps({"built_s": timing.clock() - t0, "device": device,
                       "peak_gb": system.memory_peak_bytes(1) / 1e9,
                       "bytes_limit": (jax.devices()[0].memory_stats() or {}
@@ -89,10 +92,11 @@ def main() -> int:
                        for r in run.records if r["complete"]]
                 sample = check.pick_sample(
                     fin, int(cfg["check"]["sample_requests"]), args.seed + n)
-                out["gaps"] = check.served_gaps(ses.w, cfg, sample)
+                logits_at = family.reference.logits_at
+                out["gaps"] = check.served_gaps(logits_at, ses.w, cfg, sample)
                 if args.check != "ref":
-                    out["control"] = check.served_gaps(ses.w, cfg, sample,
-                                                       quant=args.check)
+                    out["control"] = check.served_gaps(
+                        logits_at, ses.w, cfg, sample, quant=args.check)
             print(json.dumps(harness._finite(out)), flush=True)
     ses.close()
     return 0

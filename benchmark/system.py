@@ -1,18 +1,18 @@
 """The system under test, built as `lm_serve` builds it (`serve/control.py`):
 a `DecodeServer` from the configuration's serving parameters, driven by an
-`LMServingLoop` and its own thread. This is the only module of the benchmark
-that imports the program."""
+`LMServingLoop` and its own thread. What depends on the architecture (the
+model, its parameters) comes from the configuration's family
+(`benchmark/families/<family>/program.py`); beside those files this is the
+only module of the benchmark that imports the program."""
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
-
-from benchmark import weights as W
 
 
-def model_config(cfg: dict, rehearse: bool) -> dict:
+def model_config(cfg: dict, rehearse: bool, family) -> dict:
     """The configuration as it is run; ``rehearse`` swaps in the tiny widths
-    the file keeps for a CPU walk-through."""
+    the file keeps for a CPU walk-through, and the family derives what
+    follows from them."""
     if not rehearse:
         return cfg
     tiny = dict(cfg)
@@ -20,32 +20,25 @@ def model_config(cfg: dict, rehearse: bool) -> dict:
     tiny["serving"] = dict(cfg["serving"], **r.pop("serving"))
     tiny["as_run"] = dict(cfg.get("as_run", {}), dtype=r.pop("dtype"))
     tiny.update(r)
-    tiny["intermediate_size"] = 4 * tiny["hidden_size"]
-    tiny["head_dim"] = tiny["hidden_size"] // tiny["num_attention_heads"]
-    return tiny
+    return family.program.derive(tiny)
 
 
-def build(cfg: dict, w: dict, *, spans=None, name: str = "bench"):
-    """(loop, server) over the configuration ``cfg`` and weights ``w``."""
+def span_store(clock):
+    """The program's own span store, on the benchmark's clock."""
+    from idunno_tpu.utils.spans import SpanStore
+    return SpanStore("bench", clock=clock, capacity=1 << 20)
+
+
+def build(cfg: dict, w: dict, family, *, spans=None, name: str = "bench"):
+    """(loop, server) over the configuration ``cfg`` and its family's
+    weights ``w``."""
     from idunno_tpu.engine.serve_lm import DecodeServer
-    from idunno_tpu.models.transformer import TransformerLM
     from idunno_tpu.serve.lm_pool import LMServingLoop
 
-    if cfg["intermediate_size"] != 4 * cfg["hidden_size"]:
-        raise ValueError("the program's block has its MLP at 4x the width")
-    dtype = jnp.dtype(cfg.get("as_run", {}).get("dtype", "bfloat16"))
-    model = TransformerLM(
-        vocab=cfg["vocab_size"], dim=cfg["hidden_size"],
-        depth=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        dtype=dtype, param_dtype=dtype,
-        # the pool stacks per-block params itself; handing it the stacked
-        # layout saves the transient second copy of the weights
-        scan_layers=True)
+    model, params, server_kwargs = family.program.build(cfg, w)
     s = cfg["serving"]
     server = DecodeServer(
-        model, W.program_params(w), slots=int(s["slots"]),
+        model, params, slots=int(s["slots"]),
         prompt_len=max(s["prompt_buckets"]), max_len=int(s["max_len"]),
         decode_steps=int(s["decode_steps"]),
         prompt_buckets=tuple(s["prompt_buckets"]),
@@ -53,7 +46,7 @@ def build(cfg: dict, w: dict, *, spans=None, name: str = "bench"):
         kv_cache_blocks=int(s["kv_cache_blocks"]),
         paged_kernel=s.get("paged_kernel"),
         prefill_chunk=int(s.get("prefill_chunk", 0)),
-        n_model=int(s.get("n_model", 1)))
+        n_model=int(s.get("n_model", 1)), **server_kwargs)
     loop = LMServingLoop(server, name=name, spans=spans)
     return loop, server
 

@@ -1,42 +1,13 @@
 """Seeded weights, made on the device in one jitted call, in the type they
-are served in. The benchmark makes them, not the program: the program gets
-them laid out as its scanned decode pool holds them, the plain reference
-reads the same arrays under the benchmark's own names."""
+are served in. The benchmark makes them, not the program. A family
+(`benchmark/families/<family>/weights.py`) states its tensors; the drawing
+here is the same for every family."""
 from __future__ import annotations
 
 from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-from benchmark import counts
-
-
-def shapes(cfg: dict) -> dict[str, tuple]:
-    d = counts.dims(cfg)
-    L, h, H, K, hd, f, V = (d["layers"], d["h"], d["heads"], d["kvh"],
-                            d["hd"], d["ffn"], d["vocab"])
-    return {
-        "embed": (V, h),
-        "ln1_s": (L, h), "ln1_b": (L, h),
-        "wq": (L, h, H, hd), "bq": (L, H, hd),
-        "wk": (L, h, K, hd), "bk": (L, K, hd),
-        "wv": (L, h, K, hd), "bv": (L, K, hd),
-        "wo": (L, H, hd, h), "bo": (L, h),
-        "ln2_s": (L, h), "ln2_b": (L, h),
-        "w_up": (L, h, f), "b_up": (L, f),
-        "w_down": (L, f, h), "b_down": (L, h),
-        "lnf_s": (h,), "lnf_b": (h,),
-        "w_head": (h, V), "b_head": (V,),
-    }
-
-
-def _fan_in(name: str, shape: tuple) -> int:
-    if name == "wo":
-        return shape[1] * shape[2]
-    if name == "w_head":
-        return shape[0]
-    return shape[1]
 
 
 def seed_key(seed: int, impl: str | None = None):
@@ -67,45 +38,11 @@ def _make(key, spec: tuple, dtype) -> dict:
     return out
 
 
-def make_weights(cfg: dict, seed: int) -> dict:
-    """All weights of the configuration from ``seed``: kernels at
-    1/sqrt(fan-in), biases and LayerNorm offsets at 0.02, LayerNorm scales
-    at 1 +- 0.1, the embedding at 1 - so that no term of the block is a
-    no-op that a faulty path could drop unseen."""
-    dtype = jnp.dtype(cfg.get("as_run", {}).get("dtype", "bfloat16"))
-    spec = []
-    for name, shape in shapes(cfg).items():
-        if name == "embed":
-            kind, scale = "embed", 1.0
-        elif name.endswith("_s"):
-            kind, scale = "ln_scale", 0.1
-        elif name.startswith("w"):
-            kind, scale = "kernel", _fan_in(name, shape) ** -0.5
-        else:
-            kind, scale = "bias", 0.02
-        stacked = len(shape) > 1 and shape[0] == cfg["num_hidden_layers"] \
-            and name not in ("embed", "w_head")
-        spec.append((name, shape, kind, scale, stacked))
+def draw(spec, seed: int, dtype) -> dict:
+    """{name: array} for ``spec``, a sequence of (name, shape, kind, scale,
+    stacked): normal draws times ``scale`` (kind "ln_scale": 1 + the draw),
+    tensor ``i`` from ``fold_in(key, i)``; a stacked tensor is drawn one
+    leading index at a time."""
     # the rbg generator fills 3 B values in well under a second on a TPU;
     # the key is private to this call, the program's own keys are untouched
-    return _make(seed_key(seed, impl="rbg"), tuple(spec), dtype)
-
-
-def program_params(w: dict) -> dict:
-    """The same arrays as the program's scanned pool holds them
-    (`models/transformer.py:stack_block_params`): no copy."""
-    def kb(k, b):
-        return {"kernel": w[k], "bias": w[b]}
-    return {
-        "embed": {"embedding": w["embed"]},
-        "blocks": {
-            "ln1": {"scale": w["ln1_s"], "bias": w["ln1_b"]},
-            "attn": {"q": kb("wq", "bq"), "k": kb("wk", "bk"),
-                     "v": kb("wv", "bv"), "out": kb("wo", "bo")},
-            "ln2": {"scale": w["ln2_s"], "bias": w["ln2_b"]},
-            "mlp_up": kb("w_up", "b_up"),
-            "mlp_down": kb("w_down", "b_down"),
-        },
-        "ln_f": {"scale": w["lnf_s"], "bias": w["lnf_b"]},
-        "head": kb("w_head", "b_head"),
-    }
+    return _make(seed_key(seed, impl="rbg"), tuple(spec), jnp.dtype(dtype))

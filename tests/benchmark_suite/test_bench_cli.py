@@ -1,28 +1,34 @@
 """The command as the driver runs it, and the harness taking a new cell, mix,
 configuration and per-layer metric as new files alone."""
 import argparse
-import hashlib
 import json
 import os
 import shutil
 import subprocess
 import sys
 
+import pytest
+import scratch_root
+
 from benchmark import harness, manifest
 
 ROOT = manifest.ROOT
 RUN = [sys.executable, os.path.join("benchmark", "run.py")]
+# every cell the benchmark has: a later PR's cell is walked with no edit here
+CELLS = [w["name"] for w in manifest.Manifest().data["workloads"]]
 
 
-def _cli(extra, cwd=ROOT, env=None, timeout=600):
-    cmd = RUN + ["--workload", "starcoder2-7b.completion", "--seed",
-                 "2147483999", "--seconds", "3"] + extra
+def _cli(extra, cwd=ROOT, env=None, timeout=600,
+         workload="starcoder2-7b.completion"):
+    cmd = RUN + ["--workload", workload, "--seed", "2147483999",
+                 "--seconds", "3"] + extra
     return subprocess.run(cmd, cwd=cwd, env=env, capture_output=True,
                           text=True, timeout=timeout)
 
 
-def test_rehearsal_walks_the_whole_command():
-    p = _cli(["--trace", "1", "--rehearse"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_rehearsal_walks_the_whole_command(cell):
+    p = _cli(["--trace", "1", "--rehearse"], workload=cell)
     assert p.returncode == 3, p.stderr[-2000:]
     last = json.loads(p.stdout.strip().splitlines()[-1])
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(last)
@@ -55,25 +61,10 @@ def test_it_fails_where_the_program_is_missing(tmp_path):
     assert not any(l.startswith('{"correct"') for l in p.stdout.splitlines())
 
 
-def _digest(top):
-    out = {}
-    for d, _dirs, files in os.walk(top):
-        for f in files:
-            path = os.path.join(d, f)
-            with open(path, "rb") as fh:
-                out[os.path.relpath(path, top)] = hashlib.sha256(
-                    fh.read()).hexdigest()
-    return out
-
-
 def test_new_cell_mix_config_and_metric_are_files_and_entries(tmp_path):
     """What a later PR does: new files under the benchmark's directory and
     new entries in BENCHMARK.json; no file that is there changes."""
-    bench = tmp_path / "benchmark"
-    for sub in ("configs", "traffic", "metrics", "readers"):
-        shutil.copytree(os.path.join(ROOT, "benchmark", sub), bench / sub,
-                        ignore=shutil.ignore_patterns("__pycache__"))
-    before = _digest(bench)
+    bench, before = scratch_root.make(tmp_path)
     # a configuration, a mix, a reader and a metric of its own
     cfg = json.loads((bench / "configs" / "starcoder2-7b.json").read_text())
     cfg["name"] = "starcoder2-7b-d8"
@@ -117,5 +108,5 @@ def test_new_cell_mix_config_and_metric_are_files_and_entries(tmp_path):
     assert "rehearse.prefix_hit_share" in got
     assert "rehearse.batch_mfu" not in got      # out_tok_s is not its metric
     assert summary["requests_due"] > 0 and result["verdict_at_toy_size"]
-    after = _digest(bench)
+    after = scratch_root.digest(bench)
     assert {k: after[k] for k in before} == before

@@ -6,14 +6,17 @@ look for a chip."""
 import argparse
 import json
 import os
-import shutil
 
 import pytest
+import scratch_root
 
 from benchmark import check, harness, manifest
 
-CELLS = ["starcoder2-3b.batch", "starcoder2-7b.completion",
-         "starcoder2-3b.repo-prefix"]
+# every cell the benchmark has (a later PR's cell is compared with no edit
+# here), and the shared-prefix mix that is kept for later
+PREFIX_CELL = "starcoder2-3b.repo-prefix"
+CELLS = [w["name"] for w in manifest.Manifest().data["workloads"]] \
+    + [PREFIX_CELL]
 
 
 def _run(workload, *, seed=3_000_000_019, trace=0, seconds=3.0, root=None):
@@ -29,25 +32,22 @@ def prefix_run(tmp_path_factory):
     traffic file (PERF.md, Open questions) and becomes a cell by entries in
     a copy of BENCHMARK.json, as a later PR would add it."""
     root = tmp_path_factory.mktemp("with_repo_prefix")
-    for sub in ("configs", "traffic", "metrics", "readers"):
-        shutil.copytree(os.path.join(manifest.ROOT, "benchmark", sub),
-                        root / "benchmark" / sub,
-                        ignore=shutil.ignore_patterns("__pycache__"))
+    scratch_root.make(root)
     with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as f:
         data = json.load(f)
     data["workloads"].append({
-        "name": CELLS[2], "config": "starcoder2-3b",
+        "name": PREFIX_CELL, "config": "starcoder2-3b",
         "traffic": "repo-prefix", "chips": 1, "why": "shared prefixes"})
     for m in data["end_to_end"]:
         if m["name"] in ("ttft_p90_ms", "tpot_p90_ms"):
-            m["workloads"].append(CELLS[2])
+            m["workloads"].append(PREFIX_CELL)
     (root / "BENCHMARK.json").write_text(json.dumps(data))
-    return _run(CELLS[2], trace=1, root=str(root))
+    return _run(PREFIX_CELL, trace=1, root=str(root))
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_reference_agrees_with_the_program(cell, prefix_run):
-    result, summary = (prefix_run if cell == CELLS[2] else _run(cell))
+    result, summary = (prefix_run if cell == PREFIX_CELL else _run(cell))
     cmp = result["compared"]
     assert summary["requests_due"] > 0 and summary["failed"] == 0
     assert cmp["checked_tokens"]["value"] >= 30
@@ -78,7 +78,8 @@ def test_the_control_comes_out_not_correct(prefix_run):
     configuration's, serves tokens that the comparison refuses."""
     _, summary = prefix_run
     cfg, limit = summary["_cfg"], summary["_limit"]
-    ctl = check.served_gaps(summary["_weights"], cfg, summary["_sample"],
+    ctl = check.served_gaps(summary["_family"].reference.logits_at,
+                            summary["_weights"], cfg, summary["_sample"],
                             quant="fp8")
     assert ctl["tokens"] >= 30 and ctl["agree"] < 0.99
     ok, _ = check.decide({"max_gap": (ctl["max_gap"], limit)})

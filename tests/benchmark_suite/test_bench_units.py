@@ -8,7 +8,8 @@ import os
 
 import pytest
 
-from benchmark import counts, manifest, peaks, timing, trace, traffic
+from benchmark import manifest, peaks, timing, trace, traffic
+from benchmark.families.starcoder2 import counts
 
 BENCH = os.path.dirname(os.path.abspath(manifest.__file__))
 MIXES = ["batch", "completion", "repo-prefix"]
