@@ -43,6 +43,8 @@ def init_cache(model: TransformerLM, batch: int, max_len: int) -> Any:
     unscanned twin's block0 — scan-compatible models have homogeneous
     blocks, so block0 names every layer's shapes)."""
     dec = decode_model(model, max_len)
+    if hasattr(dec, "init_cache"):     # a stack that lays out its own cache
+        return dec.init_cache(batch)   # (`models/hybrid.py`)
     if getattr(model, "scan_layers", False):
         flat = dataclasses.replace(dec, scan_layers=False)
         shapes = jax.eval_shape(flat.init, jax.random.PRNGKey(0),

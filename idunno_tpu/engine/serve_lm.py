@@ -44,7 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from idunno_tpu.engine.generate import decode_model, init_cache
-from idunno_tpu.engine.kv_blocks import concat_kv_prefix
+from idunno_tpu.engine.kv_blocks import SLOT_LEAF_KEYS, concat_kv_prefix
+from idunno_tpu.models.hybrid import UnsupportedStack
 from idunno_tpu.models.transformer import (TransformerLM, decode_apply,
                                            scan_compatible,
                                            stack_block_params)
@@ -204,6 +205,18 @@ def _set_cursors(cache: Any, cursors: jnp.ndarray) -> Any:
     return jax.tree_util.tree_map_with_path(f, cache)
 
 
+def _set_valid(cache: Any, n) -> Any:
+    """Tell a batch-1 prefill cache how many of its positions are real: the
+    scalar ``valid`` leaf of a stack with recurrent layers
+    (`models/hybrid.py`), which keeps a bucket's padding out of the state
+    it carries. A cache with no such leaf comes back as it is."""
+    def f(path, leaf):
+        if path and getattr(path[-1], "key", None) == "valid":
+            return jnp.broadcast_to(jnp.asarray(n, jnp.int32), leaf.shape)
+        return leaf
+    return jax.tree_util.tree_map_with_path(f, cache)
+
+
 @partial(jax.jit, static_argnames=("model", "prompt_len"))
 def _prefill(model: TransformerLM, params: Any, prompt: jnp.ndarray,
              true_len: jnp.ndarray, prompt_len: int):
@@ -212,7 +225,7 @@ def _prefill(model: TransformerLM, params: Any, prompt: jnp.ndarray,
     insert sets the slot cursor to true_len so they are masked until
     overwritten by real generated tokens."""
     dec = decode_model(model, prompt_len)
-    cache = init_cache(model, 1, prompt_len)
+    cache = _set_valid(init_cache(model, 1, prompt_len), true_len)
     params = dequantize_tree(params)     # no-op for full-precision trees
     logits, cache = decode_apply(dec, params, cache,
                                  prompt.astype(jnp.int32))
@@ -399,7 +412,10 @@ def _splice_rows(cache: Any, row_cache: Any, slot: jnp.ndarray,
     pool cache. The two trees' structures differ only at the cursor leaves
     (scalar "cursor" in the prefill cache vs caller-owned [S] "cursors"
     in the pool) — K/V (and, for int8 caches, their scale) leaves match
-    by path, everything else untouched. ``stacked`` (static — the layout
+    by path, and so do a hybrid stack's pooled keys and recurrent state
+    (`kv_blocks.SLOT_LEAF_KEYS`; a state leaf has no token axis and lands
+    whole, so nothing of the slot's last tenant is left in it); everything
+    else untouched. ``stacked`` (static — the layout
     is not inferable from rank: a per-block cached_k and a stacked
     k_scale are both 4-D) selects the scanned layout, where every leaf
     carries a leading depth axis and the slot axis is SECOND."""
@@ -407,8 +423,7 @@ def _splice_rows(cache: Any, row_cache: Any, slot: jnp.ndarray,
            in jax.tree_util.tree_flatten_with_path(row_cache)[0]}
 
     def splice(path, dst):
-        if getattr(path[-1], "key", None) not in (
-                "cached_k", "cached_v", "k_scale", "v_scale"):
+        if getattr(path[-1], "key", None) not in SLOT_LEAF_KEYS:
             return dst
         kv = src[jax.tree_util.keystr(path)]          # [(L,) 1, P, h, d]
         if stacked:
@@ -731,6 +746,25 @@ class DecodeServer:
             # (MoE pools keep the gathered path)
             raise ValueError("paged_kernel requires the scanned decode "
                              "layout (dense scan-compatible blocks)")
+        # a stack with recurrent or block-sparse layers (`models/hybrid.py`)
+        # keeps state a slot that a chain of K/V blocks cannot rebuild: the
+        # radix cache never serves it a hit (`prefix_skipped_recurrent`
+        # counts the admissions that would have looked), and what rests on
+        # restoring or re-deriving K/V alone is refused here, by name
+        self._recurrent = bool(getattr(model, "recurrent", False))
+        if self._recurrent:
+            refused = [what for what, asked in (
+                ("draft= (speculative decoding)", draft is not None),
+                ("n_model > 1 or mesh= (no sharding specs)",
+                 n_model != 1 or mesh is not None),
+                ("paged_kernel=", paged_kernel is not None),
+                ("prefix= (a shared prefix restores K/V only)",
+                 bool(prefix)),
+                ("quantize=", quantize != "none")) if asked]
+            if refused:
+                raise UnsupportedStack(
+                    "a stack with recurrent or block-sparse layers does "
+                    "not support " + "; ".join(refused))
         # CPU tier runs the real kernel under the Pallas interpreter so
         # tier-1 tests exercise the exact kernel the TPU compiles
         self._paged_interpret = jax.devices()[0].platform != "tpu"
@@ -970,6 +1004,16 @@ class DecodeServer:
                        "kv_handoff_fallbacks": 0}
         # prefix-cache counters (zero-cost when the cache is off)
         self._pc_lookups = self._pc_hits = self._pc_tokens_saved = 0
+        # hybrid stacks only: each live slot's cursor as the host last saw
+        # it, from which a dispatch's contexts are counted without a read
+        # of the device; tokens its sparse layers' queries attended and had
+        # in context, summed over live rows and decode steps; admissions
+        # that skipped the radix lookup
+        self._seen_cursor: dict[int, int] = {}
+        if self._recurrent:
+            self._stats.update(sparse_tokens_attended=0,
+                               sparse_tokens_in_context=0,
+                               prefix_skipped_recurrent=0)
         # flips True at the first decode dispatch and NEVER resets (the
         # warmup() stats reset must not re-mark a warmed pool cold):
         # requests admitted while False carry Request.cold → their
@@ -1522,6 +1566,8 @@ class DecodeServer:
         out = dict(self._stats, live=len(self._live),
                    queued=len(self._queue), slots=self.slots,
                    config=config)
+        if self._recurrent:
+            out["recurrent_state_bytes"] = self.model.state_bytes(self.slots)
         if self._radix is not None:
             out["prefix_cache"] = self.prefix_cache_stats()
         return out
@@ -1660,7 +1706,14 @@ class DecodeServer:
         return {"published_blocks": published, "chains": len(chains),
                 "blocks": blocks}
 
+    def _refuse_recurrent(self, what: str) -> None:
+        if self._recurrent:
+            raise UnsupportedStack(
+                f"{what}: a chain of K/V blocks cannot rebuild a slot of a "
+                "stack with recurrent or block-sparse layers")
+
     def _require_cluster(self):
+        self._refuse_recurrent("cluster prefix cache")
         if self.cluster_prefix is None or self._radix is None:
             raise ValueError("pool has no cluster prefix cache "
                              "(serve with cluster_prefix= and "
@@ -1679,6 +1732,7 @@ class DecodeServer:
     # cluster prefix cache — handoff is transport-direct by design.
 
     def _require_handoff(self) -> None:
+        self._refuse_recurrent("kv handoff")
         if self._radix is None:
             raise ValueError("pool has no KV block tier "
                              "(serve with kv_block_size > 0)")
@@ -1961,7 +2015,10 @@ class DecodeServer:
             # block until prefix+hit+bucket fits max_len (hit 0 always
             # fits — the plain path's own guarantee).
             hit, hit_chain = 0, []
-            if self._radix is not None:
+            if self._recurrent:
+                if self._radix is not None:     # never a hit: see __init__
+                    self._stats["prefix_skipped_recurrent"] += 1
+            elif self._radix is not None:
                 self._pc_lookups += 1
                 t_lookup = sp and self.spans.clock()
                 hit_chain = self._radix.lookup(per_req)
@@ -2038,7 +2095,9 @@ class DecodeServer:
                     sp.attrs["chunked"] = True
                 self._pending = {
                     "req": req, "slot": slot,
-                    "cache": _chunk_init(self._prefill_model, pre, total),
+                    "cache": _set_valid(
+                        _chunk_init(self._prefill_model, pre, total),
+                        pl + suffix_true),
                     "suffix": suffix, "true": suffix_true - hit,
                     "suffix_true": suffix_true, "cursor0": pl + hit,
                     "bucket": suffix_bucket, "off": 0, "hit": hit,
@@ -2117,6 +2176,7 @@ class DecodeServer:
                 "lm.prefill_chunk", trace=p["span"].trace_id,
                 parent=p["span"].span_id,
                 attrs={"id": p["req"].id, "chunk": p["chunks"] - 1,
+                       "of": -(-p["bucket"] // self.prefill_chunk),
                        "tokens": int(n)})
         p["off"] += n
         if p["off"] >= p["bucket"]:
@@ -2137,7 +2197,7 @@ class DecodeServer:
         paged table install, slot splice, per-slot sampler state, spans.
         Shared verbatim by the one-shot (`_admit`) and chunked
         (`_advance_prefill`) prefill paths so they cannot drift."""
-        if self._radix is not None:
+        if self._radix is not None and not self._recurrent:
             # seed/extend the tree from this prefill's row cache and
             # pin the request's full chain for its lifetime (insert
             # returns it acquired); the temporary hit pins drop. On the
@@ -2201,10 +2261,17 @@ class DecodeServer:
         seed = req.id if req.seed is None else req.seed
         first, key = _pick_first(last_logits, temp,
                                  jax.random.PRNGKey(seed), topp, topk)
+        t_splice = span and self._recurrent and self.spans.clock()
         self._tokens, self._cache = _insert(
             self._tokens, self._cache, row_cache, jnp.asarray(prompt),
             first, jnp.int32(true_len), jnp.int32(slot), bucket,
             stacked=self._scan)
+        if self._recurrent:
+            # the row's state and pooled keys went into the slot with its
+            # K/V, whole: nothing of the slot's last tenant is left
+            self._seen_cursor[slot] = true_len
+            self._child_span(span, "state.splice", t_splice,
+                             state_bytes=self.model.state_bytes(1))
         if self._draft_model is not None:
             # the draft needs the FULL request prompt through ITS
             # OWN weights (a radix hit only covers the target's
@@ -2347,8 +2414,26 @@ class DecodeServer:
         blocks = self._rc_cache is None and bool(self._live)
         with self._span("lm.step.sync", after=after) if blocks else NO_SPAN:
             if stops:
+                if self._recurrent:
+                    self._count_attended()
                 self._apply_stops()
             self._retire_finished()
+
+    def _count_attended(self) -> None:
+        """After a dispatch on a hybrid stack: what its sparse layers'
+        queries attended and what they had in context, a token a live row
+        a decode step, from the cursors the dispatch left and those the
+        host last saw (the model's geometry says what a context of n
+        tokens attends)."""
+        cursors = self._remaining_cursors()[1]
+        for slot in self._live:
+            now, was = int(cursors[slot]), self._seen_cursor[slot]
+            if now > was:
+                ctx = np.arange(was + 1, now + 1)
+                self._stats["sparse_tokens_in_context"] += int(ctx.sum())
+                self._stats["sparse_tokens_attended"] += int(
+                    self.model.attended_tokens(ctx).sum())
+                self._seen_cursor[slot] = now
 
     def _step(self, st) -> int:
         admitted0 = self._stats["admitted"]

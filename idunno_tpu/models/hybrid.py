@@ -1,0 +1,506 @@
+"""A hybrid LM stack described by data: layers of two kinds in any order.
+
+`TransformerLM` is one block repeated, one cache shape a layer. This module
+serves stacks whose layers differ in kind (`mixers`, one name a layer) and
+whose caches differ with them:
+
+  ``minicpm4``        block-sparse softmax attention (InfLLM-v2): grouped
+                      query heads without RoPE over `cached_k`/`cached_v`,
+                      plus `comp_k`, the keys mean-pooled over
+                      `kernel_size` tokens every `kernel_stride` (the
+                      indexer's cache). A query whose context is longer
+                      than `dense_len` scores the pooled keys, takes a
+                      block's score from the pooled keys that overlap it and
+                      attends block 0, the blocks over its last
+                      `window_size` tokens and the `topk` best others.
+  ``lightning-attn``  decayed linear attention: a float32 `state`
+                      [heads, d, d] a row, `S_t = exp(-s) S_{t-1} + k_t^T
+                      v_t`, `o_t = q_t S_t / sqrt(d)`; no token axis.
+
+Both sit in one pre-norm block (RMSNorm, bias-free projections, q/k
+RMSNorm, sigmoid output gate, gated SiLU MLP) with muP multipliers
+(`scale_emb`, `scale_depth / sqrt(published depth)`, logits over
+`logit_div`). Consecutive layers of one kind are ONE `lax.scan` over their
+stacked parameters and cache, so a stack of 1 + 6 + 2 + 3 layers is four
+scans in one program, not twelve dispatches.
+
+`HybridLM` is a frozen dataclass, not a flax module: it is the jit-static
+description, `init_cache` and `decode_apply` are its two entry points, and
+`engine.generate.init_cache` / `models.transformer.decode_apply` hand over
+to them, so `engine/generate.py` and `DecodeServer` stay layout-blind. The
+cache follows the scanned layout's rules (leaves named by kind, depth
+leading, the slot axis second): `cached_k`/`cached_v` [L, B, T, kvh, d],
+`comp_k` [L, B, T/stride, kvh, d] float32, `state` [L, B, H, d, d] float32,
+one `cursor`/`cursors` leaf and, in the scalar-cursor (prefill) shape, one
+`valid` leaf: positions at or past it are padding and enter neither the
+state nor the pooled keys.
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from idunno_tpu.models.transformer import rope
+
+SPARSE, LINEAR = "minicpm4", "lightning-attn"
+_HI = jax.lax.Precision.HIGHEST
+_NO_LIMIT = np.iinfo(np.int32).max
+_LIN_BLOCK = 256        # tokens a lightning sub-block (intra-chunk product)
+_KEY_TILE = 1024        # keys a tile of the chunked sparse prefill
+
+
+class UnsupportedStack(ValueError):
+    """A serving feature that a stack with recurrent or block-sparse layers
+    cannot use yet (a radix hit restores keys and values only)."""
+
+
+@dataclass(frozen=True)
+class HybridLM:
+    vocab: int
+    dim: int
+    mlp_dim: int
+    mixers: tuple            # one of SPARSE / LINEAR a layer
+    layer_ids: tuple         # each layer's PUBLISHED index (its slopes)
+    published_depth: int
+    num_heads: int           # sparse layers: query heads
+    num_kv_heads: int        # sparse layers: KV heads
+    head_dim: int
+    lightning_heads: int
+    lightning_head_dim: int
+    scale_emb: float = 1.0
+    scale_depth: float = 1.0
+    logit_div: float = 1.0   # hidden_size / dim_model_base
+    eps: float = 1e-6
+    rope_theta: float = 10000.0
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    block_size: int = 64
+    topk: int = 64
+    init_blocks: int = 1
+    window_size: int = 2048
+    dense_len: int = 8192
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    decode: bool = False
+    decode_per_row: bool = False
+    max_decode_len: int = 0
+    # what `DecodeServer` asks of any model
+    causal: bool = True
+    scan_layers: bool = True
+    kv_cache_dtype: str = "native"
+    ffn_factory: Any = None
+
+    def __post_init__(self):
+        if len(self.mixers) != len(self.layer_ids):
+            raise ValueError("one published index a layer")
+        bad = set(self.mixers) - {SPARSE, LINEAR}
+        if bad:
+            raise ValueError(f"unknown mixer kinds {sorted(bad)}")
+        if (self.block_size % self.kernel_stride
+                or self.kernel_size % self.kernel_stride):
+            raise ValueError("block_size and kernel_size must be multiples "
+                             "of kernel_stride")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("query heads must be a multiple of KV heads")
+
+    @property
+    def depth(self) -> int:
+        return len(self.mixers)
+
+    # a slot holds state that keys and values cannot rebuild: a radix
+    # prefix hit is not restorable (`DecodeServer` asks)
+    recurrent = True
+
+    def runs(self) -> list[tuple[str, tuple]]:
+        """[(kind, published indices)] of consecutive layers of one kind."""
+        out: list[list] = []
+        for kind, lid in zip(self.mixers, self.layer_ids):
+            if out and out[-1][0] == kind:
+                out[-1][1].append(lid)
+            else:
+                out.append([kind, [lid]])
+        return [(k, tuple(ids)) for k, ids in out]
+
+    def slopes(self, layer_id: int) -> np.ndarray:
+        """Lightning Attention's decay rates of one layer, a head each."""
+        h = self.lightning_heads
+        base = 2.0 ** (-8.0 * (np.arange(h) + 1) / h)
+        return (base * (1.0 - layer_id / (self.published_depth - 1) + 1e-5)
+                ).astype(np.float32)
+
+    def sel_width(self) -> int:
+        """Blocks a sparse decode step gathers at most: the selection
+        (init + window + topk) or a whole dense context, whichever is
+        more."""
+        window = -(-self.window_size // self.block_size) + 1
+        return max(self.init_blocks + window + self.topk,
+                   -(-self.dense_len // self.block_size))
+
+    def attended_tokens(self, contexts) -> np.ndarray:
+        """Tokens a sparse layer's query attends when its context holds
+        ``contexts`` tokens (itself included), from the geometry alone."""
+        n = np.asarray(contexts, np.int64)
+        bs = self.block_size
+        t = n - 1
+        w0 = np.maximum(t - self.window_size + 1, 0) // bs
+        init = np.minimum(self.init_blocks, w0)
+        picked = np.minimum(self.topk, w0 - init)
+        sparse = (init + picked) * bs + (n - w0 * bs)
+        return np.where(n > self.dense_len, sparse, n)
+
+    def state_bytes(self, batch: int) -> int:
+        """Bytes of recurrent state ``batch`` rows hold."""
+        n = sum(1 for m in self.mixers if m == LINEAR)
+        return 4 * n * batch * self.lightning_heads * self.lightning_head_dim ** 2
+
+    def init_cache(self, batch: int) -> dict:
+        """Zeroed cache for ``batch`` rows of ``max_decode_len`` tokens."""
+        if self.max_decode_len <= 0:
+            raise ValueError("decode=True needs max_decode_len > 0")
+        tm = self.max_decode_len
+        if tm % self.block_size:
+            raise ValueError(
+                f"cache length {tm} must be a multiple of the selection's "
+                f"block_size {self.block_size}")
+        nk = max(1, (tm - self.kernel_size) // self.kernel_stride + 1)
+        cache: dict = {}
+        if self.decode_per_row:
+            cache["cursors"] = jnp.zeros((batch,), jnp.int32)
+        else:
+            cache["cursor"] = jnp.zeros((), jnp.int32)
+            cache["valid"] = jnp.full((), _NO_LIMIT, jnp.int32)
+        for r, (kind, ids) in enumerate(self.runs()):
+            n = len(ids)
+            if kind == SPARSE:
+                kv = (n, batch, tm, self.num_kv_heads, self.head_dim)
+                cache[f"run{r}"] = {
+                    "cached_k": jnp.zeros(kv, self.dtype),
+                    "cached_v": jnp.zeros(kv, self.dtype),
+                    "comp_k": jnp.zeros((n, batch, nk) + kv[3:],
+                                        jnp.float32)}
+            else:
+                d = self.lightning_head_dim
+                cache[f"run{r}"] = {"state": jnp.zeros(
+                    (n, batch, self.lightning_heads, d, d), jnp.float32)}
+        return cache
+
+    def decode_apply(self, params, cache, tokens, paged=None):
+        return hybrid_apply(self, params, cache, tokens, paged=paged)
+
+
+# -- pieces ----------------------------------------------------------------
+
+def _rms(x, scale, eps, dtype):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(dtype)
+
+
+def _proj(x, w):
+    return jnp.einsum("btd,dhk->bthk", x, w)
+
+
+def block_scores(p, model: HybridLM, nblocks: int):
+    """[..., NK] pooled-key scores -> [..., nblocks]: a block's score is
+    the largest among the pooled keys that overlap it (absent: 0)."""
+    r = model.block_size // model.kernel_stride
+    back = model.kernel_size // model.kernel_stride - 1
+    need = back + r * nblocks
+    pad = [(0, 0)] * (p.ndim - 1) + [(back, max(0, need - back - p.shape[-1]))]
+    pp = jnp.pad(p, pad)[..., :need]
+    out = pp[..., 0::r][..., :nblocks]
+    for o in range(1, r + back):
+        out = jnp.maximum(out, pp[..., o::r][..., :nblocks])
+    return out
+
+
+def _pooled_scores(model: HybridLM, q, comp_k, pos):
+    """Block scores of the queries ``q`` [B, T, K, G, d] at positions
+    ``pos`` [B, T] against the pooled keys ``comp_k`` [B, NK, K, d]:
+    softmax over the pooled keys that end at or before the query, summed
+    over the group's heads, max over a block's pooled keys. Float32 at
+    full precision: the scores decide a discrete choice."""
+    d = q.shape[-1]
+    nk = comp_k.shape[1]
+    ends = (model.kernel_stride * jnp.arange(nk) + model.kernel_size - 1)
+    ok = ends[None, None, :] <= pos[:, :, None]                  # [B, T, NK]
+    ls = jnp.einsum("btkgd,bjkd->bkgtj", q.astype(jnp.float32), comp_k,
+                    precision=_HI) / (d ** 0.5)
+    ok = ok[:, None, None]
+    ls = jnp.where(ok, ls, -jnp.inf)
+    m = jnp.max(ls, axis=-1, keepdims=True)
+    e = jnp.where(ok, jnp.exp(ls - jnp.where(jnp.isfinite(m), m, 0.0)), 0.0)
+    den = jnp.sum(e, axis=-1, keepdims=True)
+    return jnp.sum(e / jnp.where(den > 0, den, 1.0), axis=2)     # [B,K,T,NK]
+
+
+def _select(model: HybridLM, score, pos):
+    """[B, K, T, NB] block scores, [B, T] positions -> bool [B, K, T, NB]:
+    the blocks each query attends (`selected_blocks` of the reference)."""
+    bs = model.block_size
+    nb = score.shape[-1]
+    b = jnp.arange(nb)
+    t = pos[:, None, :, None]
+    mine = t // bs
+    w0 = jnp.maximum(t - model.window_size + 1, 0) // bs
+    forced = (b < model.init_blocks) | (b >= w0)
+    others = ~forced
+    k = min(model.topk, nb)
+    _vals, best = jax.lax.top_k(jnp.where(others, score, -jnp.inf), k)
+    picked = jnp.any(best[..., None] == b, axis=-2) & others
+    sparse = (forced | picked) & (b <= mine)
+    return jnp.where(t + 1 > model.dense_len, sparse, b <= mine)
+
+
+def _pool_update(model: HybridLM, comp_k, k_cache, p0, t, valid):
+    """Write into ``comp_k`` [B, NK, K, d] the pooled keys whose span ends
+    inside [p0, p0 + t) and before ``valid``, from the keys just cached
+    (``k_cache`` [B, Tm, K, d]); ``p0`` is [B]."""
+    ks, st = model.kernel_size, model.kernel_stride
+    nk = comp_k.shape[1]
+    n = -(-t // st)
+    jlo = jnp.maximum(-((ks - 1 - p0) // st), 0)                 # ceil
+    j = jlo[:, None] + jnp.arange(n)[None, :]                    # [B, n]
+    end = st * j + ks - 1
+    done = (end < p0[:, None] + t) & (end < valid) & (j < nk)
+    idx = jnp.clip(st * j[:, :, None] + jnp.arange(ks), 0,
+                   k_cache.shape[1] - 1)                         # [B, n, ks]
+    rows = jnp.arange(comp_k.shape[0])
+    span = k_cache[rows[:, None, None], idx].astype(jnp.float32)
+    pooled = jnp.mean(span, axis=2)                              # [B,n,K,d]
+    return comp_k.at[rows[:, None], jnp.where(done, j, nk)].set(
+        pooled, mode="drop")
+
+
+def _sparse_decode(model: HybridLM, q, kc, vc, comp_k, pos):
+    """One query a row (``q`` [B, 1, K, G, d], ``pos`` [B, 1]): select
+    blocks, gather them, attend. Reads the selected blocks only."""
+    b, _t, kvh, g, d = q.shape
+    bs = model.block_size
+    nb = kc.shape[1] // bs
+    score = block_scores(_pooled_scores(model, q, comp_k, pos), model, nb)
+    sel = _select(model, score, pos)[:, :, 0]                    # [B, K, NB]
+    width = min(model.sel_width(), nb)
+    if width < nb:
+        # the selected blocks first, in order: a stable sort of "not chosen"
+        idx = jnp.argsort(~sel, axis=-1, stable=True)[..., :width]
+        live = jnp.take_along_axis(sel, idx, axis=-1)
+    else:
+        idx = jnp.broadcast_to(jnp.arange(nb), sel.shape)
+        live = sel
+    rows = jnp.arange(b)[:, None, None]
+
+    def gather(cache):
+        blocks = cache.reshape(b, nb, bs, kvh, d)[rows, idx]  # [B,K,W,bs,K,d]
+        return jnp.stack([blocks[:, h, :, :, h] for h in range(kvh)], 1)
+
+    kb, vb = gather(kc), gather(vc)                          # [B,K,W,bs,d]
+    tok = idx[..., None] * bs + jnp.arange(bs)               # [B,K,W,bs]
+    mask = live[..., None] & (tok <= pos[:, :, None, None])
+    s = jnp.einsum("bkgd,bkwsd->bkgws", q[:, 0].astype(jnp.float32),
+                   kb.astype(jnp.float32)) / (d ** 0.5)
+    s = jnp.where(mask[:, :, None], s, -jnp.inf)
+    w = jax.nn.softmax(s.reshape(b, kvh, g, -1), axis=-1)
+    out = jnp.einsum("bkgn,bknd->bkgd", w,
+                     vb.reshape(b, kvh, -1, d).astype(jnp.float32))
+    return out[:, None]                                      # [B,1,K,G,d]
+
+
+def _sparse_chunk(model: HybridLM, q, kc, vc, comp_k, pos):
+    """A chunk of queries that share their positions (``q`` [B, T, K, G,
+    d], ``pos`` [B, T], every row at the same positions): block selection a
+    query, then attention over the cache in tiles of keys with a running
+    softmax. Only the tiles that hold a key at or before the chunk's last
+    position are visited."""
+    b, t, kvh, g, d = q.shape
+    bs = model.block_size
+    tm = kc.shape[1]
+    nb = tm // bs
+    sel = _select(model, block_scores(
+        _pooled_scores(model, q, comp_k, pos), model, nb), pos)  # [B,K,T,NB]
+    per = max(m for m in range(1, _KEY_TILE // bs + 1) if nb % m == 0)
+    tile = per * bs
+    qf = q.astype(jnp.float32)
+    last = pos[0, -1]
+
+    def body(i, carry):
+        m, l, acc = carry
+        k_t = jax.lax.dynamic_slice_in_dim(kc, i * tile, tile, axis=1)
+        v_t = jax.lax.dynamic_slice_in_dim(vc, i * tile, tile, axis=1)
+        sel_t = jax.lax.dynamic_slice_in_dim(sel, i * per, per, axis=3)
+        tok = i * tile + jnp.arange(tile)
+        mask = (jnp.repeat(sel_t, bs, axis=-1)
+                & (tok[None, None, None, :] <= pos[:, None, :, None]))
+        s = jnp.einsum("btkgd,bskd->bkgts", qf,
+                       k_t.astype(jnp.float32)) / (d ** 0.5)
+        s = jnp.where(mask[:, :, None], s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(s - safe[..., None])
+        scale = jnp.exp(m - safe)            # 0 while nothing was seen
+        l = l * scale + jnp.sum(p, axis=-1)
+        acc = acc * scale[..., None] + jnp.einsum(
+            "bkgts,bskd->bkgtd", p, v_t.astype(jnp.float32))
+        return m_new, l, acc
+
+    init = (jnp.full((b, kvh, g, t), -jnp.inf, jnp.float32),
+            jnp.zeros((b, kvh, g, t), jnp.float32),
+            jnp.zeros((b, kvh, g, t, d), jnp.float32))
+    _m, l, acc = jax.lax.fori_loop(0, last // tile + 1, body, init)
+    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    return jnp.transpose(out, (0, 3, 1, 2, 4))               # [B,T,K,G,d]
+
+
+def _linear_block(q, k, v, m, state, slope):
+    """One sub-block of the decayed recurrence: ``q``/``k``/``v`` [B, C, H,
+    d] float32, ``m`` [B, C] (1 = a real token), ``state`` [B, H, d, d].
+    Padding neither decays the state nor adds to it."""
+    c = jnp.cumsum(m, axis=1)                                    # [B, C]
+    s = slope[None, :, None]                                     # [1, H, 1]
+    dist = c[:, :, None] - c[:, None, :]                         # [B, C, C]
+    tri = (jnp.arange(q.shape[1])[:, None] >= jnp.arange(q.shape[1])[None])
+    keep = tri[None] & (m[:, None, :] > 0)
+    decay = jnp.where(keep[:, None], jnp.exp(
+        -slope[None, :, None, None] * jnp.maximum(dist, 0)[:, None]), 0.0)
+    a = jnp.einsum("bihd,bjhd->bhij", q, k, precision=_HI) * decay
+    intra = jnp.einsum("bhij,bjhd->bihd", a, v, precision=_HI)
+    qd = q * jnp.exp(-s * c[:, None, :]).transpose(0, 2, 1)[..., None]
+    inter = jnp.einsum("bihd,bhde->bihe", qd, state, precision=_HI)
+    total = c[:, -1]                                             # [B]
+    kd = k * (m[:, None, :] * jnp.exp(
+        -s * (total[:, None, None] - c[:, None, :]))
+              ).transpose(0, 2, 1)[..., None]
+    state = (state * jnp.exp(-s * total[:, None, None])[..., None]
+             + jnp.einsum("bjhd,bjhe->bhde", kd, v, precision=_HI))
+    return intra + inter, state
+
+
+def _linear_mix(q, k, v, m, state, slope):
+    """The recurrence over [B, T, H, d] in sub-blocks of `_LIN_BLOCK`."""
+    b, t, h, d = q.shape
+    if t <= _LIN_BLOCK:
+        return _linear_block(q, k, v, m, state, slope)
+    n = -(-t // _LIN_BLOCK)
+    pad = n * _LIN_BLOCK - t
+
+    def cut(x):
+        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        return jnp.moveaxis(
+            x.reshape((b, n, _LIN_BLOCK) + x.shape[2:]), 1, 0)
+
+    def body(state, xs):
+        o, state = _linear_block(*xs, state, slope)
+        return state, o
+
+    state, o = jax.lax.scan(body, state, (cut(q), cut(k), cut(v), cut(m)))
+    o = jnp.moveaxis(o, 0, 1).reshape(b, n * _LIN_BLOCK, h, d)
+    return o[:, :t], state
+
+
+# -- the stack -------------------------------------------------------------
+
+def _mlp(model: HybridLM, p, x):
+    hn = _rms(x, p["ln2"], model.eps, model.dtype)
+    act = jax.nn.silu(hn @ p["wg"]) * (hn @ p["wu"])
+    return act @ p["wd"]
+
+
+def _write_kv(cache_leaf, new, p0, per_row: bool):
+    """Cache ``new`` [B, T, K, d] at each row's positions p0.. ."""
+    new = new.astype(cache_leaf.dtype)
+    if per_row:
+        rows = jnp.arange(new.shape[0])[:, None]
+        slot = jnp.clip(p0[:, None] + jnp.arange(new.shape[1])[None, :], 0,
+                        cache_leaf.shape[1] - 1)
+        return cache_leaf.at[rows, slot].set(new)
+    return jax.lax.dynamic_update_slice(cache_leaf, new, (0, p0[0], 0, 0))
+
+
+def _qkv_gate(model: HybridLM, p, x):
+    """Both mixers' way in: normed q and k, v, the float32 output gate."""
+    hn = _rms(x, p["ln1"], model.eps, model.dtype)
+    q = _rms(_proj(hn, p["wq"]), p["qn"], model.eps, model.dtype)
+    k = _rms(_proj(hn, p["wk"]), p["kn"], model.eps, model.dtype)
+    gate = jax.nn.sigmoid(_proj(hn, p["wz"]).astype(jnp.float32))
+    return q, k, _proj(hn, p["wv"]), gate
+
+
+def _sparse_layer(model: HybridLM, p, c, x, pos, valid):
+    b, t, _ = x.shape
+    kvh, g = model.num_kv_heads, model.num_heads // model.num_kv_heads
+    q, k, v, gate = _qkv_gate(model, p, x)
+    p0 = pos[:, 0]
+    kc = _write_kv(c["cached_k"], k, p0, model.decode_per_row)
+    vc = _write_kv(c["cached_v"], v, p0, model.decode_per_row)
+    comp_k = _pool_update(model, c["comp_k"], kc, p0, t, valid)
+    q5 = q.reshape(b, t, kvh, g, model.head_dim)
+    if t == 1:
+        o = _sparse_decode(model, q5, kc, vc, comp_k, pos)
+    elif model.decode_per_row:
+        raise UnsupportedStack(
+            "a block-sparse layer takes one token a row a step, or a chunk "
+            "of one row (speculative verification is not supported)")
+    else:
+        o = _sparse_chunk(model, q5, kc, vc, comp_k, pos)
+    o = (o.reshape(b, t, model.num_heads, model.head_dim) * gate
+         ).astype(model.dtype)
+    out = jnp.einsum("bthk,hkd->btd", o, p["wo"])
+    return out, {"cached_k": kc, "cached_v": vc, "comp_k": comp_k}
+
+
+def _linear_layer(model: HybridLM, p, c, slope, x, pos, valid):
+    d = model.lightning_head_dim
+    q, k, v, gate = _qkv_gate(model, p, x)
+    posf = pos.astype(jnp.float32)
+    q = rope(q, base=model.rope_theta, positions=posf).astype(jnp.float32)
+    k = rope(k, base=model.rope_theta, positions=posf).astype(jnp.float32)
+    m = (pos < valid).astype(jnp.float32)
+    o, state = _linear_mix(q, k, v.astype(jnp.float32), m, c["state"], slope)
+    o = _rms(o / (d ** 0.5), p["on"], model.eps, jnp.float32) * gate
+    out = jnp.einsum("bthk,hkd->btd", o.astype(model.dtype), p["wo"])
+    return out, {"state": state}
+
+
+def hybrid_apply(model: HybridLM, params, cache, tokens, paged=None):
+    """One decode or prefill step: (float32 logits [B, T, vocab], new
+    cache), the contract of `models.transformer.decode_apply`."""
+    if paged is not None:
+        raise UnsupportedStack("a hybrid stack has no paged attention path")
+    if not model.decode:
+        raise ValueError("HybridLM runs in decode mode only "
+                         "(engine.generate.decode_model)")
+    b, t = tokens.shape
+    if model.decode_per_row:
+        p0 = cache["cursors"]
+        valid = jnp.int32(_NO_LIMIT)
+    else:
+        p0 = jnp.broadcast_to(cache["cursor"], (b,))
+        valid = cache["valid"]
+    pos = p0[:, None] + jnp.arange(t)[None, :]
+    a = model.scale_depth / model.published_depth ** 0.5
+    x = (params["embed"][tokens] * model.scale_emb).astype(model.dtype)
+    new_cache = dict(cache)
+    for r, (kind, ids) in enumerate(model.runs()):
+        def body(h, layer, kind=kind):
+            p_l, c_l, slope = layer
+            if kind == SPARSE:
+                mix, c_l = _sparse_layer(model, p_l, c_l, h, pos, valid)
+            else:
+                mix, c_l = _linear_layer(model, p_l, c_l, slope, h, pos,
+                                         valid)
+            h = h + (a * mix).astype(model.dtype)
+            h = h + (a * _mlp(model, p_l, h)).astype(model.dtype)
+            return h, c_l
+
+        slopes = jnp.asarray(np.stack([model.slopes(i) for i in ids]))
+        x, new_cache[f"run{r}"] = jax.lax.scan(
+            body, x, (params["runs"][r], cache[f"run{r}"], slopes))
+    if not model.decode_per_row:
+        new_cache["cursor"] = cache["cursor"] + t
+    hn = _rms(x, params["norm_f"], model.eps, jnp.float32) / model.logit_div
+    logits = hn.astype(model.dtype) @ params["head"]
+    return logits.astype(jnp.float32), new_cache

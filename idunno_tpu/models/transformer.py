@@ -591,7 +591,12 @@ def scanned_apply(model: TransformerLM, params, cache, tokens, paged=None):
 def decode_apply(model: TransformerLM, params, cache, tokens, paged=None):
     """THE decode-step entry point: dispatches on ``model.scan_layers``
     so callers (`engine.serve_lm`, `engine.generate`) are layout-blind.
-    Returns ``(float32 logits, new cache)``."""
+    Returns ``(float32 logits, new cache)``. A model that is no
+    `TransformerLM` (`models/hybrid.py`: layers of several kinds, caches
+    of several shapes) brings its own step as ``model.decode_apply``."""
+    own = getattr(model, "decode_apply", None)
+    if own is not None:
+        return own(params, cache, tokens, paged=paged)
     if getattr(model, "scan_layers", False):
         return scanned_apply(model, params, cache, tokens, paged=paged)
     if paged is not None:

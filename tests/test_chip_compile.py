@@ -164,3 +164,53 @@ def test_block_write_updates_the_store_in_place(one_chip, store, row, dtype,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == mem.output_size_in_bytes
     assert mem.temp_size_in_bytes < mem.output_size_in_bytes // 4
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+def test_hybrid_stack_steps_at_published_widths(one_chip, which):
+    """A sparse and a linear layer of `models/hybrid.py` at MiniCPM-SALA's
+    published widths: the decode step over the benchmark's 16 slots x 16896
+    (top-k, the gather of selected blocks, the state update) and a
+    2048-token prefill chunk of a 16384-token row (the selection mask over
+    key tiles with a running softmax) pass the chip's compiler and fit its
+    memory. Plain XLA: no custom call is looked for."""
+    import dataclasses
+
+    from idunno_tpu.models.hybrid import LINEAR, SPARSE, HybridLM
+
+    dt = jnp.bfloat16
+    model = HybridLM(
+        vocab=73448, dim=4096, mlp_dim=16384, mixers=(SPARSE, LINEAR),
+        layer_ids=(9, 10), published_depth=32, num_heads=32, num_kv_heads=2,
+        head_dim=128, lightning_heads=32, lightning_head_dim=128,
+        scale_emb=12.0, scale_depth=1.4, logit_div=16.0, dtype=dt,
+        param_dtype=dt)
+
+    def sds(shape, dtype=dt):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(heads):
+        return {"ln1": sds((1, 4096)), "wq": sds((1, 4096, 32, 128)),
+                "wk": sds((1, 4096, heads, 128)),
+                "wv": sds((1, 4096, heads, 128)), "qn": sds((1, 128)),
+                "kn": sds((1, 128)), "wz": sds((1, 4096, 32, 128)),
+                "wo": sds((1, 32, 128, 4096)), "ln2": sds((1, 4096)),
+                "wg": sds((1, 4096, 16384)), "wu": sds((1, 4096, 16384)),
+                "wd": sds((1, 16384, 4096))}
+    params = {"embed": sds((73448, 4096)),
+              "runs": (layer(2), dict(layer(32), on=sds((1, 32, 128)))),
+              "norm_f": sds((4096,)), "head": sds((4096, 73448))}
+    if which == "decode":
+        dec = dataclasses.replace(model, decode=True, decode_per_row=True,
+                                  max_decode_len=16896)
+        rows, tokens = 16, 1
+    else:
+        dec = dataclasses.replace(model, decode=True, max_decode_len=16384)
+        rows, tokens = 1, 2048
+    cache = jax.tree.map(lambda s: sds(s.shape, s.dtype),
+                         jax.eval_shape(lambda: dec.init_cache(rows)))
+    compiled = jax.jit(dec.decode_apply).lower(
+        params, cache, sds((rows, tokens), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 12e9
