@@ -192,6 +192,11 @@ class Completion:
         return (self.t_last - self.t_first) / later
 
 
+# `run`'s arguments that a TPU dispatch donates (`_build_decode`): tokens,
+# cache, cursors, remaining, keys, logprobs, counts
+_DECODE_DONATED = (1, 2, 3, 4, 8, 9, 12)
+
+
 def _set_cursors(cache: Any, cursors: jnp.ndarray) -> Any:
     """Overwrite every per-layer ``cursors`` leaf with the server's single
     source of truth (the layers never disagree; per-row cursors are
@@ -1067,6 +1072,9 @@ class DecodeServer:
         return self._per_row_decode(self.model)
 
     def _build_decode(self, n_steps: int):
+        """The decode dispatch (`jit_run`): ``n_steps`` tokens for every
+        slot, a `fori_loop` of (model step, fused sampling tail) over the
+        donated decode state."""
         dec = self._dec
         track = self.track_logprobs     # static: traced once
         pen = self.penalties            # static: traced once
@@ -1091,8 +1099,8 @@ class DecodeServer:
                 cache = _set_cursors(cache, cursors)
                 tok = jnp.take_along_axis(tokens, cursors[:, None], axis=1)
                 # decode_apply: the scanned step (one lax.scan over the
-                # stacked layers) on scan-compatible pools, the flax
-                # per-layer loop otherwise
+                # stacked layers, the cache its carry) on scan-compatible
+                # pools, the flax per-layer loop otherwise
                 logits, cache = decode_apply(dec, params, cache, tok,
                                              paged=ctx)
                 # the whole post-model tail — penalties, sampling pick,
@@ -1113,14 +1121,17 @@ class DecodeServer:
                 (tokens, cache, cursors, remaining, keys, logprobs,
                  counts))
 
-        # donate the decode state (tokens/cache/cursors/remaining/keys/
-        # logprobs): the KV cache is by far the largest buffer and every
-        # step returns a fresh one — donation lets XLA update it in place
-        # instead of copying it per dispatch. (CPU doesn't implement
-        # donation and would warn.) temps/top_ps/top_ks are read-only and
-        # not donated.
+        # donate the decode state: the slot cache is by far the largest
+        # buffer, and with the donation the dispatch updates it where it
+        # lies — the layer scan carries the stacked K/V and writes only the
+        # new tokens' rows (`models/transformer.py:scanned_apply`), and this
+        # `fori_loop` hands the same buffers from step to step, so no whole
+        # leaf is copied in, between or after the steps
+        # (`tests/test_chip_compile.py` holds the compiled program to
+        # that). (CPU doesn't implement donation and would warn.)
+        # temps/top_ps/top_ks are read-only and not donated.
         if jax.devices()[0].platform == "tpu":
-            return jax.jit(run, donate_argnums=(1, 2, 3, 4, 8, 9, 12))
+            return jax.jit(run, donate_argnums=_DECODE_DONATED)
         return jax.jit(run)
 
     def _build_spec_round(self, gamma: int, rounds: int = 1):

@@ -138,7 +138,7 @@ class MultiHeadAttention(nn.Module):
         return kv
 
     @nn.compact
-    def __call__(self, x, paged=None):
+    def __call__(self, x, paged=None, layer=None):
         b, t, _ = x.shape
         head_dim = self.dim // self.num_heads
         kv_heads = self._kv_heads
@@ -148,9 +148,10 @@ class MultiHeadAttention(nn.Module):
         k = dense(features=(kv_heads, head_dim), name="k")(x)
         v = dense(features=(kv_heads, head_dim), name="v")(x)
         if self.decode:
-            return self._decode_step(q, k, v, paged=paged)
-        if paged is not None:
-            raise ValueError("paged KV attention is a decode-mode feature")
+            return self._decode_step(q, k, v, paged=paged, layer=layer)
+        if paged is not None or layer is not None:
+            raise ValueError("paged KV attention and a depth-stacked cache "
+                             "are decode-mode features")
         if self.use_rope:
             q, k = rope(q), rope(k)
         if kv_heads != self.num_heads:
@@ -167,7 +168,7 @@ class MultiHeadAttention(nn.Module):
                                param_dtype=self.param_dtype,
                                name="out")(out)
 
-    def _decode_step(self, q, k, v, paged=None):
+    def _decode_step(self, q, k, v, paged=None, layer=None):
         """Autoregressive serving against the KV cache — three shapes:
 
         scalar cursor, t=1: one token in, one out (``engine.generate``);
@@ -193,7 +194,16 @@ class MultiHeadAttention(nn.Module):
         gather); the two normalized partials merge exactly via their
         log-sum-exps (`merge_attention`). A row's own chunk positions
         always sit beyond its paged region, so the local partial is
-        never empty; zero-length chains contribute weight exactly 0."""
+        never empty; zero-length chains contribute weight exactly 0.
+
+        ``layer`` (a traced index; `scanned_apply`) says the K/V (and
+        scale) variables are the DEPTH-STACKED leaves ``[L, B, T, kv, d]``
+        the layer scan carries: this layer writes its new tokens' rows at
+        ``(layer, row, position)`` of the stacked leaf, in place, and
+        attends over its slice of that leaf read where it lies — the
+        slice is never a value the scan hands back. The arithmetic is the
+        same either way: write, then attend over the cache that holds the
+        new token."""
         if self.max_decode_len <= 0:
             raise ValueError("decode=True needs max_decode_len > 0")
         if not self.causal:
@@ -228,6 +238,11 @@ class MultiHeadAttention(nn.Module):
             s = jnp.maximum(jnp.abs(xf).max(axis=-1) / 127.0, 1e-8)
             vals = jnp.clip(jnp.round(xf / s[..., None]), -127, 127)
             return vals.astype(jnp.int8), s
+
+        def at(*idx):
+            """Index of this layer's rows in a cache leaf."""
+            return idx if layer is None else (layer, *idx)
+
         if self.decode_per_row:
             cur = self.variable("cache", "cursors",
                                 lambda: jnp.zeros((b,), jnp.int32))
@@ -241,36 +256,19 @@ class MultiHeadAttention(nn.Module):
                 p = pos_bt.astype(jnp.float32)
                 q, k = rope(q, positions=p), rope(k, positions=p)
             slot = jnp.clip(pos_bt, 0, self.max_decode_len - 1)  # [B, t]
-            rows = jnp.arange(b)
-            if quant:
-                (k_st, k_sc), (v_st, v_sc) = q8(k), q8(v)
-            else:
-                k_st, v_st = k, v
-            # overflow gating happens on the VALUES before the scatter (an
-            # overflowing row re-writes its old cache entries — a no-op),
-            # never as a post-scatter jnp.where over the whole cache: that
-            # select would keep the pre-scatter cache live, forcing XLA to
-            # COPY the full [B, L, H, D] buffer every layer every decode
-            # step instead of scattering in place (the dominant cost of
-            # the 2026-07-31 capture's 6.8 ms decode step)
-            ovr_g = overflow[:, None, None, None]            # [B,1,1,1]
-            old_k = ck.value[rows[:, None], slot]            # [B,t,kv,d]
-            old_v = cv.value[rows[:, None], slot]
-            new_k = ck.value.at[rows[:, None], slot].set(
-                jnp.where(ovr_g, old_k, k_st))
-            new_v = cv.value.at[rows[:, None], slot].set(
-                jnp.where(ovr_g, old_v, v_st))
-            new_ks = new_vs = None
-            if quant:
-                ovr_s = overflow[:, None, None]
-                new_ks = ks.value.at[rows[:, None], slot].set(
-                    jnp.where(ovr_s, ks.value[rows[:, None], slot], k_sc))
-                new_vs = vs.value.at[rows[:, None], slot].set(
-                    jnp.where(ovr_s, vs.value[rows[:, None], slot], v_sc))
-            if not self.is_initializing():  # init returns a CLEAN cache;
-                ck.value, cv.value = new_k, new_v   # cursors: caller-owned
-                if quant:
-                    ks.value, vs.value = new_ks, new_vs
+            sel = at(jnp.arange(b)[:, None], slot)
+
+            def put(var, vals):
+                # overflow gating happens on the VALUES before the scatter
+                # (an overflowing row re-writes its old cache entries — a
+                # no-op), never as a post-scatter jnp.where over the whole
+                # cache: that select would keep the pre-scatter cache live,
+                # forcing XLA to COPY the full buffer every layer every
+                # decode step instead of scattering in place
+                ovr = overflow.reshape((b,) + (1,) * (vals.ndim - 1))
+                return var.value.at[sel].set(
+                    jnp.where(ovr, var.value[sel], vals))
+            nxt = i          # read, never advanced: the caller owns them
             # [B, 1, t, T]: row r's chunk position j attends slots ≤ i[r]+j
             ax = jnp.arange(self.max_decode_len)[None, None, :]
             live = ax <= pos_bt[:, :, None]
@@ -289,37 +287,21 @@ class MultiHeadAttention(nn.Module):
             overflow = i + t > self.max_decode_len
             if self.use_rope:
                 q, k = rope(q, positions=pos), rope(k, positions=pos)
-            if quant:
-                (k_st, k_sc), (v_st, v_sc) = q8(k), q8(v)
-            else:
-                k_st, v_st = k, v
-            # same value-gating as the per-row branch: on overflow the
-            # update writes back the OLD slice (dynamic_slice/-update
-            # clamp the start identically, so the round-trip is a no-op)
-            # instead of post-selecting over the whole cache, which would
-            # block the in-place update and copy the full buffer
-            old_k = jax.lax.dynamic_slice(ck.value, (0, i, 0, 0),
-                                          k_st.shape)
-            old_v = jax.lax.dynamic_slice(cv.value, (0, i, 0, 0),
-                                          v_st.shape)
-            new_k = jax.lax.dynamic_update_slice(
-                ck.value, jnp.where(overflow, old_k, k_st), (0, i, 0, 0))
-            new_v = jax.lax.dynamic_update_slice(
-                cv.value, jnp.where(overflow, old_v, v_st), (0, i, 0, 0))
-            new_ks = new_vs = None
-            if quant:
-                old_ks = jax.lax.dynamic_slice(ks.value, (0, i, 0),
-                                               k_sc.shape)
-                old_vs = jax.lax.dynamic_slice(vs.value, (0, i, 0),
-                                               v_sc.shape)
-                new_ks = jax.lax.dynamic_update_slice(
-                    ks.value, jnp.where(overflow, old_ks, k_sc), (0, i, 0))
-                new_vs = jax.lax.dynamic_update_slice(
-                    vs.value, jnp.where(overflow, old_vs, v_sc), (0, i, 0))
-            if not self.is_initializing():  # init must return a CLEAN cache
-                ck.value, cv.value, cur.value = new_k, new_v, i + t
-                if quant:
-                    ks.value, vs.value = new_ks, new_vs
+
+            def put(var, vals):
+                # same value-gating as the per-row branch: on overflow the
+                # update writes back the OLD slice (dynamic_slice/-update
+                # clamp the start identically, so the round-trip is a
+                # no-op) instead of post-selecting over the whole cache,
+                # which would block the in-place update and copy the
+                # full buffer
+                start = at(0, i) + (0,) * (vals.ndim - 2)
+                if layer is not None:
+                    vals = vals[None]
+                old = jax.lax.dynamic_slice(var.value, start, vals.shape)
+                return jax.lax.dynamic_update_slice(
+                    var.value, jnp.where(overflow, old, vals), start)
+            nxt = i + t
             # [q, T]: chunk position j attends cache slots ≤ i + j
             ax = jnp.arange(self.max_decode_len)[None, :]
             live = ax <= (i + jnp.arange(t))[:, None]
@@ -329,14 +311,31 @@ class MultiHeadAttention(nn.Module):
                           & (ax < paged.start + paged.lengths[0]))
             mask = live[None, None, :, :]
             poison = overflow
+        if quant:
+            (k_st, k_sc), (v_st, v_sc) = q8(k), q8(v)
+            written = [(ck, k_st), (cv, v_st), (ks, k_sc), (vs, v_sc)]
+        else:
+            written = [(ck, k), (cv, v)]
+        new = [put(var, vals) for var, vals in written]
+        if not self.is_initializing():      # init must return a CLEAN cache
+            for (var, _), leaf in zip(written, new):
+                var.value = leaf
+            cur.value = nxt
+        if layer is not None:
+            # the layer's [B, T, ...] slice of the carried leaf, read where
+            # it lies: no copy of it is a value of the scan
+            new = [jax.lax.dynamic_index_in_dim(leaf, layer, 0,
+                                                keepdims=False)
+                   for leaf in new]
+        new_k, new_v, *scales = new
         # grouped attention against the (possibly narrower) cache: query
         # heads reshape to [.., kv_heads, group, d] so the einsum reads
         # the small cache straight from HBM — no repeat materialization.
         # group == 1 is exact MHA (identical contraction).
         group = h // kv_heads
         if quant:
-            new_k = new_k.astype(jnp.float32) * new_ks[..., None]
-            new_v = new_v.astype(jnp.float32) * new_vs[..., None]
+            new_k = new_k.astype(jnp.float32) * scales[0][..., None]
+            new_v = new_v.astype(jnp.float32) * scales[1][..., None]
         q5 = q.reshape(b, t, kv_heads, group, d)
         # f32 casts on the operands: they FUSE into the dot reads (HBM
         # traffic stays at the cache's stored width), and XLA:CPU's
@@ -401,7 +400,7 @@ class Block(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x, paged=None):
+    def __call__(self, x, paged=None, layer=None):
         ln = partial(nn.LayerNorm, dtype=self.dtype,
                      param_dtype=self.param_dtype)
         x = x + MultiHeadAttention(
@@ -413,7 +412,7 @@ class Block(nn.Module):
             kv_cache_dtype=self.kv_cache_dtype,
             dtype=self.dtype,
             param_dtype=self.param_dtype, name="attn")(
-                ln(name="ln1")(x), paged=paged)
+                ln(name="ln1")(x), paged=paged, layer=layer)
         h_in = ln(name="ln2")(x)
         if self.ffn_factory is not None:
             return x + self.ffn_factory(
@@ -535,15 +534,24 @@ def stack_block_params(params, depth: int):
 
 def scanned_apply(model: TransformerLM, params, cache, tokens, paged=None):
     """One decode/prefill step of a ``scan_layers=True`` model: embed →
-    `lax.scan` of `Block.apply` over the depth-stacked (params, cache) →
-    final norm → logits. Returns ``(float32 logits, new cache)`` — the
-    same contract as ``model.apply(..., mutable=["cache"])`` unpacked,
-    with the cache's leading axis the layer index.
+    `lax.scan` of `Block.apply` over the depth-stacked params → final norm
+    → logits. Returns ``(float32 logits, new cache)`` — the same contract
+    as ``model.apply(..., mutable=["cache"])`` unpacked, with the cache's
+    leading axis the layer index.
+
+    The K/V leaves (K, V and, under int8, their scales) ride through the
+    scan as CARRY, whole and depth-stacked: a layer writes only its new
+    tokens' rows into them, in place, and reads its own slice where it
+    lies (`MultiHeadAttention._decode_step`, ``layer``). As the scan's
+    ``xs``/``ys`` they were sliced out and written back whole, every
+    layer of every step — more device time than the weight stream at 28
+    slots x 4096 (PERF.md §6, PR 30). The cursor leaves are a few
+    integers a layer and stay ``xs``/``ys``.
 
     ``paged`` carries depth-stacked page stores (``[L, N, bs, ...]``,
     `engine.kv_blocks.KVBlockPool.kv_pages`); the scan slices each
-    layer's page array alongside its params/cache slice, so the block
-    pool is read in place — never gathered."""
+    layer's page array alongside its params slice, so the block pool is
+    read in place — never gathered."""
     blk = Block(model.dim, model.num_heads,
                 num_kv_heads=model.num_kv_heads,
                 causal=model.causal,
@@ -558,34 +566,33 @@ def scanned_apply(model: TransformerLM, params, cache, tokens, paged=None):
     x = nn.Embed(model.vocab, model.dim, dtype=model.dtype,
                  param_dtype=model.param_dtype).apply(
         {"params": params["embed"]}, tokens)
+    cursors = {k: v for k, v in cache["attn"].items()
+               if k in ("cursor", "cursors")}
+    kv = {k: v for k, v in cache["attn"].items() if k not in cursors}
+    pages = None if paged is None else (
+        paged.k_pages, paged.v_pages, paged.k_scale_pages,
+        paged.v_scale_pages)
 
-    if paged is None:
-        def body(h, layer):
-            p_l, c_l = layer
-            h, mut = blk.apply({"params": p_l, "cache": c_l}, h,
-                               mutable=["cache"])
-            return h, mut["cache"]
+    def body(carry, layer):
+        h, kv = carry
+        i_l, p_l, cur_l, pages_l = layer
+        h, mut = blk.apply(
+            {"params": p_l, "cache": {"attn": {**kv, **cur_l}}}, h,
+            paged=None if paged is None else paged.layer(*pages_l),
+            layer=i_l, mutable=["cache"])
+        new = mut["cache"]["attn"]
+        return ((h, {k: new[k] for k in kv}),
+                {k: new[k] for k in cur_l})
 
-        x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
-    else:
-        pages = (paged.k_pages, paged.v_pages,
-                 paged.k_scale_pages, paged.v_scale_pages)
-
-        def body(h, layer):
-            p_l, c_l, (kp, vp, ksp, vsp) = layer
-            h, mut = blk.apply({"params": p_l, "cache": c_l}, h,
-                               paged=paged.layer(kp, vp, ksp, vsp),
-                               mutable=["cache"])
-            return h, mut["cache"]
-
-        x, new_cache = jax.lax.scan(
-            body, x, (params["blocks"], cache, pages))
+    (x, kv), cursors = jax.lax.scan(
+        body, (x, kv),
+        (jnp.arange(model.depth), params["blocks"], cursors, pages))
     x = nn.LayerNorm(dtype=model.dtype, param_dtype=model.param_dtype
                      ).apply({"params": params["ln_f"]}, x)
     logits = nn.Dense(model.vocab, dtype=model.dtype,
                       param_dtype=model.param_dtype).apply(
         {"params": params["head"]}, x)
-    return logits.astype(jnp.float32), new_cache
+    return logits.astype(jnp.float32), {"attn": {**kv, **cursors}}
 
 
 def decode_apply(model: TransformerLM, params, cache, tokens, paged=None):

@@ -14,14 +14,20 @@ touches `topologies`, libtpu or a TPU device while any module is imported —
 the driver's six xdist workers each import this file, and only the worker
 that RUNS it may load the library. All such compiles live in this ONE file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from idunno_tpu.engine.generate import init_cache
 from idunno_tpu.engine.kv_blocks import _WRITE_GROUP, _write_block
+from idunno_tpu.engine.serve_lm import _DECODE_DONATED, DecodeServer
+from idunno_tpu.models.transformer import (TransformerLM, decode_apply,
+                                           stack_block_params)
 from idunno_tpu.ops.flash_attention import flash_attention
 from idunno_tpu.ops.paged_attention import paged_attention_grouped
 from idunno_tpu.ops.pallas_preprocess import preprocess_batch_pallas
@@ -214,3 +220,191 @@ def test_hybrid_stack_steps_at_published_widths(one_chip, which):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < 12e9
+
+
+# -- the decode programs over the slot cache, at the benchmark's widths ------
+
+_SLOTS, _MAX_LEN, _VOCAB = 28, 4096, 49152
+_WIDTHS = {"3b": {"dim": 3072, "depth": 30, "heads": 24, "kv": 2},
+           "7b": {"dim": 4608, "depth": 16, "heads": 36, "kv": 4}}
+
+
+def _nbytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def _pool_and_shapes(widths: str, quant: bool, mesh=None):
+    """A toy pool with the benchmark's head counts, its decode twin widened
+    to the benchmark's widths AFTER the build (`_build_decode` reads
+    ``_dec`` when called), and the shapes of the wide parameters and slot
+    cache: nothing of the real size is ever allocated here."""
+    w = _WIDTHS[widths]
+    dt = jnp.bfloat16
+    toy = TransformerLM(vocab=64, dim=w["heads"] * 8, depth=1,
+                        num_heads=w["heads"], num_kv_heads=w["kv"],
+                        dtype=dt, param_dtype=dt,
+                        kv_cache_dtype="int8" if quant else "native")
+    params = toy.init(jax.random.PRNGKey(0),
+                      jnp.zeros((1, 8), jnp.int32))["params"]
+    srv = DecodeServer(toy, params, slots=_SLOTS, prompt_len=64,
+                       max_len=_MAX_LEN, decode_steps=4, mesh=mesh)
+    srv._dec = dataclasses.replace(srv._dec, vocab=_VOCAB, dim=w["dim"],
+                                   depth=w["depth"])
+    flat = dataclasses.replace(srv._dec, scan_layers=False, decode=False)
+    p_shapes = jax.eval_shape(lambda: stack_block_params(
+        flat.init(jax.random.PRNGKey(0),
+                  jnp.zeros((1, 8), jnp.int32))["params"], w["depth"]))
+    return srv, p_shapes
+
+
+def _described(tree, shardings):
+    """The shapes of ``tree`` on described devices: one sharding for every
+    leaf, or a tree of them."""
+    if isinstance(shardings, jax.sharding.Sharding):
+        one = shardings
+        shardings = jax.tree.map(lambda _: one, tree)
+    return jax.tree.map(lambda x, sh: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sh), tree, shardings)
+
+
+def _compile_run(srv, params, cache, state_shardings):
+    """`DecodeServer._build_decode`'s own `run` (4 steps in a `fori_loop`
+    around the layer scan) with the donation a TPU pool gives it, lowered
+    over the wide shapes and the toy pool's own sampling state."""
+    state = _described(
+        (srv._tokens, srv._cursors, srv._remaining, srv._temps,
+         srv._top_ps, srv._top_ks, srv._keys, srv._logprobs, srv._pres,
+         srv._freq, srv._counts), state_shardings)
+    run = srv._build_decode(4).__wrapped__
+    return jax.jit(run, donate_argnums=_DECODE_DONATED).lower(
+        params, state[0], cache, *state[1:]).compile()
+
+
+def _whole_slice_updates(text: str, rows: int, length: int) -> list[str]:
+    """The `dynamic-update-slice`s of a compiled program, fused or bare,
+    whose update is a whole ``[rows, length, ...]`` slice of a cache leaf
+    (leading 1s aside)."""
+    shapes = dict(re.findall(r"%([\w.\-]+) = \(?(\w+\[[\d,]*\])", text))
+    found = []
+    for m in re.finditer(r"%([\w.\-]+) = \S+ dynamic-update-slice\("
+                         r"(?:[^\s%]\S* )?%[\w.\-]+, "
+                         r"(?:[^\s%]\S* )?%([\w.\-]+)",
+                         text):
+        dims = [int(d) for d in re.findall(r"\d+", shapes.get(
+            m.group(2), "").partition("[")[2])]
+        while dims and dims[0] == 1:
+            dims.pop(0)
+        whole = [rows, length] if rows > 1 else [length]
+        if dims[:len(whole)] == whole:
+            found.append(f"{m.group(1)} <- {shapes[m.group(2)]}")
+    return found
+
+
+def _in_place(compiled, cache_bytes: int, rows: int, temp_share=4):
+    """What PR 30 holds a decode program to: the new cache IS the donated
+    one, the program's own memory holds no second cache (``temp_share``
+    None: a batch-1 chunk, whose scores outweigh its cache), and no
+    layer's whole slice is written back."""
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    if temp_share:
+        assert mem.temp_size_in_bytes < cache_bytes // temp_share
+    assert not _whole_slice_updates(compiled.as_text(), rows, _MAX_LEN)
+
+
+# s8 leaves with 2 kv heads: the chip lays the argument out with the
+# token axis minor (a tile holds 32 rows of a byte each: 2 heads would
+# pad 16 times) and the loop with the heads minor, so the dispatch copies
+# each leaf in and out: 4.2 GB of temporaries. The parent does not
+# compile there at all (20.8 GB). PERF.md section 7; strict, so that the
+# cure shows
+_INT8_2KV = pytest.mark.xfail(
+    strict=True, reason="int8 leaves with 2 kv heads are re-laid out at "
+    "the dispatch's two ends (PERF.md section 7)")
+
+
+@pytest.mark.parametrize("widths,quant", [
+    ("3b", False), ("7b", False),
+    pytest.param("3b", True, marks=_INT8_2KV), ("7b", True),
+], ids=["3b-native", "7b-native", "3b-int8", "7b-int8"])
+def test_decode_dispatch_updates_the_slot_cache_in_place(one_chip, widths,
+                                                         quant):
+    """The whole `jit_run` as `DecodeServer._build_decode` makes it (4
+    steps in a `fori_loop` around the layer scan, with its donation), at
+    28 slots x 4096."""
+    srv, p_shapes = _pool_and_shapes(widths, quant)
+    cache = _described(jax.eval_shape(
+        lambda: init_cache(srv._dec, _SLOTS, _MAX_LEN)), one_chip)
+    compiled = _compile_run(srv, _described(p_shapes, one_chip), cache,
+                            one_chip)
+    _in_place(compiled, _nbytes(cache), _SLOTS)
+
+
+@pytest.mark.parametrize("widths,quant,rows,tokens", [
+    ("3b", False, _SLOTS, 4), ("7b", False, _SLOTS, 4),
+    pytest.param("3b", True, _SLOTS, 4, marks=_INT8_2KV),
+    ("7b", True, _SLOTS, 4),
+    ("3b", False, 1, 1024), ("7b", False, 1, 1024),
+    ("3b", True, 1, 1024), ("7b", True, 1, 1024),
+], ids=["3b-native-chunk4", "7b-native-chunk4", "3b-int8-chunk4",
+        "7b-int8-chunk4", "3b-native-prefill1024", "7b-native-prefill1024",
+        "3b-int8-prefill1024", "7b-int8-prefill1024"])
+def test_chunked_steps_update_the_cache_in_place(one_chip, widths, quant,
+                                                 rows, tokens):
+    """`decode_apply` with more than one token a row: the per-row chunk of
+    4 over the pool's slots (speculative verification) and the
+    scalar-cursor 1024-token chunk of a 4096-token row (chunked
+    prefill)."""
+    srv, p_shapes = _pool_and_shapes(widths, quant)
+    dec = dataclasses.replace(srv._dec, decode_per_row=rows > 1)
+    cache = _described(jax.eval_shape(
+        lambda: init_cache(dec, rows, _MAX_LEN)), one_chip)
+    compiled = jax.jit(
+        lambda p, c, t: decode_apply(dec, p, c, t),
+        donate_argnums=(1,)).lower(
+        _described(p_shapes, one_chip), cache,
+        jax.ShapeDtypeStruct((rows, tokens), jnp.int32,
+                             sharding=one_chip)).compile()
+    _in_place(compiled, _nbytes(cache), rows,
+              temp_share=4 if rows > 1 else None)
+
+
+# what the parent of PR 30 compiled to on this very case: the two
+# all-reduces a layer (attention out, MLP down) inside the scan; the rest
+# is the embedding's and the sharded sampling tail's
+_TP_COLLECTIVES = {"all-reduce": 6, "all-gather": 4, "all-to-all": 0,
+                   "collective-permute": 0, "reduce-scatter": 0}
+
+
+def test_tp_decode_dispatch_in_place_and_no_new_collectives(topo):
+    """`jit_run` of the head-sharded pool (`n_model` 2) on two of the
+    described 2x2's chips, at the 7B widths: each chip updates its half of
+    the slot cache in place, and carrying the cache through the scan brings
+    no collective that the parent's program did not have: no all-gather of
+    a cache leaf."""
+    from idunno_tpu.parallel.mesh import make_mesh
+    from idunno_tpu.parallel.sharding import lm_cache_specs, lm_tp_specs
+
+    srv, p_shapes = _pool_and_shapes(
+        "7b", False, mesh=make_mesh(1, 2, devices=jax.devices()[:2]))
+    mesh = make_mesh(1, 2, devices=list(topo.devices)[:2])
+
+    def placed(tree, specs):
+        return _described(tree, jax.tree.map(
+            lambda sp: NamedSharding(mesh, sp), specs))
+    c_shapes = jax.eval_shape(
+        lambda: init_cache(srv._dec, _SLOTS, _MAX_LEN))
+    cache = placed(c_shapes, lm_cache_specs(c_shapes, n_model=2))
+    params = placed(p_shapes, lm_tp_specs(p_shapes, n_model=2))
+    # the pool spreads its sampling state over the data axis, which has
+    # one chip here: whole on both
+    compiled = _compile_run(srv, params, cache,
+                            NamedSharding(mesh, PartitionSpec()))
+    # memory_analysis counts one device: half of the cache
+    _in_place(compiled, _nbytes(cache) // 2, _SLOTS)
+    text = compiled.as_text()
+    counts = {op: len(re.findall(rf" {op}(?:-start)?\(", text))
+              for op in _TP_COLLECTIVES}
+    assert counts == _TP_COLLECTIVES, counts
+    assert not [line for line in text.splitlines()
+                if " all-gather" in line and str(_MAX_LEN) in line]

@@ -249,3 +249,155 @@ def test_tp_sharded_tail_one_scan_no_sort_depth_invariant():
     assert ar_counts[2] > 0, "TP step must contain model-axis reductions"
     assert ar_counts[2] == ar_counts[4], \
         f"collective count grew with depth: {ar_counts}"
+
+
+# -- the layer scan carries the cache: what a step writes, and only that -----
+
+_DEPTH, _MAX, _BS = 3, 24, 4
+# (rows, tokens a row, cursors): the per-row step, the per-row chunk
+# (speculative verification), the scalar-cursor chunk (chunked prefill)
+_SHAPES = {"per-row-step": (3, 1, (9, 13, 8)),
+           "per-row-chunk": (3, 4, (9, 13, 8)),
+           "scalar-chunk": (1, 5, 9)}
+
+
+def _carried_case(shape: str, quant: bool, paged: bool, cursors=None):
+    """(decode twin, stacked params, a cache full of noise with its cursors
+    set, tokens, paged context or None)."""
+    from idunno_tpu.ops.paged_attention import PagedContext
+
+    rows, t, cur = _SHAPES[shape]
+    cur = cur if cursors is None else cursors
+    model = TransformerLM(vocab=VOCAB, dim=32, depth=_DEPTH, num_heads=4,
+                          num_kv_heads=2,
+                          kv_cache_dtype="int8" if quant else "native")
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    dec = dataclasses.replace(decode_model(model, _MAX), scan_layers=True,
+                              decode_per_row=rows > 1)
+    keys = iter(jax.random.split(jax.random.PRNGKey(7), 16))
+
+    def noise(leaf):
+        if leaf.dtype == jnp.int32:                       # the cursors
+            return jnp.broadcast_to(jnp.asarray(cur, jnp.int32), leaf.shape)
+        if leaf.dtype == jnp.int8:
+            return jax.random.randint(next(keys), leaf.shape, -127, 128,
+                                      jnp.int32).astype(jnp.int8)
+        x = jax.random.normal(next(keys), leaf.shape, jnp.float32)
+        return jnp.abs(x) / 64 if leaf.ndim == 4 else x   # scales: 4-D
+    cache = jax.tree.map(noise, init_cache(dec, rows, _MAX))
+    ctx = None
+    if paged:
+        store = cache["attn"]
+        pages = {k: jax.tree.map(noise, jnp.zeros(
+            (_DEPTH, 6, _BS) + v.shape[3:], v.dtype))
+            for k, v in store.items() if v.ndim >= 4}
+        ctx = PagedContext(
+            pages["cached_k"], pages["cached_v"],
+            jnp.asarray([[1, 4], [2, 0], [0, 0]], jnp.int32)[:rows],
+            jnp.asarray([8, 4, 0], jnp.int32)[:rows],
+            k_scale_pages=pages.get("k_scale"),
+            v_scale_pages=pages.get("v_scale"), start=0, kernel="xla")
+    tok = jax.random.randint(jax.random.PRNGKey(3), (rows, t), 0, VOCAB)
+    return dec, stack_block_params(params, _DEPTH), cache, tok, ctx
+
+
+def _per_layer_loop(dec, params, cache, tok, ctx):
+    """The same step, one `Block.apply` a layer over that layer's own
+    slice of parameters, cache and pages: what the scan did before it
+    carried the cache, unrolled."""
+    import flax.linen as nn
+    from idunno_tpu.models.transformer import Block
+
+    blk = Block(dec.dim, dec.num_heads, num_kv_heads=dec.num_kv_heads,
+                decode=True, max_decode_len=dec.max_decode_len,
+                decode_per_row=dec.decode_per_row,
+                kv_cache_dtype=dec.kv_cache_dtype)
+    h = nn.Embed(dec.vocab, dec.dim).apply({"params": params["embed"]}, tok)
+    layers = []
+    for i in range(dec.depth):
+        def at(tree):
+            return jax.tree.map(lambda x: x[i], tree)
+        pg = None if ctx is None else ctx.layer(
+            ctx.k_pages[i], ctx.v_pages[i], at(ctx.k_scale_pages),
+            at(ctx.v_scale_pages))
+        h, mut = blk.apply({"params": at(params["blocks"]),
+                            "cache": at(cache)}, h, paged=pg,
+                           mutable=["cache"])
+        layers.append(mut["cache"])
+    h = nn.LayerNorm().apply({"params": params["ln_f"]}, h)
+    logits = nn.Dense(dec.vocab).apply({"params": params["head"]}, h)
+    return logits, jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+
+
+def _written(shape: str, leaf_shape, cursors=None) -> np.ndarray:
+    """Mask over a K/V or scale leaf [L, rows, T, ...] of the positions a
+    step writes: every layer, each row's cursor .. cursor + t - 1."""
+    rows, t, cur = _SHAPES[shape]
+    cur = np.broadcast_to(np.asarray(cur if cursors is None else cursors),
+                          (rows,))
+    mask = np.zeros(leaf_shape, bool)
+    for r in range(rows):
+        if cur[r] + t <= _MAX:                  # an overflowing row: none
+            mask[:, r, cur[r]:cur[r] + t] = True
+    return mask
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged-xla"])
+@pytest.mark.parametrize("quant", [False, True], ids=["native", "int8"])
+@pytest.mark.parametrize("shape", list(_SHAPES))
+def test_scanned_step_writes_only_the_new_rows(shape, quant, paged):
+    """The scan that carries the stacked cache gives the per-layer loop's
+    logits, leaves every byte of the cache as it was but the new tokens'
+    rows at (layer, row, position), and puts there what the loop puts."""
+    dec, params, cache, tok, ctx = _carried_case(shape, quant, paged)
+    want_logits, want = _per_layer_loop(dec, params, cache, tok, ctx)
+    logits, new = jax.jit(
+        lambda p, c, t, g: decode_apply(dec, p, c, t, paged=g))(
+        params, cache, tok, ctx)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits),
+                               rtol=1e-5, atol=1e-5)
+    assert jax.tree.structure(new) == jax.tree.structure(cache)
+    for name, before in cache["attn"].items():
+        after, ref = np.asarray(new["attn"][name]), np.asarray(
+            want["attn"][name])
+        before = np.asarray(before)
+        if before.dtype == np.int32:            # cursor(s)
+            np.testing.assert_array_equal(after, ref)
+            continue
+        mask = _written(shape, before.shape)
+        assert mask.any() and after.dtype == before.dtype
+        np.testing.assert_array_equal(after[~mask], before[~mask])
+        assert (after[mask] != before[mask]).any()
+        # an int8 value may round the other way where the scan body's
+        # fusion moved the float by an ulp
+        np.testing.assert_allclose(
+            after[mask].astype(np.float32), ref[mask].astype(np.float32),
+            rtol=1e-5, atol=1.0 if before.dtype == np.int8 else 1e-5)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["native", "int8"])
+@pytest.mark.parametrize("shape,cursors", [
+    ("per-row-step", (_MAX, 13, 8)), ("per-row-chunk", (_MAX - 3, 13, 8)),
+    ("scalar-chunk", _MAX - 4)])
+def test_overflow_leaves_the_carried_cache_untouched(shape, cursors, quant):
+    """A row whose chunk would run past ``max_len``: none of its cache rows
+    change, in any layer, and its scores are poisoned; the other rows
+    write and answer as ever."""
+    dec, params, cache, tok, _ = _carried_case(shape, quant, False,
+                                               cursors=cursors)
+    logits, new = decode_apply(dec, params, cache, tok)
+    over = (np.broadcast_to(np.asarray(cursors), (tok.shape[0],))
+            + tok.shape[1]) > _MAX
+    assert over.any()
+    assert np.isnan(np.asarray(logits)[over]).all()
+    assert np.isfinite(np.asarray(logits)[~over]).all()
+    for name, before in cache["attn"].items():
+        if before.dtype == jnp.int32:
+            continue
+        after, before = np.asarray(new["attn"][name]), np.asarray(before)
+        mask = _written(shape, before.shape, cursors)
+        np.testing.assert_array_equal(after[~mask], before[~mask])
+        assert not mask[:, over].any()
+        if (~over).any():
+            assert (after[mask] != before[mask]).any()
