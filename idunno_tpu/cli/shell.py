@@ -61,8 +61,7 @@ HELP = """\
         auto-resumed on another node if its host dies)
   train-status <name> | train-stop <name>
   lm-serve <name> <prompt_len> <max_len> [k=v ...]  continuous-batching pool
-       (slots decode_steps quantize=int8 eos_id=N draft=<lm> draft_len=N;
-        draft pools: greedy token-exact, sampled distribution-exact;
+       (slots decode_steps quantize=int8 eos_id=N;
         place=1 = cluster-managed: master-placed, requests journaled to
         the standby, pool+requests recovered if its node dies)
   lm-submit <name> <max_new> [temperature= top_p= top_k=
@@ -454,27 +453,21 @@ class Shell:
                     "[slots= decode_steps= quantize=int8 "
                     "kv_cache_dtype=int8 eos_id=N logprobs=1 penalties=1 "
                     "prefix=7,2,19 kv_block_size=N kv_cache_blocks=N "
-                    "draft=<lm> draft_len=N place=1 reload=1 "
+                    "place=1 reload=1 "
                     "gateway=1 quota=tenant:rate:burst:weight[;...] "
                     "gw_queue=N]\n"
-                    "note: draft (speculative) pools serve greedy "
-                    "requests token-exact and sampled requests "
-                    "distribution-exact (speculative sampling); "
-                    "kv_block_size>0 enables the paged cross-request "
+                    "note: kv_block_size>0 enables the paged cross-request "
                     "prefix cache (token-exact, block-aligned hits); "
                     "gateway=1 puts the QoS admission gateway in front "
                     "(quota rate '-' = unlimited)")
         kv = self._kv(args[3:])
         payload = {k: int(kv.pop(k))
                    for k in ("slots", "decode_steps", "eos_id",
-                             "draft_len", "kv_block_size",
-                             "kv_cache_blocks") if k in kv}
+                             "kv_block_size", "kv_cache_blocks") if k in kv}
         if "quantize" in kv:
             payload["quantize"] = kv.pop("quantize")
         if "kv_cache_dtype" in kv:
             payload["kv_cache_dtype"] = kv.pop("kv_cache_dtype")
-        if "draft" in kv:
-            payload["draft"] = kv.pop("draft")
         if "place" in kv and kv.pop("place") not in ("0", "false", ""):
             # cluster-managed pool: the acting master places it on the
             # least-loaded node, journals requests, and recovers it (with
@@ -665,8 +658,6 @@ class Shell:
                     f"kv_cache={cfg['kv_cache_dtype']} "
                     f"weights={cfg['quantize']} "
                     f"decode_steps={cfg['decode_steps']}"
-                    + (f" draft_len={cfg['speculative_draft_len']}"
-                       if cfg["speculative_draft_len"] else "")
                     + (f" n_model={cfg['n_model']} "
                        f"tp_bytes/step={cfg['tp_collective_bytes']}"
                        if cfg.get("n_model", 1) > 1 else ""))

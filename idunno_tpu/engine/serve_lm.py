@@ -20,10 +20,7 @@ TPU-first structure (everything static-shape, three compiled programs):
              retired rows idle harmlessly (their writes are idempotent and
              gated out). ``decode_steps>1`` fuses N tokens into one
              dispatch with a `lax.fori_loop` (fewer host round-trips; the
-             trade is admission only happens at dispatch boundaries). On
-             a speculative pool the same knob fuses N draft+verify ROUNDS
-             per dispatch (up to N·(draft_len+1) tokens), stream-identical
-             to single-round dispatches.
+             trade is admission only happens at dispatch boundaries).
 
 The reference serves nothing autoregressive at all; this is the
 beyond-parity serving tier over the same engine/model machinery
@@ -54,10 +51,7 @@ from idunno_tpu.parallel.sharding import (sampling_collective_bytes,
 from idunno_tpu.ops.paged_attention import (PagedContext,
                                             resolve_paged_kernel)
 from idunno_tpu.ops.quantize import dequantize_tree, quantize_tree
-from idunno_tpu.ops.sampling import (filter_on as _filter_on,
-                                     filtered_probs, fused_decode_tail,
-                                     masked_sample_logits,
-                                     safe_log as _safe_log)
+from idunno_tpu.ops.sampling import fused_decode_tail, masked_sample_logits
 
 # slot default shared with the serving control plane (`serve/control.py`,
 # `serve/lm_manager.py`). 16 is the measured knee of the BENCH_SUITE=
@@ -380,10 +374,6 @@ def _prefill_chunk(model: TransformerLM, params: Any, cache: Any,
     return cache, logits
 
 
-# _safe_log/_filter_on live in `ops.sampling` (shared with the fused
-# decode tail and the spec round); imported above under their former names.
-
-
 def _next_token(logits: jnp.ndarray, temp: jnp.ndarray,
                 key: jnp.ndarray, top_p: jnp.ndarray,
                 top_k: jnp.ndarray) -> jnp.ndarray:
@@ -460,110 +450,6 @@ def _insert(tokens: jnp.ndarray, cache: Any, row_cache: Any,
     return tokens, _splice_rows(cache, row_cache, slot, stacked)
 
 
-@partial(jax.jit, static_argnames=("stacked",), donate_argnums=(0,))
-def _insert_cache(cache: Any, row_cache: Any, slot: jnp.ndarray,
-                  stacked: bool = False) -> Any:
-    """Cache-only splice (the draft model's prompt prefill — tokens were
-    already written by the target's `_insert`)."""
-    return _splice_rows(cache, row_cache, slot, stacked)
-
-
-def _fill_cand(proposals: jnp.ndarray, bonus: jnp.ndarray,
-               acc: jnp.ndarray) -> jnp.ndarray:
-    """[S, γ+1] candidate tokens from [S, γ] proposals: positions < acc
-    keep the (accepted) proposal, position acc carries the bonus token,
-    the rest are zero padding (never committed)."""
-    s, gamma = proposals.shape
-    jidx = jnp.arange(gamma + 1)[None, :]
-    props_pad = jnp.concatenate(
-        [proposals, jnp.zeros((s, 1), jnp.int32)], axis=1)
-    return jnp.where(jidx < acc[:, None], props_pad,
-                     jnp.where(jidx == acc[:, None], bonus[:, None], 0))
-
-
-def greedy_commit(proposals: jnp.ndarray,
-                  tpred: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Greedy-lane speculative commit: accept the longest prefix where the
-    proposal equals the target argmax; bonus = the target argmax at the
-    first miss. The committed stream is exactly the target's own greedy
-    sequence. ONE definition shared by `spec_commit` (its greedy lane) and
-    the all-greedy fast path in `DecodeServer._build_spec_round`, so the
-    two can never drift."""
-    gamma = proposals.shape[1]
-    ok = proposals == tpred[:, :gamma]                       # [S, γ]
-    acc = jnp.cumprod(ok.astype(jnp.int32), axis=1).sum(axis=1)
-    bonus = jnp.take_along_axis(tpred, acc[:, None], axis=1)[:, 0]
-    return _fill_cand(proposals, bonus, acc), acc
-
-
-def spec_commit(proposals: jnp.ndarray, qdist: jnp.ndarray,
-                pdist: jnp.ndarray, tpred: jnp.ndarray,
-                sampled: jnp.ndarray, u: jnp.ndarray,
-                resid_keys: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Speculative-decoding acceptance + commit math, standalone so its
-    distribution guarantee is testable without a model.
-
-    Greedy rows (``sampled[r]`` False): accept the longest prefix where
-    the proposal equals the target argmax, bonus = target argmax — the
-    committed stream is exactly the target's greedy sequence.
-
-    Sampled rows: standard speculative SAMPLING (Leviathan et al. 2023 /
-    Chen et al. 2023 rejection scheme): proposal j is accepted iff
-    ``u_j < p_j(x_j) / q_j(x_j)``; at the first rejection the bonus
-    draws from the residual ``max(p_j - q_j, 0)`` (normalized), and when
-    every proposal is accepted it draws from the target's ``p_{γ+1}``.
-    The committed tokens are then distributed EXACTLY as sampling the
-    target one token at a time — the sampled analogue of the greedy
-    exactness contract (the residual construction makes
-    P[token] = q·min(1, p/q) + (1-α)·resid = p for every token).
-
-    Shapes: proposals [S, γ] int32; qdist [S, γ, V] draft probabilities;
-    pdist [S, γ+1, V] target probabilities; tpred [S, γ+1] target argmax;
-    sampled [S] bool; u [S, γ] uniforms; resid_keys [S, 2] per-row keys.
-    Returns (cand [S, γ+1] int32 candidate tokens, acc [S] int32 accepted
-    proposal count); callers commit ``cand[:, :acc+1]``.
-    """
-    gamma = proposals.shape[1]
-    # greedy lane: the shared helper (row-wise identical to the previous
-    # merged formulation — cumprod/take/fill all commute with the per-row
-    # select below, and each row reads only its own lane)
-    cand_g, acc_g = greedy_commit(proposals, tpred)
-
-    # sampled lane: rejection acceptance per position
-    p_at = jnp.take_along_axis(pdist[:, :gamma], proposals[..., None],
-                               axis=2)[..., 0]               # [S, γ]
-    q_at = jnp.take_along_axis(qdist, proposals[..., None],
-                               axis=2)[..., 0]               # [S, γ]
-    ratio = p_at / jnp.maximum(q_at, 1e-20)
-    sampled_ok = u < ratio
-    acc_s = jnp.cumprod(sampled_ok.astype(jnp.int32),
-                        axis=1).sum(axis=1)                  # [S] 0..γ
-
-    # bonus token at the first non-accepted position: residual sampling.
-    # qdist zero-padded at position γ makes the all-accepted case fall out
-    # of the same formula (residual = p_{γ+1} - 0 = the target dist).
-    q_pad = jnp.concatenate([qdist, jnp.zeros_like(qdist[:, :1])], axis=1)
-    p_acc = jnp.take_along_axis(
-        pdist, acc_s[:, None, None], axis=1)[:, 0]           # [S, V]
-    q_acc = jnp.take_along_axis(
-        q_pad, acc_s[:, None, None], axis=1)[:, 0]           # [S, V]
-    resid = jnp.maximum(p_acc - q_acc, 0.0)
-    mass = resid.sum(axis=1, keepdims=True)
-    # p == q exactly → zero residual, but then rejection has probability
-    # 0 under exact arithmetic; guard float round-off by falling back to p
-    resid = jnp.where(mass > 1e-12, resid, p_acc)
-    bonus_sampled = jax.vmap(
-        lambda k, r: jax.random.categorical(
-            k, jnp.where(r > 0.0, jnp.log(jnp.maximum(r, 1e-38)),
-                         -jnp.inf)))(
-            resid_keys, resid).astype(jnp.int32)             # [S]
-    cand_s = _fill_cand(proposals, bonus_sampled, acc_s)
-
-    acc = jnp.where(sampled, acc_s, acc_g)
-    cand = jnp.where(sampled[:, None], cand_s, cand_g)
-    return cand, acc
-
-
 class DecodeServer:
     """Continuous-batching decode pool over a dense `TransformerLM`.
 
@@ -571,8 +457,7 @@ class DecodeServer:
     prompts are padded to the static ``prompt_len`` bucket (true lengths
     tracked exactly). Greedy requests match `generate(temperature=0)`
     token-for-token (the tests' exactness oracle); sampled requests draw
-    per-request seeded streams (and on speculative pools, the rejection
-    scheme keeps them distribution-exact vs the target).
+    per-request seeded streams.
 
     Usage::
 
@@ -588,8 +473,6 @@ class DecodeServer:
                  prompt_len: int, max_len: int, decode_steps: int = 1,
                  quantize: str = "none", eos_id: int | None = None,
                  mesh=None, n_model: int = 1,
-                 draft: tuple | None = None,
-                 draft_len: int = 4,
                  prompt_buckets: tuple[int, ...] | None = None,
                  track_logprobs: bool = False,
                  penalties: bool = False,
@@ -672,7 +555,7 @@ class DecodeServer:
         # cheap argument validation BEFORE any device allocation or
         # weight quantization: a bad prefix must fail in microseconds
         self.prefix = list(prefix) if prefix else None
-        self._prefix_cache = self._draft_prefix_cache = None
+        self._prefix_cache = None
         if self.prefix:
             for t in self.prefix:
                 if not 0 <= t < model.vocab:
@@ -682,32 +565,6 @@ class DecodeServer:
                 raise ValueError(
                     f"prefix of {len(self.prefix)} + prompt bucket "
                     f"{max(self.prompt_buckets)} exceeds max_len {max_len}")
-        if draft is not None:
-            # decode_steps on a speculative pool = draft+verify ROUNDS
-            # fused into one dispatch (each round commits 1..draft_len+1
-            # tokens per row) — the same host-round-trip amortization the
-            # plain path gets: a one-round dispatch pays the fixed
-            # dispatch latency once per handful of tokens.
-            if draft_len < 1:
-                raise ValueError(f"draft_len {draft_len} must be >= 1")
-            if not draft[0].causal:
-                raise ValueError("the draft model must be causal")
-            if draft[0].vocab != model.vocab:
-                raise ValueError(
-                    f"draft vocab {draft[0].vocab} != target {model.vocab}")
-            if model.ffn_factory is not None:
-                # routed-FFN logits depend on the batch COMPOSITION (expert
-                # capacity is proportional to tokens-per-apply, so a γ+1
-                # verify chunk routes differently than token-by-token
-                # decode) — the verify would silently diverge from the
-                # target's own greedy stream, breaking the exactness
-                # contract. The DRAFT may be anything: proposals are only
-                # guesses the dense target verifies.
-                raise ValueError(
-                    "speculative decoding requires a dense target "
-                    "(routed-FFN logits are batch-composition-dependent, "
-                    "so chunked verification is not equivalent to "
-                    "per-token decode)")
         if quantize == "int8":
             # decode re-reads every weight per step — int8 residency halves
             # that HBM traffic; dequant happens inside the jitted programs
@@ -723,15 +580,8 @@ class DecodeServer:
         self.track_logprobs = bool(track_logprobs)
         # compile-time flag for presence/frequency penalties (a [S, vocab]
         # generated-token count buffer + a scatter-add per step; zero cost
-        # when off). Speculative pools cannot honor them: a verify chunk's
-        # later positions would need counts that include tokens committed
-        # EARLIER in the same chunk, which depend on acceptance — a
-        # sequential dependency the parallel verify cannot express.
+        # when off)
         self.penalties = bool(penalties)
-        if self.penalties and draft is not None:
-            raise ValueError(
-                "penalties are not supported on speculative pools "
-                "(count-dependent logits break the parallel verify)")
         # scanned decode hot loop: every scan-compatible model (dense
         # blocks — `models.transformer.scan_compatible`) is converted to
         # the stacked layout here, INSIDE the server, so callers keep
@@ -759,7 +609,6 @@ class DecodeServer:
         self._recurrent = bool(getattr(model, "recurrent", False))
         if self._recurrent:
             refused = [what for what, asked in (
-                ("draft= (speculative decoding)", draft is not None),
                 ("n_model > 1 or mesh= (no sharding specs)",
                  n_model != 1 or mesh is not None),
                 ("paged_kernel=", paged_kernel is not None),
@@ -787,21 +636,6 @@ class DecodeServer:
 
         self._dec = self._per_row_decode(model, max_len)
         self._prefill_model = model
-
-        # speculative decoding: a cheap draft proposes draft_len tokens per
-        # round, the target verifies them all in ONE chunked apply; greedy
-        # rows commit EXACTLY the target's own greedy sequence, sampled
-        # rows commit tokens distributed exactly as target sampling
-        # (rejection scheme — `spec_commit`)
-        self.draft_len = draft_len
-        self._draft_model = self._draft_params = None
-        if draft is not None:
-            dm, dp = draft
-            if scan_compatible(dm) and not getattr(dm, "scan_layers",
-                                                   False):
-                dm = dataclasses.replace(dm, scan_layers=True)
-                dp = stack_block_params(dp, dm.depth)
-            self._draft_model, self._draft_params = dm, dp
 
         # mesh sharding: the pool's slot dimension spreads over the mesh's
         # data axis (every per-row decode op is elementwise over slots, so
@@ -942,46 +776,6 @@ class DecodeServer:
         self._counts = (zeros((slots, model.vocab), jnp.int32)
                         if self.penalties
                         else jnp.zeros((slots, 0), jnp.int32))
-        self._draft_cache = None
-        if self._draft_model is not None:
-            ddec = self._per_row_decode(self._draft_model)
-            dshapes = jax.eval_shape(
-                lambda: init_cache(ddec, slots, max_len))
-            dstacked = bool(getattr(self._draft_model, "scan_layers",
-                                    False))
-            # the draft TP-shards only when its own Q heads divide the
-            # model axis (no hard error: a tiny replicated draft is fine)
-            draft_tp = (self.n_model > 1 and dstacked and
-                        self._draft_model.num_heads % self.n_model == 0)
-            if draft_tp:
-                from jax.sharding import NamedSharding
-                from idunno_tpu.parallel.sharding import (lm_cache_specs,
-                                                          lm_tp_specs)
-                dkvh = (getattr(self._draft_model, "num_kv_heads", None)
-                        or self._draft_model.num_heads)
-                dkv_shard = dkvh % self.n_model == 0
-                dspec = lm_cache_specs(dshapes, n_model=self.n_model,
-                                       kv_shard=dkv_shard)
-                self._draft_cache = jax.tree.map(
-                    lambda s, sp: jax.jit(
-                        lambda: jnp.zeros(s.shape, s.dtype),
-                        out_shardings=NamedSharding(mesh, sp))(),
-                    dshapes, dspec)
-                pspec = lm_tp_specs(self._draft_params,
-                                    n_model=self.n_model,
-                                    kv_shard=dkv_shard)
-                self._draft_params = jax.tree.map(
-                    lambda leaf, sp: jax.device_put(
-                        leaf, NamedSharding(mesh, sp)),
-                    self._draft_params, pspec)
-            else:
-                self._draft_cache = jax.tree.map(
-                    lambda s: zeros(s.shape, s.dtype, stacked=dstacked),
-                    dshapes)
-                if mesh is not None:
-                    from idunno_tpu.parallel.sharding import replicate
-                    self._draft_params = replicate(mesh, self._draft_params)
-
         # host state
         self._queue: deque[Request] = deque()
         self._live: dict[int, Request] = {}       # slot → request
@@ -1025,9 +819,6 @@ class DecodeServer:
         # completions are cold_start-tagged
         self._dispatched_ever = False
 
-        if self._draft_model is not None:
-            self._decode_spec = self._build_spec_round(draft_len,
-                                                       decode_steps)
         self._decode = self._build_decode(decode_steps)
 
         # shared-prefix cache (system prompt): the prefix is prefilled
@@ -1040,10 +831,6 @@ class DecodeServer:
             pl = len(self.prefix)
             self._prefix_cache, _ = _prefill(
                 self._prefill_model, self.params, pf, jnp.int32(pl), pl)
-            if self._draft_model is not None:
-                self._draft_prefix_cache, _ = _prefill(
-                    self._draft_model, self._draft_params, pf,
-                    jnp.int32(pl), pl)
 
         # paged KV block pool + radix tree over PER-REQUEST prompt
         # prefixes (the static prefix above is shared by construction
@@ -1063,8 +850,7 @@ class DecodeServer:
     def _per_row_decode(model: TransformerLM,
                         max_len: int = 0) -> TransformerLM:
         """The per-row-cursor decode twin of ``model`` (max_len 0 = leave
-        for `init_cache` to set) — single source for every decode-mode
-        replace (pool, draft cache, speculative round)."""
+        for `init_cache` to set)."""
         return dataclasses.replace(model, decode=True, decode_per_row=True,
                                    max_decode_len=max_len)
 
@@ -1134,236 +920,6 @@ class DecodeServer:
             return jax.jit(run, donate_argnums=_DECODE_DONATED)
         return jax.jit(run)
 
-    def _build_spec_round(self, gamma: int, rounds: int = 1):
-        """``rounds`` speculative rounds, all rows, one compiled program —
-        each round:
-
-          1. the draft runs ``gamma`` single-token steps → proposals
-             (greedy for temperature-0 rows; sampled from its own
-             temperature-scaled distribution for sampled rows);
-          2. the target verifies committed-last + all proposals in ONE
-             chunked per-row apply (γ+1 positions);
-          3. `spec_commit` accepts per row: greedy rows commit the longest
-             argmax-matching prefix plus the target's own next token
-             (stream EXACTLY the target's greedy sequence); sampled rows
-             run the standard rejection scheme, committing tokens whose
-             DISTRIBUTION is exactly the target's sampling distribution —
-             including under nucleus sampling: q and p are both the
-             FILTERED distributions, so the same residual math yields
-             exactly the target's nucleus-sampled stream.
-
-        Rejected positions leave stale K/V in both caches strictly past
-        the new cursors; they are overwritten when those positions are
-        genuinely ingested (the standard per-row-cursor invariant).
-
-        ``rounds`` > 1 chains that round body through a `lax.fori_loop`
-        so ONE dispatch advances every row by up to rounds·(γ+1) tokens —
-        the key-split chain, per-row gating, and commit math are byte-for-
-        byte the round-at-a-time logic, so streams are identical to
-        ``rounds`` separate dispatches (exactness tests hold across any
-        ``decode_steps``). Rows that retire mid-dispatch idle harmlessly:
-        their writes land strictly past their final cursor and their
-        carried state is fully gated on ``active``."""
-        dec = self._dec
-        ddec = self._per_row_decode(self._draft_model, self.max_len)
-        track = self.track_logprobs     # static: traced once
-
-        def run(params, dparams, tokens, cache, dcache, cursors,
-                remaining, temps, top_ps, top_ks, keys, logprobs,
-                tables=None, plens=None, pages=None):
-            params = dequantize_tree(params)
-            dparams = dequantize_tree(dparams)
-            # paged pool: only the TARGET verify attends through the
-            # block table — the draft keeps its own contiguous cache (it
-            # prefills the full prompt through its own weights, so its
-            # hit region is never zeroed)
-            ctx = (_make_paged_ctx(pages, tables, plens, self._pl_static,
-                                   self.paged_kernel,
-                                   self._paged_interpret)
-                   if self._paged else None)
-            s = tokens.shape[0]
-            rows = jnp.arange(s)
-            sampled = temps > 0.0                            # [S]
-            safe_t = jnp.maximum(temps, 1e-6)[:, None]
-
-            def round_body(carry):
-                (tokens, cache, dcache, cursors, remaining, keys,
-                 logprobs) = carry
-                active = remaining > 0
-                prev = jnp.take_along_axis(tokens, cursors[:, None],
-                                           axis=1)[:, 0]    # [S]
-                # sampling machinery (per-row key splits, the [S, γ, V]
-                # float32 draft-distribution carry, categorical draws, the
-                # [S, γ+1, V] target softmax, accept uniforms) runs only
-                # when a LIVE row actually samples — the all-greedy pool
-                # (the bench's constructed-ceiling point and the common
-                # serving case) skips all of it. Exactness mirrors the
-                # plain-decode fast path (`_build_decode`): with a sampled
-                # live row the branch is the byte-identical math as
-                # always; without one, greedy commits read only proposals/
-                # tpred, retired rows' state is fully gated on ``active``
-                # (their draft-cache writes land strictly past their final
-                # cursor), and frozen keys are harmless (a retired sampled
-                # row never draws again; admission re-seeds the slot).
-                any_sampling = jnp.any(active & sampled)
-
-                def draft_apply(dcache, dcur, tok):
-                    """One draft step shared by BOTH branches' loop bodies
-                    (cursor set, model apply, f32 logits) so the greedy
-                    fast path can never drift from the full path's model
-                    plumbing — only the sampling machinery around it is
-                    branch-local."""
-                    dcache = _set_cursors(dcache, dcur)
-                    logits, dcache = decode_apply(ddec, dparams, dcache,
-                                                  tok[:, None])
-                    return dcache, logits[:, 0].astype(
-                        jnp.float32)                         # [S, V]
-
-                # -- 1. draft: gamma proposals (+ full distributions and
-                # key bookkeeping only on the sampling branch) -------------
-                def draft_full():
-                    any_filter = jnp.any(active & sampled
-                                         & _filter_on(top_ps, top_ks))
-                    # per-row subkeys: γ draft draws + γ accept uniforms +
-                    # 1 residual/bonus draw + 1 carried-forward key
-                    subs = jax.vmap(
-                        lambda k: jax.random.split(k, 2 * gamma + 2))(
-                        keys)                                # [S, 2γ+2, 2]
-                    draft_keys = subs[:, :gamma]
-
-                    def dbody(j, carry):
-                        dcache, dcur, tok, props, qdist = carry
-                        dcache, l = draft_apply(dcache, dcur, tok)
-                        # per-row select inside the fast-path cond: an
-                        # unfiltered row's distribution is the plain
-                        # softmax in BOTH branches, so no row depends on
-                        # co-residents
-                        q = jax.lax.cond(
-                            any_filter,
-                            lambda: jnp.where(
-                                _filter_on(top_ps, top_ks)[:, None],
-                                filtered_probs(l / safe_t, top_ps, top_ks),
-                                jax.nn.softmax(l / safe_t, axis=-1)),
-                            lambda: jax.nn.softmax(l / safe_t, axis=-1))
-                        greedy = jnp.argmax(l, axis=-1).astype(jnp.int32)
-                        draw = jax.vmap(jax.random.categorical)(
-                            draft_keys[:, j],
-                            _safe_log(q)).astype(jnp.int32)
-                        nxt = jnp.where(sampled, draw, greedy)
-                        return (dcache, dcur + 1, nxt,
-                                props.at[:, j].set(nxt),
-                                qdist.at[:, j].set(q))
-
-                    props0 = jnp.zeros((s, gamma), jnp.int32)
-                    qdist0 = jnp.zeros((s, gamma, self.model.vocab),
-                                       jnp.float32)
-                    dc, _, _, proposals, qdist = jax.lax.fori_loop(
-                        0, gamma, dbody,
-                        (dcache, cursors, prev, props0, qdist0))
-                    return (dc, proposals, qdist,
-                            subs[:, gamma:2 * gamma],    # accept_keys
-                            subs[:, 2 * gamma],          # resid_keys
-                            subs[:, 2 * gamma + 1])      # new_keys
-
-                def draft_greedy():
-                    def dbody(j, carry):
-                        dcache, dcur, tok, props = carry
-                        dcache, l = draft_apply(dcache, dcur, tok)
-                        nxt = jnp.argmax(l, axis=-1).astype(jnp.int32)
-                        return (dcache, dcur + 1, nxt,
-                                props.at[:, j].set(nxt))
-
-                    props0 = jnp.zeros((s, gamma), jnp.int32)
-                    dc, _, _, proposals = jax.lax.fori_loop(
-                        0, gamma, dbody, (dcache, cursors, prev, props0))
-                    # the zero qdist/key stand-ins exist because cond
-                    # branches must return one pytree; the [S, γ, V] fill
-                    # is ~10 µs/round at bench shapes — accepted so the
-                    # BIG target-verify apply stays OUTSIDE the cond (one
-                    # cond spanning draft+verify+commit would compile the
-                    # verify body into both branches)
-                    return (dc, proposals,
-                            jnp.zeros((s, gamma, self.model.vocab),
-                                      jnp.float32),
-                            jnp.zeros((s, gamma) + keys.shape[1:],
-                                      keys.dtype),
-                            jnp.zeros_like(keys), keys)
-
-                (dcache, proposals, qdist, accept_keys, resid_keys,
-                 new_keys) = jax.lax.cond(any_sampling, draft_full,
-                                          draft_greedy)
-
-                # -- 2. target: verify the whole chunk in one apply ----------
-                cache = _set_cursors(cache, cursors)
-                tin = jnp.concatenate([prev[:, None], proposals], axis=1)
-                logits, cache = decode_apply(dec, params, cache, tin,
-                                             paged=ctx)
-                logits = logits.astype(jnp.float32)
-                tpred = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S,γ+1]
-
-                # -- 3. acceptance + commit (`spec_commit`; pure greedy
-                # prefix-match commit on the all-greedy branch) -------------
-                def commit_full():
-                    any_filter = jnp.any(active & sampled
-                                         & _filter_on(top_ps, top_ks))
-                    pdist = jax.lax.cond(
-                        any_filter,
-                        lambda: jnp.where(
-                            _filter_on(top_ps, top_ks)[:, None, None],
-                            filtered_probs(logits / safe_t[..., None],
-                                           top_ps[:, None], top_ks[:, None]),
-                            jax.nn.softmax(logits / safe_t[..., None],
-                                           axis=-1)),
-                        lambda: jax.nn.softmax(logits / safe_t[..., None],
-                                               axis=-1))
-                    u = jax.vmap(
-                        lambda ks: jax.vmap(jax.random.uniform)(ks))(
-                        accept_keys)                             # [S, γ]
-                    return spec_commit(proposals, qdist, pdist, tpred,
-                                       sampled, u, resid_keys)
-
-                # greedy branch: `greedy_commit` — the same function
-                # spec_commit's greedy lane calls, so the two cannot drift
-                cand, acc = jax.lax.cond(
-                    any_sampling, commit_full,
-                    lambda: greedy_commit(proposals, tpred))
-                jidx = jnp.arange(gamma + 1)[None, :]
-                commit = jnp.minimum(acc + 1, remaining)         # [S] ≥1 active
-                if self.eos_id is not None:
-                    hit = (cand == self.eos_id) & (jidx < commit[:, None])
-                    any_eos = hit.any(axis=1)
-                    eos_pos = jnp.argmax(hit, axis=1)
-                    commit = jnp.where(any_eos, eos_pos + 1, commit)
-                    rem_after = jnp.where(any_eos, 0, remaining - commit)
-                else:
-                    rem_after = remaining - commit
-                wpos = jnp.clip(cursors[:, None] + 1 + jidx, 0,
-                                self.max_len - 1)                # [S, γ+1]
-                old = jnp.take_along_axis(tokens, wpos, axis=1)
-                keep = (jidx < commit[:, None]) & active[:, None]
-                tokens = tokens.at[rows[:, None], wpos].set(
-                    jnp.where(keep, cand, old))
-                if track:
-                    lp_all = jax.nn.log_softmax(logits, axis=-1)
-                    lp_cand = jnp.take_along_axis(
-                        lp_all, cand[..., None], axis=-1)[..., 0]  # [S,γ+1]
-                    lp_old = jnp.take_along_axis(logprobs, wpos, axis=1)
-                    logprobs = logprobs.at[rows[:, None], wpos].set(
-                        jnp.where(keep, lp_cand, lp_old))
-                cursors = jnp.where(active, cursors + commit, cursors)
-                remaining = jnp.where(active, rem_after, remaining)
-                keys_out = jnp.where(active[:, None], new_keys, keys)
-                return (tokens, cache, dcache, cursors, remaining,
-                        keys_out, logprobs)
-            return jax.lax.fori_loop(
-                0, rounds, lambda _, c: round_body(c),
-                (tokens, cache, dcache, cursors, remaining, keys,
-                 logprobs))
-
-        if jax.devices()[0].platform == "tpu":
-            return jax.jit(run, donate_argnums=(2, 3, 4, 5, 6, 10, 11))
-        return jax.jit(run)
-
     # -- client surface ---------------------------------------------------
 
     def validate(self, tokens: list[int], max_new: int,
@@ -1386,15 +942,11 @@ class DecodeServer:
         if len(tokens) > self.prompt_len:
             raise ValueError(f"prompt of {len(tokens)} tokens exceeds the "
                              f"prompt_len bucket {self.prompt_len}")
-        headroom = (self.draft_len + 1 if self._draft_model is not None
-                    else 0)   # a verify chunk may overshoot the last token
         pl = len(self.prefix) if self.prefix else 0
-        if pl + len(tokens) + max_new + headroom > self.max_len:
+        if pl + len(tokens) + max_new > self.max_len:
             raise ValueError(
                 (f"{pl} prefix + " if pl else "")
                 + f"{len(tokens)} prompt + {max_new} new"
-                + (f" + {headroom} speculative headroom" if headroom
-                   else "")
                 + f" > max_len {self.max_len}")
         if max_new < 1:
             raise ValueError("max_new must be >= 1")
@@ -1535,12 +1087,11 @@ class DecodeServer:
                 + (1 if self._pending is not None else 0))
 
     def stats(self) -> dict:
-        """Serving counters: decode dispatches (``decode_steps`` tokens —
-        or, speculative, that many draft+verify rounds — per live row
-        each), requests admitted/completed, generated-token total,
+        """Serving counters: decode dispatches (``decode_steps`` tokens per
+        live row each), requests admitted/completed, generated-token total,
         current occupancy, and the pool's serving configuration (what an
         operator reading `lm_stats` needs to know the pool is actually
-        running — GQA width, cache dtype, weight quantization, draft)."""
+        running — GQA width, cache dtype, weight quantization)."""
         m = self.model
         config = {
             "vocab": m.vocab, "dim": m.dim, "depth": m.depth,
@@ -1553,9 +1104,6 @@ class DecodeServer:
             "prefix_len": len(self.prefix) if self.prefix else 0,
             "decode_steps": self.decode_steps,
             "prompt_len": self.prompt_len, "max_len": self.max_len,
-            "speculative_draft_len": (self.draft_len
-                                      if self._draft_model is not None
-                                      else None),
             "kv_block_size": self.kv_block_size,
             "paged_kernel": self.paged_kernel,
             "prefill_chunk": self.prefill_chunk,
@@ -2283,30 +1831,6 @@ class DecodeServer:
             self._seen_cursor[slot] = true_len
             self._child_span(span, "state.splice", t_splice,
                              state_bytes=self.model.state_bytes(1))
-        if self._draft_model is not None:
-            # the draft needs the FULL request prompt through ITS
-            # OWN weights (a radix hit only covers the target's
-            # cache; suffix-only applies just past the pool's shared
-            # static prefix)
-            dbucket = next(b for b in self.prompt_buckets
-                           if b >= suffix_true)
-            dsuffix = np.zeros((1, dbucket), np.int32)
-            dsuffix[0, :suffix_true] = per_req
-            if self.prefix:
-                drow, _ = _prefill_suffix(
-                    self._draft_model, self._draft_params,
-                    self._draft_prefix_cache, jnp.asarray(dsuffix),
-                    jnp.int32(suffix_true), len(self.prefix),
-                    dbucket)
-            else:
-                drow, _ = _prefill(
-                    self._draft_model, self._draft_params,
-                    jnp.asarray(dsuffix), jnp.int32(suffix_true),
-                    dbucket)
-            self._draft_cache = _insert_cache(
-                self._draft_cache, drow, jnp.int32(slot),
-                stacked=bool(getattr(self._draft_model, "scan_layers",
-                                     False)))
         self._cursors = self._cursors.at[slot].set(true_len)
         self._temps = self._temps.at[slot].set(temp)
         self._top_ps = self._top_ps.at[slot].set(topp)
@@ -2363,8 +1887,7 @@ class DecodeServer:
                  if req.stop}
         if not stops:
             return
-        bound = self.decode_steps * (
-            self.draft_len + 1 if self._draft_model is not None else 1)
+        bound = self.decode_steps
         cursors = self._remaining_cursors()[1]
         for slot, seqs in stops.items():
             gen_start = len(self._live[slot].tokens)
@@ -2392,8 +1915,7 @@ class DecodeServer:
 
     def step(self) -> int:
         """Retire finished rows, admit queued prompts into free slots, run
-        one decode dispatch (``decode_steps`` tokens — or speculative
-        rounds — for every live row).
+        one decode dispatch (``decode_steps`` tokens for every live row).
         Returns live rows + still-queued requests — 0 means drained (a
         max_new=1 admission can retire instantly, leaving 0 live rows with
         the queue non-empty, so live alone would end a client loop early)."""
@@ -2464,23 +1986,14 @@ class DecodeServer:
             with self._span("lm.decode_step", rows=rows) as sp:
                 for req in self._new_traced:
                     req.t_decode0 = sp.t_start
-                if self._draft_model is not None:
-                    (self._tokens, self._cache, self._draft_cache,
-                     self._cursors, self._remaining,
-                     self._keys, self._logprobs) = self._decode_spec(
-                        self.params, self._draft_params, self._tokens,
-                        self._cache, self._draft_cache, self._cursors,
-                        self._remaining, self._temps, self._top_ps,
-                        self._top_ks, self._keys, self._logprobs, *pg)
-                else:
-                    (self._tokens, self._cache, self._cursors,
-                     self._remaining, self._keys, self._logprobs,
-                     self._counts) = self._decode(
-                        self.params, self._tokens, self._cache,
-                        self._cursors, self._remaining, self._temps,
-                        self._top_ps, self._top_ks, self._keys,
-                        self._logprobs, self._pres, self._freq,
-                        self._counts, *pg)
+                (self._tokens, self._cache, self._cursors,
+                 self._remaining, self._keys, self._logprobs,
+                 self._counts) = self._decode(
+                    self.params, self._tokens, self._cache,
+                    self._cursors, self._remaining, self._temps,
+                    self._top_ps, self._top_ks, self._keys,
+                    self._logprobs, self._pres, self._freq,
+                    self._counts, *pg)
             self._stats["dispatches"] += 1
             self._dispatched_ever = True
             self._rc_invalidate()         # the dispatch advanced the rows
@@ -2558,11 +2071,9 @@ class DecodeServer:
         if self._queue or self._live:
             raise RuntimeError("warmup() needs an idle pool")
         toks = [t % self.model.vocab for t in (1, 2, 3)][:self.prompt_len]
-        headroom = (self.draft_len + 1 if self._draft_model is not None
-                    else 0)
         pl = len(self.prefix) if self.prefix else 0
         max_new = max(1, min(self.decode_steps + 1,
-                             self.max_len - pl - len(toks) - headroom))
+                             self.max_len - pl - len(toks)))
         t0 = time.perf_counter()
         self.submit(toks, max_new=max_new)
         self.run_until_drained()

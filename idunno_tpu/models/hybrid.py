@@ -443,7 +443,7 @@ def _sparse_layer(model: HybridLM, p, c, x, pos, valid):
     elif model.decode_per_row:
         raise UnsupportedStack(
             "a block-sparse layer takes one token a row a step, or a chunk "
-            "of one row (speculative verification is not supported)")
+            "of one row")
     else:
         o = _sparse_chunk(model, q5, kc, vc, comp_k, pos)
     o = (o.reshape(b, t, model.num_heads, model.head_dim) * gate

@@ -179,8 +179,8 @@ class MultiHeadAttention(nn.Module):
           BY THE CALLER (read, never advanced here; the serving loop
           advances only its live rows — `engine.serve_lm.DecodeServer`);
           t>1 is the per-row chunk: row r writes K/V at cursors[r]..
-          cursors[r]+t-1, causal within the chunk (speculative-decoding
-          verification feeds the whole draft in one apply).
+          cursors[r]+t-1, causal within the chunk (no program path feeds
+          this shape; the model-level tests do — ROADMAP D16).
 
         Uses its own cached softmax-attention kernel — any correct causal
         ``attn_fn`` (full/ring/flash) is numerically equivalent, so the

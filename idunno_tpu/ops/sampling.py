@@ -1,10 +1,10 @@
 """Shared sampling transforms for the generation tiers.
 
 One implementation of top-k and nucleus (top-p) filtering serves both
-one-shot `engine.generate` and the continuous-batching pool /
-speculative-sampling path (`engine.serve_lm`) — the pool's
-distribution-exactness contract depends on the two tiers filtering
-identically, so the construction lives here once. Two forms of it:
+one-shot `engine.generate` and the continuous-batching pool
+(`engine.serve_lm`) — the pool's token-exactness contract depends on the
+two tiers filtering identically, so the construction lives here once.
+Two forms of it:
 
 - `sample_keep_mask`/`masked_sample_logits`: the TOKEN-exact hot path
   (generate loop, `fused_decode_tail`, the prefill pick). Thresholds
@@ -13,9 +13,9 @@ identically, so the construction lives here once. Two forms of it:
   vocab-sharded unembed without all-gathering [rows, vocab] logits
   (ISSUE 16).
 - `filtered_probs`/`nucleus_probs`: the sort-based NORMALIZED
-  distribution, kept for speculative verification (`spec_commit` needs
-  actual probabilities, and the spec contract is distribution-exact,
-  not stream-exact).
+  distribution, the plain statement of the filter. No program path
+  calls it: it is the reference `tests/test_sampling.py` holds
+  `sample_keep_mask`'s survivor set to.
 
 Reference has no sampling at all (`alexnet_resnet.py` serves argmax
 classifications only).
@@ -67,9 +67,9 @@ def filtered_probs(scaled_logits: jnp.ndarray, top_p: jnp.ndarray,
     probs = jax.nn.softmax(scaled_logits, axis=-1)
     v = probs.shape[-1]
     k = jnp.clip(top_k, 0, v)
-    # ONE descending sort serves both filters (this runs on the decode
-    # hot path): the top-k survivors are exactly the prefix of sorted_p
-    # at/above the k-th probability, and k-masking preserves sort order,
+    # ONE descending sort serves both filters: the top-k survivors are
+    # exactly the prefix of sorted_p at/above the k-th probability, and
+    # k-masking preserves sort order,
     # so the nucleus cutoff over the RENORMALIZED top-k distribution is
     # derivable from the same sorted array — cumsum of the masked prefix
     # divided by its total is the normalized cumulative the nucleus
@@ -175,14 +175,6 @@ def masked_sample_logits(scaled: jnp.ndarray, top_p: jnp.ndarray,
     keep = sample_keep_mask(scaled, top_p, top_k)
     off = ~filter_on(top_p, top_k)
     return jnp.where(keep | off[..., None], scaled, -jnp.inf)
-
-
-def safe_log(probs: jnp.ndarray) -> jnp.ndarray:
-    """log with EXACT -inf outside the support — a filtered-out token
-    must have probability zero, not e^-69 (matches generate's -inf
-    nucleus masking)."""
-    return jnp.where(probs > 0.0, jnp.log(jnp.maximum(probs, 1e-38)),
-                     -jnp.inf)
 
 
 def filter_on(top_p: jnp.ndarray, top_k: jnp.ndarray) -> jnp.ndarray:

@@ -324,6 +324,13 @@ class ControlService:
                 raise ValueError(
                     f"kv_cache_dtype {p['kv_cache_dtype']!r}: "
                     "want native|int8")
+            gone = [k for k in ("draft", "draft_len") if k in p]
+            if gone:
+                # serving without the draft would change a seeded sampled
+                # stream without saying so
+                raise ValueError(
+                    f"lm_serve option(s) {gone} are not supported: "
+                    "speculative decoding was removed")
             gw_spec = p.get("gateway")
             if gw_spec:
                 # same validate-before-registry rule: a bad gateway spec
@@ -364,11 +371,6 @@ class ControlService:
                     import dataclasses as _dc
                     model = _dc.replace(
                         model, kv_cache_dtype=p["kv_cache_dtype"])
-                draft = None
-                if p.get("draft"):
-                    # speculative decoding: the draft is another
-                    # store-persisted LM (typically a much smaller one)
-                    draft = load_lm(node.store, p["draft"])
                 from idunno_tpu.engine.serve_lm import DEFAULT_SLOTS
                 server = DecodeServer(
                     model, params,
@@ -383,8 +385,6 @@ class ControlService:
                             if p.get("prefix") else None),
                     eos_id=(int(p["eos_id"])
                             if p.get("eos_id") is not None else None),
-                    draft=draft,
-                    draft_len=int(p.get("draft_len", 4)),
                     prompt_buckets=(tuple(int(b) for b
                                           in p["prompt_buckets"])
                                     if p.get("prompt_buckets") else None),

@@ -369,7 +369,7 @@ class LMPoolManager:
               assigned: bool = False) -> dict[str, Any]:
         """Place a decode pool on the least-loaded alive node and register
         it. ``spec`` is the node-local ``lm_serve`` payload (name,
-        prompt_len, max_len, slots, draft, ...).
+        prompt_len, max_len, slots, ...).
 
         Multi-owner placement (ISSUE 15): the pool's fence scope has a
         deterministic rendezvous owner over the alive hosts; when that

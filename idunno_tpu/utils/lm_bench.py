@@ -14,13 +14,6 @@ codebase, so it carries its own measured surface (round-3 VERDICT weak #3):
               decode MFU (2·params FLOPs/token convention) the record carries
               the implied weight-stream bandwidth — the honest utilization
               axis for this phase.
-  spec      — best-case speculative decoding point: target and draft share
-              constructed weights that agree everywhere (zeroed trees →
-              identical argmax streams → acceptance 1.0), measuring the
-              MECHANISM ceiling (chunked verify vs per-token decode) with
-              data-independent matmul timing. Untrained random weights would
-              floor acceptance near 0; real deployments (distilled drafts)
-              sit between — see docs/DEPLOY.md.
   int8      — the same steady-state decode with int8 weight-only residency
               (`ops/quantize.py`): decode re-reads every weight per step, so
               residency is the lever.
@@ -72,181 +65,9 @@ def lm_bench_config(platform: str) -> dict:
         # scan-tiled prefill dispatches (the CNN sweep's BENCH_SCAN_TILE
         # analog): tile full prefill batches per timed dispatch
         "prefill_tile": _env_int("BENCH_LM_PREFILL_TILE", 4 if tpu else 1),
-        "draft_dim": _env_int("BENCH_LM_DRAFT_DIM", 256 if tpu else 64),
-        "draft_depth": _env_int("BENCH_LM_DRAFT_DEPTH", 2 if tpu else 1),
-        "draft_len": _env_int("BENCH_LM_DRAFT_LEN", 4),
         # full-suite GQA comparison point: same model with this many K/V
         # heads (must divide heads; 0 disables the point)
         "gqa_kv_heads": _env_int("BENCH_LM_GQA_KV_HEADS", 4 if tpu else 1),
-        # trained-draft speculative point (speculative_trained): target and
-        # draft sizes + shared-corpus train steps; the draft trains for a
-        # third of the steps so its quality gap — and so the acceptance
-        # rate — is realistic rather than constructed
-        "trained_dim": _env_int("BENCH_LM_TRAINED_DIM", 512 if tpu else 48),
-        "trained_depth": _env_int("BENCH_LM_TRAINED_DEPTH", 4 if tpu else 1),
-        "trained_draft_dim": _env_int("BENCH_LM_TRAINED_DRAFT_DIM",
-                                      128 if tpu else 24),
-        "trained_draft_depth": _env_int("BENCH_LM_TRAINED_DRAFT_DEPTH", 1),
-        "trained_steps": _env_int("BENCH_LM_TRAINED_STEPS",
-                                  600 if tpu else 40),
-    }
-
-
-def spec_max_new(cfg: dict) -> int:
-    """max_new for the speculative phase: speculative rows reserve
-    draft_len+1 headroom below max_len (DecodeServer.validate), so the
-    plain max_new is clamped against the serving config. Single source of
-    truth — the phase and its config-guard test both call this."""
-    return min(cfg["max_new"],
-               cfg["max_len"] - cfg["prompt_len"] - cfg["draft_len"] - 1)
-
-
-def spec_rounds(cfg: dict) -> int:
-    """Fused draft+verify rounds per dispatch for the speculative phase:
-    enough to amortize the fixed dispatch latency (one dispatch
-    advances ~decode_steps tokens at full acceptance), clamped so a full
-    request spans ≥3 dispatches — the untimed warm-up dispatch must not
-    retire the rows and zero the timed region. A row has spec_max_new-1
-    tokens of remaining budget after its prefill token, so admissibility
-    is ``rounds·(draft_len+1) < spec_max_new - 1``; the shipped defaults
-    satisfy it (config-guard test), and the phase raises loudly if an
-    operator override does not. Single source of truth — the phase and
-    its config-guard test both call this."""
-    chunk = cfg["draft_len"] + 1
-    r = max(1, min(cfg["decode_steps"] // chunk,
-                   spec_max_new(cfg) // (3 * chunk)))
-    while r > 1 and r * chunk >= spec_max_new(cfg) - 1:
-        r -= 1
-    return r
-
-
-def _markov_corpus(rng: np.random.Generator, n: int, seq: int,
-                   vocab_sub: int) -> np.ndarray:
-    """Order-2 Markov sequences: t⁺ = (3·t + 5·t⁻ + e) mod vocab_sub with
-    e ∈ {0,1,2} at p = (.7,.2,.1). Structured enough to learn in a few
-    hundred steps, stochastic enough that no model predicts it exactly —
-    the acceptance rate of a draft trained on it lands strictly inside
-    (0, 1), which is the whole point of the trained-speculative bench."""
-    out = np.zeros((n, seq), np.int64)
-    out[:, 0] = rng.integers(0, vocab_sub, size=n)
-    out[:, 1] = rng.integers(0, vocab_sub, size=n)
-    noise = rng.choice(3, size=(n, seq), p=[0.7, 0.2, 0.1])
-    for i in range(2, seq):
-        out[:, i] = (3 * out[:, i - 1] + 5 * out[:, i - 2]
-                     + noise[:, i]) % vocab_sub
-    return out
-
-
-def _trained_spec_point(platform: str, cfg: dict, base_tok_s_note: str
-                        ) -> dict:
-    """Speculative decoding with a TRAINED draft (round-4 VERDICT next-6):
-    the existing `speculative` phase measures the mechanism ceiling with
-    constructed 100%-acceptance weights; this one trains a target and a
-    smaller draft on a shared synthetic corpus (the draft for 1/3 the
-    steps), so acceptance is realistic ∈ (0,1), and measures end-to-end
-    spec-vs-plain decode on the SAME trained target — positive or
-    honestly negative. Cites `engine/serve_lm.py` spec_commit for the
-    sampling-exact commit rule; training via `engine/train_lm` on-device."""
-    import optax
-
-    from idunno_tpu.engine.serve_lm import DecodeServer
-    from idunno_tpu.engine.train import flat_tx
-    from idunno_tpu.engine.train_lm import (create_lm_train_state,
-                                            make_lm_train_step)
-    from idunno_tpu.models.transformer import TransformerLM
-
-    dt = jnp.bfloat16 if platform == "tpu" else jnp.float32
-    vocab_sub = min(cfg["vocab"], 512)
-    seq, batch = 128, 16
-    rng = np.random.default_rng(42)
-    heads = max(2, cfg["trained_dim"] // 64)
-    target = TransformerLM(vocab=cfg["vocab"], dim=cfg["trained_dim"],
-                           depth=cfg["trained_depth"], num_heads=heads,
-                           causal=True, dtype=dt, param_dtype=dt)
-    draft = TransformerLM(vocab=cfg["vocab"], dim=cfg["trained_draft_dim"],
-                          depth=cfg["trained_draft_depth"],
-                          num_heads=max(2, cfg["trained_draft_dim"] // 32),
-                          causal=True, dtype=dt, param_dtype=dt)
-
-    def train(model, steps, seed):
-        # flat layout (engine/train.py:flat_tx): at these tiny dims the
-        # per-tensor adam stream dominates step time
-        tx = flat_tx(optax.adam(3e-4))
-        state = create_lm_train_state(model, jax.random.PRNGKey(seed),
-                                      seq, tx)
-        step = jax.jit(make_lm_train_step(model, tx))
-        loss = None
-        for _ in range(steps):
-            toks = jnp.asarray(_markov_corpus(rng, batch, seq, vocab_sub))
-            state, metrics = step(state, toks)
-        loss = float(metrics["loss"])
-        return state.params, loss
-
-    t0 = time.perf_counter()
-    tparams, tloss = train(target, cfg["trained_steps"], 0)
-    dparams, dloss = train(draft, max(1, cfg["trained_steps"] // 3), 1)
-    train_s = time.perf_counter() - t0
-
-    prompt_len, chunk = 16, cfg["draft_len"] + 1
-    max_new = min(cfg["max_new"], cfg["max_len"] - prompt_len - chunk)
-    rounds = max(1, min(cfg["decode_steps"] // chunk,
-                        (max_new - 1) // (3 * chunk)))
-    prompts = _markov_corpus(rng, cfg["slots"], prompt_len, vocab_sub)
-
-    def steady(srv, steps_per_dispatch):
-        for row in prompts:
-            srv.submit([int(t) for t in row], max_new=max_new)
-        srv.step()
-        cur0 = np.asarray(srv._cursors).copy()
-        disp0 = srv.stats()["dispatches"]
-        t0 = time.perf_counter()
-        srv.run_until_drained()
-        dt_s = time.perf_counter() - t0
-        per_row = np.asarray(srv._cursors) - cur0
-        return (int(per_row.sum()), dt_s, per_row,
-                srv.stats()["dispatches"] - disp0)
-
-    plain = DecodeServer(target, tparams, slots=cfg["slots"],
-                         prompt_len=prompt_len, max_len=cfg["max_len"],
-                         decode_steps=cfg["decode_steps"])
-    plain.submit([1, 2, 3], max_new=cfg["decode_steps"] + 1)
-    plain.run_until_drained()                                 # compile
-    gen_p, dt_p, _, _ = steady(plain, cfg["decode_steps"])
-    del plain
-    spec = DecodeServer(target, tparams, slots=cfg["slots"],
-                        prompt_len=prompt_len, max_len=cfg["max_len"],
-                        draft=(draft, dparams), draft_len=cfg["draft_len"],
-                        decode_steps=rounds)
-    spec.submit([1, 2, 3], max_new=2)
-    spec.run_until_drained()                                  # compile
-    gen_s, dt_s, per_row, disp = steady(spec, rounds)
-    # acceptance: committed tokens per round ∈ [1, chunk]; executed
-    # rounds = ceil(tokens/chunk) only at FULL acceptance, so here the
-    # denominator is the dispatch count × rounds-per-dispatch bound,
-    # minus the idle tail estimated per row (rows retire raggedly)
-    exec_rounds = max(1, disp * rounds)
-    commit_per_round = gen_s / exec_rounds
-    plain_tok_s = gen_p / dt_p
-    spec_tok_s = gen_s / dt_s
-    return {
-        "target_dim": cfg["trained_dim"], "draft_dim":
-            cfg["trained_draft_dim"],
-        "train_steps": {"target": cfg["trained_steps"],
-                        "draft": max(1, cfg["trained_steps"] // 3)},
-        "train_s": round(train_s, 1),
-        "final_loss": {"target": round(tloss, 3),
-                       "draft": round(dloss, 3)},
-        "corpus": f"order-2 markov mod {vocab_sub}",
-        "plain_tokens_per_s": round(plain_tok_s, 1),
-        "tokens_per_s": round(spec_tok_s, 1),
-        "speedup_vs_plain": round(spec_tok_s / plain_tok_s, 2),
-        "draft_len": cfg["draft_len"],
-        "rounds_per_dispatch": rounds,
-        "avg_commit_per_round": round(commit_per_round, 2),
-        "acceptance_note": ("avg_commit_per_round / (draft_len+1) bounds "
-                            "per-token acceptance; commit includes the "
-                            "bonus token"),
-        "note": base_tok_s_note,
     }
 
 
@@ -307,7 +128,7 @@ def run_lm_bench(platform: str, device_kind: str, n_devices: int,
                  compact: bool = False) -> dict:
     """One measured LM record. ``deadline`` is a perf_counter() stamp after
     which optional phases are skipped (each phase is a fresh compile).
-    ``compact`` drops the speculative, int8 and gqa phases
+    ``compact`` drops the int8 and gqa phases
     (the unattended default run embeds the compact record; BENCH_SUITE=lm
     runs everything)."""
     from idunno_tpu.engine.serve_lm import DecodeServer
@@ -478,70 +299,7 @@ def run_lm_bench(platform: str, device_kind: str, n_devices: int,
     if peak_bf16:
         out["decode"]["mfu"] = round(tok_s * 2.0 * n_params / peak_bf16, 4)
 
-    # -- speculative best-case + int8 residency (full suite only) ---------
-    if not compact and time.perf_counter() < deadline:
-        try:
-            zt = jax.tree.map(jnp.zeros_like, params)
-            draft_model = TransformerLM(
-                vocab=cfg["vocab"], dim=cfg["draft_dim"],
-                depth=cfg["draft_depth"],
-                num_heads=max(1, cfg["heads"] // 4),
-                causal=True, dtype=dt, param_dtype=dt)
-            zd = jax.tree.map(
-                jnp.zeros_like,
-                draft_model.init(jax.random.PRNGKey(1),
-                                 jnp.zeros((1, 8), jnp.int32))["params"])
-            # fused rounds amortize the fixed dispatch latency (one
-            # round per dispatch was the bug spec_rounds() fixes); see
-            # its docstring for the warm-up admissibility clamp
-            chunk = cfg["draft_len"] + 1
-            n_rounds = spec_rounds(cfg)
-            spec = DecodeServer(
-                model, zt, slots=cfg["slots"], prompt_len=cfg["prompt_len"],
-                max_len=cfg["max_len"], draft=(draft_model, zd),
-                draft_len=cfg["draft_len"], decode_steps=n_rounds)
-            spec.warmup()                                # compile
-            for _ in range(cfg["slots"]):
-                spec.submit(list(range(1, cfg["prompt_len"] + 1)),
-                            max_new=spec_max_new(cfg))
-            spec.step()              # admission (prefills) + first round
-            cur0 = np.asarray(spec._cursors).copy()
-            disp0 = spec.stats()["dispatches"]
-            t0 = time.perf_counter()
-            spec.run_until_drained()
-            dt_s = time.perf_counter() - t0
-            # tokens committed inside the timed region, via cursor advance
-            # (excludes admission/prefill cost, matching the plain decode
-            # steady-state methodology; the ragged tail stays included)
-            per_row = np.asarray(spec._cursors) - cur0
-            gen = int(per_row.sum())
-            if gen <= 0:
-                raise RuntimeError(
-                    "speculative timed region committed 0 tokens (warm-up "
-                    "retired every row — config inadmissible)")
-            disp = max(1, spec.stats()["dispatches"] - disp0)
-            # denominator: rounds that actually did work. Per row that is
-            # ceil(tokens/chunk) under full acceptance (these constructed
-            # weights), which excludes the idle tail rounds of the final
-            # ragged dispatch — disp·spec_rounds would count them and
-            # fake a rejection rate into the 100%-acceptance ceiling.
-            rounds = max(1, int(np.ceil(per_row / chunk).sum()))
-            spec_tok_s = gen / dt_s
-            out["speculative"] = {
-                "tokens_per_s": round(spec_tok_s, 1),
-                "speedup_vs_plain": round(spec_tok_s / tok_s, 2),
-                "draft_len": cfg["draft_len"],
-                "rounds_per_dispatch": n_rounds,
-                "timed_dispatches": disp,
-                "avg_commit_per_round": round(gen / rounds, 2),
-                "note": ("constructed 100%-acceptance weights: mechanism "
-                         "ceiling; untrained random weights floor "
-                         "acceptance near 0 (docs/DEPLOY.md)"),
-            }
-            del spec
-        except Exception as e:  # noqa: BLE001
-            out["speculative"] = {"error": f"{type(e).__name__}: {e}"}
-
+    # -- int8 residency (full suite only) ---------------------------------
     if not compact and time.perf_counter() < deadline:
         try:
             tok8, _, _, _ = measure_pool(model, params, quantize="int8")
@@ -604,19 +362,6 @@ def run_lm_bench(platform: str, device_kind: str, n_devices: int,
             }
         except Exception as e:  # noqa: BLE001
             out["decode_slots_scaling"] = {"error": f"{type(e).__name__}: {e}"}
-
-    # trained-draft speculative point LAST (newest phase sacrifices first
-    # under the deadline): realistic acceptance ∈ (0,1) from a draft
-    # trained on 1/3 the shared-corpus steps of its target
-    if not compact and time.perf_counter() < deadline:
-        try:
-            out["speculative_trained"] = _trained_spec_point(
-                platform, cfg,
-                "trained pair on a shared corpus — realistic acceptance, "
-                "vs the constructed ceiling in `speculative`")
-        except Exception as e:  # noqa: BLE001
-            out["speculative_trained"] = {
-                "error": f"{type(e).__name__}: {e}"}
 
     return out
 

@@ -352,7 +352,7 @@ def test_decode_dispatch_updates_the_slot_cache_in_place(one_chip, widths,
 def test_chunked_steps_update_the_cache_in_place(one_chip, widths, quant,
                                                  rows, tokens):
     """`decode_apply` with more than one token a row: the per-row chunk of
-    4 over the pool's slots (speculative verification) and the
+    4 over the pool's slots and the
     scalar-cursor 1024-token chunk of a 4096-token row (chunked
     prefill)."""
     srv, p_shapes = _pool_and_shapes(widths, quant)

@@ -7,6 +7,7 @@ wall-clock sleeps (fast lane). The integration test drives a REAL
 tier's standing oracle: every ADMITTED request's token stream is exact
 vs standalone `engine.generate`, while batch traffic takes the sheds.
 """
+import itertools
 import time
 
 import jax
@@ -28,6 +29,18 @@ class FakeClock:
 
     def advance(self, dt: float) -> None:
         self.t += dt
+
+
+class TickingClock:
+    """Every reading is 2 ms after the last, whichever thread makes it:
+    a deadline of 1 ms has passed by any reading after the one that set
+    it."""
+
+    def __init__(self):
+        self._n = itertools.count()        # next() is atomic in CPython
+
+    def __call__(self) -> float:
+        return 0.002 * next(self._n)
 
 
 def gw(spec=None, clock=None) -> AdmissionGateway:
@@ -258,7 +271,7 @@ def test_gateway_pool_overload(lm):
         # batch sheds once backlog >= 2 * 1.5 = 3; interactive absorbs
         # the whole burst (threshold 2 * 21 = 42)
         "batch_wait_slack": 0.5, "interactive_wait_slack": 20.0,
-        "max_queue": 64}))
+        "max_queue": 64}, clock=TickingClock()))
     try:
         rng = np.random.default_rng(3)
         want = {}
@@ -276,9 +289,9 @@ def test_gateway_pool_overload(lm):
             assert ei.value.reason == "backpressure"
             sheds += 1
 
-        # dispatch budget is 2*slots = 4: with >= 4 requests un-retired
-        # on the server, the gateway dispatches nothing, so a 1 ms
-        # deadline expires in-queue deterministically
+        # its deadline sorts it ahead of the whole burst, so whether the
+        # loop's next drain has room for it must not matter: on the
+        # gateway's clock the 1 ms have passed by that drain's own reading
         dead_prompt = [7, 8, 9]
         dead_rid = loop.submit(dead_prompt, 5, deadline_ms=1.0)
 

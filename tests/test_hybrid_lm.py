@@ -199,11 +199,8 @@ def test_one_shot_prefill_masks_its_padding(fam, weights, built):
     (dict(paged_kernel="xla"), "paged_kernel"),
     (dict(prefix=[1, 2, 3]), "prefix="),
     (dict(quantize="int8"), "quantize="),
-    (dict(draft="a draft"), "draft="),
 ])
 def test_what_rests_on_kv_alone_is_refused_by_name(built, kw, what):
-    if "draft" in kw:
-        kw = dict(draft=built)
     with pytest.raises(UnsupportedStack, match=what):
         _server(built, **kw)
 

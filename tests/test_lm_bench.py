@@ -11,18 +11,14 @@ import pytest
 from idunno_tpu.utils.lm_bench import (lm_bench_config,
                                         prefix_bench_workload, run_lm_bench,
                                         run_lm_cluster_prefix_bench,
-                                        run_lm_prefix_bench, spec_max_new,
-                                        spec_rounds)
+                                        run_lm_prefix_bench)
 
 TINY = {
     "BENCH_LM_DIM": "64", "BENCH_LM_DEPTH": "1", "BENCH_LM_HEADS": "2",
     "BENCH_LM_VOCAB": "128", "BENCH_LM_SLOTS": "2", "BENCH_LM_PROMPT": "8",
     "BENCH_LM_MAXNEW": "16", "BENCH_LM_MAXLEN": "64",
     "BENCH_LM_DECODE_STEPS": "4", "BENCH_LM_PREFILL_BATCH": "2",
-    "BENCH_LM_PREFILL_SEQ": "32", "BENCH_LM_DRAFT_DIM": "32",
-    "BENCH_LM_DRAFT_DEPTH": "1", "BENCH_LM_GQA_KV_HEADS": "1",
-    "BENCH_LM_TRAINED_DIM": "32", "BENCH_LM_TRAINED_DEPTH": "1",
-    "BENCH_LM_TRAINED_DRAFT_DIM": "16", "BENCH_LM_TRAINED_STEPS": "6",
+    "BENCH_LM_PREFILL_SEQ": "32", "BENCH_LM_GQA_KV_HEADS": "1",
 }
 
 
@@ -46,10 +42,6 @@ def test_full_suite_record_shape(tiny_env):
     assert rec["flash_attention"] == "n/a (cpu)"
     assert rec["decode"]["tokens_per_s"] > 0
     assert rec["decode"]["slots"] == 2
-    # speculative: constructed weights agree everywhere, so every round
-    # must commit more than 1 token per row on average
-    assert rec["speculative"]["avg_commit_per_round"] > 1.5
-    assert rec["speculative"]["tokens_per_s"] > 0
     assert rec["int8_decode"]["tokens_per_s"] > 0
     assert rec["gqa_decode"]["tokens_per_s"] > 0
     assert rec["gqa_decode"]["kv_heads"] == 1
@@ -58,13 +50,6 @@ def test_full_suite_record_shape(tiny_env):
     # not silently become an {"error": ...} record in a live capture)
     assert rec["decode_slots_scaling"]["slots"] == 8
     assert rec["decode_slots_scaling"]["tokens_per_s"] > 0
-    # trained-draft speculative: a REAL train run (no constructed
-    # weights), commit per round within the mechanism's hard bounds
-    tr = rec["speculative_trained"]
-    assert "error" not in tr, tr
-    assert tr["train_steps"] == {"target": 6, "draft": 2}
-    assert tr["tokens_per_s"] > 0 and tr["plain_tokens_per_s"] > 0
-    assert 1.0 <= tr["avg_commit_per_round"] <= tr["draft_len"] + 1
     # tiled prefill: tokens/s must reflect tile*b*t tokens per dispatch
     assert rec["prefill"]["scan_tile"] == 1     # cpu default
 
@@ -72,9 +57,8 @@ def test_full_suite_record_shape(tiny_env):
 def test_compact_skips_optional_phases(tiny_env):
     rec = run_lm_bench("cpu", "cpu", 1, None,
                        deadline=time.perf_counter() + 600, compact=True)
-    assert "speculative" not in rec and "int8_decode" not in rec
+    assert "int8_decode" not in rec
     assert "gqa_decode" not in rec and "decode_slots_scaling" not in rec
-    assert "speculative_trained" not in rec
     assert "xla_full_attention" not in rec["prefill"]
     assert rec["decode"]["tokens_per_s"] > 0
 
@@ -82,9 +66,8 @@ def test_compact_skips_optional_phases(tiny_env):
 def test_deadline_skips_optional_phases(tiny_env):
     rec = run_lm_bench("cpu", "cpu", 1, None,
                        deadline=time.perf_counter() - 1, compact=False)
-    assert "speculative" not in rec and "int8_decode" not in rec
+    assert "int8_decode" not in rec
     assert "decode_slots_scaling" not in rec
-    assert "speculative_trained" not in rec
     assert rec["decode"]["tokens_per_s"] > 0
 
 
@@ -92,21 +75,12 @@ def test_deadline_skips_optional_phases(tiny_env):
 def test_default_config_phases_fit_serving_limits(platform, monkeypatch):
     """The unattended defaults must keep EVERY phase admissible — a knob
     bump that overflows a validate() limit silently turns a capture phase
-    into an error record (caught live: max_new 448 + draft headroom > 512)."""
-    for k in list(TINY) + ["BENCH_LM_MAXNEW", "BENCH_LM_MAXLEN",
-                           "BENCH_LM_DRAFT_LEN"]:
+    into an error record."""
+    for k in TINY:
         monkeypatch.delenv(k, raising=False)   # pin the SHIPPED defaults
     cfg = lm_bench_config(platform)
     # plain/int8/gqa rows
     assert cfg["prompt_len"] + cfg["max_new"] <= cfg["max_len"]
-    # speculative rows: after the bench's clamp (same helper the phase
-    # calls) the rows must still generate enough to time ≥1 full round
-    assert spec_max_new(cfg) > cfg["draft_len"] + 1
-    # and the fused-round clamp (same helper the phase calls) must leave
-    # real work after the untimed warm-up dispatch: a row's remaining
-    # budget after prefill is spec_max_new-1, so a warm-up that could
-    # retire every row would zero the measurement
-    assert spec_max_new(cfg) - 1 > spec_rounds(cfg) * (cfg["draft_len"] + 1)
     # _steady_decode_tok_s times k = (max_new-1)//decode_steps - 1 ≥ 1
     # FULL dispatches after the untimed first one; anything less and the
     # max(1, ...) floor counts a partial dispatch as a full one

@@ -295,61 +295,94 @@ def test_continuous_batching_served_over_control_rpc(stores):
         ctl.close()
 
 
-def test_speculative_pool_over_rpc(stores):
-    """lm_serve with draft=<another stored LM>: speculative continuous
-    batching over RPC, exact vs local generate from the target."""
-    import time
-
-    from idunno_tpu.comm.message import Message
-    from idunno_tpu.engine.generate import save_lm
+def _control_on(store):
+    """A `ControlService` on a node stub over ``store``."""
     from idunno_tpu.serve.control import ControlService
-    from idunno_tpu.utils.types import MessageType
-
-    target = TransformerLM(vocab=32, dim=32, depth=2, num_heads=4)
-    tparams = target.init(jax.random.PRNGKey(0),
-                          jnp.zeros((1, 8), jnp.int32))["params"]
-    draft = TransformerLM(vocab=32, dim=16, depth=1, num_heads=2)
-    dparams = draft.init(jax.random.PRNGKey(1),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    save_lm(stores["n0"], "spec-target", target, tparams)
-    save_lm(stores["n0"], "spec-draft", draft, dparams)
 
     node = type("NodeStub", (), {})()
     # minimal fence surface for ControlService._handle's epoch check
     node.membership = SimpleNamespace(epoch=EpochFence(), scopes=FenceRegistry())
-    node.host, node.store = "n1", stores["n1"]
-    node.transport = stores["n1"].transport
-    ctl = ControlService(node)
+    node.host, node.store = store.host, store
+    node.transport = store.transport
+    return ControlService(node)
+
+
+@pytest.fixture
+def loads(monkeypatch):
+    """The names `load_lm` was asked for, in order."""
+    import idunno_tpu.engine.generate as gen
+
+    asked, real = [], gen.load_lm
+
+    def counting(store, name, *a, **kw):
+        asked.append(name)
+        return real(store, name, *a, **kw)
+    monkeypatch.setattr(gen, "load_lm", counting)
+    return asked
+
+
+@pytest.mark.parametrize("key, value", [("draft", "small-lm"),
+                                        ("draft_len", 3)])
+def test_lm_serve_refuses_a_removed_option_by_name(stores, loads, key, value):
+    """A payload that still carries `draft` or `draft_len` is refused by
+    name before any model is loaded, and the node serves the next
+    `lm_serve` of the same name."""
+    from idunno_tpu.comm.message import Message
+    from idunno_tpu.engine.generate import save_lm
+    from idunno_tpu.utils.types import MessageType
+
+    model = TransformerLM(vocab=32, dim=32, depth=1, num_heads=4)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    save_lm(stores["n0"], "plain", model, params)
+    ctl = _control_on(stores["n1"])
 
     def call(payload):
         return ctl._handle("control", Message(
             MessageType.INFERENCE, "client", payload))
 
+    serve = {"verb": "lm_serve", "name": "plain", "slots": 1,
+             "prompt_len": 4, "max_len": 12}
     try:
-        # decode_steps=2 on a speculative pool = two fused draft+verify
-        # rounds per dispatch — the RPC surface must carry the knob and
-        # the stream must stay exact vs local generate
-        out = call({"verb": "lm_serve", "name": "spec-target",
-                    "draft": "spec-draft", "draft_len": 3,
-                    "decode_steps": 2,
-                    "slots": 2, "prompt_len": 4, "max_len": 24})
-        assert out.type is MessageType.ACK, out.payload
-        prompt = [3, 9, 14]
-        out = call({"verb": "lm_submit", "name": "spec-target",
-                    "prompt": prompt, "max_new": 8})
-        assert out.type is MessageType.ACK, out.payload
-        rid, got = out.payload["id"], None
-        deadline = time.time() + 180.0
-        while time.time() < deadline and got is None:
-            for c in call({"verb": "lm_poll",
-                           "name": "spec-target"}).payload["completions"]:
-                if c["id"] == rid:
-                    got = c
-            time.sleep(0.05)
-        assert got is not None
-        want = generate(target, tparams, jnp.asarray([prompt], jnp.int32),
-                        prompt_len=3, max_new=8)
-        assert got["tokens"] == [int(t) for t in np.asarray(want[0])]
+        out = call(dict(serve, **{key: value}))
+        assert out.type is MessageType.ERROR
+        assert repr(key) in out.payload["error"], out.payload
+        assert loads == []
+        out = call({"verb": "lm_submit", "name": "plain",
+                    "prompt": [1], "max_new": 1})
+        assert out.type is MessageType.ERROR          # nothing serves yet
+        out = call(serve)
+        assert out.type is MessageType.ACK and out.payload["slots"] == 1
+        assert loads == ["plain"]
+    finally:
+        ctl.close()
+
+
+def test_manager_serve_of_a_removed_option_registers_no_pool(stores, loads):
+    """`LMPoolManager.serve` of a spec carrying `draft`: the node's refusal
+    comes back through the failed-build path and no pool stays registered,
+    so nothing respawns it."""
+    from idunno_tpu.serve.lm_manager import LMPoolManager
+
+    ctl = _control_on(stores["n1"])
+
+    class ToTheNode:
+        def call(self, node, component, msg, timeout=30.0):
+            return ctl._handle(component, msg)
+
+    membership = SimpleNamespace(
+        is_acting_master=True, epoch=EpochFence(), scopes=FenceRegistry(),
+        members=SimpleNamespace(alive_hosts=lambda: ["n1"]),
+        on_change=lambda cb: None, acting_master=lambda: "n1")
+    cfg = ClusterConfig(hosts=("n1",), coordinator="n1",
+                        standby_coordinator="n1", introducer="n1")
+    mgr = LMPoolManager("n1", cfg, ToTheNode(), membership)
+    try:
+        with pytest.raises(ValueError, match="'draft'"):
+            mgr.serve({"name": "plain", "slots": 1, "prompt_len": 4,
+                       "max_len": 12, "draft": "small-lm"})
+        assert not mgr.has_pool("plain") and mgr._pools == {}
+        assert loads == []
     finally:
         ctl.close()
 

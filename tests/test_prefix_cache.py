@@ -5,8 +5,8 @@ Exactness oracle: a radix hit splices KV another request computed — greedy
 decode through a `kv_block_size` pool must stay token-for-token identical
 to `engine.generate.generate` at EVERY hit depth (empty, partial-block,
 multi-block, full-prompt), for MHA, GQA/MQA, penalties pools, int8
-caches, a pool-level static prefix, and a speculative draft. The
-reference has no counterpart: every query recomputes from scratch
+caches and a pool-level static prefix. The reference has no
+counterpart: every query recomputes from scratch
 (`mp4_machinelearning.py:541-616`).
 """
 import jax
@@ -260,23 +260,6 @@ def test_hit_depths_with_static_prefix_and_int8(lm):
     assert srv.prefix_cache_stats()["hits"] == 3
 
 
-def test_hit_depths_speculative(lm):
-    """The radix cache covers the TARGET only; the draft prefills its own
-    full prompt — fused spec rounds must stay greedy token-exact."""
-    model, params = lm
-    draft = TransformerLM(vocab=VOCAB, dim=16, depth=1, num_heads=2)
-    dparams = draft.init(jax.random.PRNGKey(9),
-                         jnp.zeros((1, 4), jnp.int32))["params"]
-    srv = DecodeServer(model, params, slots=2, prompt_len=8, max_len=32,
-                       draft=(draft, dparams), draft_len=3, decode_steps=2,
-                       kv_block_size=BS, kv_cache_blocks=16)
-    for prompt, _ in hit_depth_prompts(np.random.default_rng(11)):
-        rid = srv.submit(prompt, max_new=8)
-        done = {c.id: c for c in srv.run_until_drained()}
-        assert done[rid].tokens == expected(model, params, prompt, 8)
-    assert srv.prefix_cache_stats()["hits"] == 3
-
-
 def test_prompt_bucket_shrinks_after_hit(lm):
     """A radix hit must move the suffix into a SMALLER prompt bucket —
     the prefill-FLOPs reduction the cache exists for — visible in the
@@ -374,24 +357,6 @@ def test_paged_int8_static_prefix_token_exact(lm, kernel, resolved):
         assert done[rid].tokens == expected(model, params, pre + prompt, 5)
     assert srv.prefix_cache_stats()["hits"] == 3
     assert srv.stats()["kv_gather_bytes_saved"] > 0
-
-
-def test_paged_speculative_token_exact(lm):
-    """Fused spec rounds verify the TARGET through the block table; the
-    draft stays contiguous. Greedy must remain token-exact."""
-    model, params = lm
-    draft = TransformerLM(vocab=VOCAB, dim=16, depth=1, num_heads=2)
-    dparams = draft.init(jax.random.PRNGKey(9),
-                         jnp.zeros((1, 4), jnp.int32))["params"]
-    srv = DecodeServer(model, params, slots=2, prompt_len=8, max_len=32,
-                       draft=(draft, dparams), draft_len=3, decode_steps=2,
-                       kv_block_size=BS, kv_cache_blocks=16,
-                       paged_kernel="pallas")
-    for prompt, _ in hit_depth_prompts(np.random.default_rng(11)):
-        rid = srv.submit(prompt, max_new=8)
-        done = {c.id: c for c in srv.run_until_drained()}
-        assert done[rid].tokens == expected(model, params, prompt, 8)
-    assert srv.prefix_cache_stats()["hits"] == 3
 
 
 def test_paged_requires_blocks_and_scan(lm):

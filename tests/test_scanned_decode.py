@@ -254,8 +254,8 @@ def test_tp_sharded_tail_one_scan_no_sort_depth_invariant():
 # -- the layer scan carries the cache: what a step writes, and only that -----
 
 _DEPTH, _MAX, _BS = 3, 24, 4
-# (rows, tokens a row, cursors): the per-row step, the per-row chunk
-# (speculative verification), the scalar-cursor chunk (chunked prefill)
+# (rows, tokens a row, cursors): the per-row step, the per-row chunk,
+# the scalar-cursor chunk (chunked prefill)
 _SHAPES = {"per-row-step": (3, 1, (9, 13, 8)),
            "per-row-chunk": (3, 4, (9, 13, 8)),
            "scalar-chunk": (1, 5, 9)}

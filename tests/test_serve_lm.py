@@ -72,6 +72,24 @@ def test_short_requests_complete_while_long_one_runs(lm):
     assert set(finished_order) == {long_id, *short_ids}
 
 
+def test_request_filling_max_len_exactly_is_served(lm):
+    """prompt + max_new == max_len is admitted, one token more is refused,
+    and under four tokens a dispatch (the budget ends mid-dispatch, the
+    last write lands on the row's last position) the stream is exact."""
+    model, params = lm
+    srv = DecodeServer(model, params, slots=2, prompt_len=4, max_len=20,
+                       decode_steps=4)
+    prompt = [5, 11, 17]
+    with pytest.raises(ValueError, match="> max_len 20"):
+        srv.submit(prompt, max_new=18)
+    rid = srv.submit(prompt, max_new=17)
+    other = srv.submit([9], max_new=6)               # a co-resident row
+    done = {c.id: c for c in srv.run_until_drained()}
+    assert len(done[rid].tokens) == 20
+    assert done[rid].tokens == expected(model, params, prompt, 17)
+    assert done[other].tokens == expected(model, params, [9], 6)
+
+
 def test_fused_decode_steps_match(lm):
     model, params = lm
     prompt = [5, 11, 17]
@@ -83,38 +101,6 @@ def test_fused_decode_steps_match(lm):
     a = one.run_until_drained()[0]
     b = fused.run_until_drained()[0]
     assert a.tokens == b.tokens == expected(model, params, prompt, 10)
-
-
-def test_fused_spec_rounds_match(lm):
-    """decode_steps on a SPECULATIVE pool fuses that many draft+verify
-    rounds into one dispatch. The fused server's streams must be
-    token-identical to the round-per-dispatch server's — greedy rows,
-    seeded nucleus rows, and under a weak (rejecting) draft — while
-    issuing strictly fewer decode dispatches (the whole point: one
-    dispatch per round cannot win over a high-latency link)."""
-    model, params = lm
-    weak = TransformerLM(vocab=VOCAB, dim=16, depth=1, num_heads=2)
-    weak_params = weak.init(jax.random.PRNGKey(99),
-                            jnp.zeros((1, 4), jnp.int32))["params"]
-    prompt = [3, 1, 4]
-
-    def serve(steps, draft, draft_params):
-        srv = DecodeServer(model, params, slots=2, prompt_len=4,
-                           max_len=48, draft=(draft, draft_params),
-                           draft_len=3, decode_steps=steps)
-        rid_g = srv.submit(prompt, max_new=12)
-        rid_s = srv.submit(prompt, max_new=12, temperature=0.9,
-                           top_p=0.8, seed=7)
-        done = {c.id: c for c in srv.run_until_drained()}
-        return (done[rid_g].tokens, done[rid_s].tokens,
-                srv.stats()["dispatches"])
-
-    for draft, dparams in ((model, params), (weak, weak_params)):
-        g1, s1, d1 = serve(1, draft, dparams)
-        g3, s3, d3 = serve(3, draft, dparams)
-        assert g1 == g3 == expected(model, params, prompt, 12)
-        assert s1 == s3, "fused rounds changed a sampled stream"
-        assert d3 < d1, f"fusing 3 rounds should cut dispatches ({d3} vs {d1})"
 
 
 def test_docstring_loop_serves_all_instant_requests(lm):
@@ -253,83 +239,6 @@ def test_sampling_fast_path_boundary(lm):
     assert done[lid] == fresh_tokens[fid]
 
 
-def test_spec_fast_path_boundary(lm):
-    """The speculative round has the same all-greedy fast path as plain
-    decode (no live row samples → the draft-distribution/key/uniform
-    machinery is skipped). Cross that boundary mid-serving on a SPEC pool
-    in both directions: a short sampled row retires while a long greedy
-    row keeps decoding (rounds flip full→greedy), then a NEW sampled
-    request admits into the freed slot (greedy→full). The greedy stream
-    must equal `generate` exactly across both flips, and the late sampled
-    stream must reproduce its fresh-pool tokens — its rejection-scheme
-    key chain depends only on its own admission seed, not on which branch
-    earlier rounds took."""
-    model, params = lm
-    prompt = [5, 11, 17]
-    kw = dict(slots=2, prompt_len=4, max_len=40,
-              draft=(model, params), draft_len=3)
-    srv = DecodeServer(model, params, **kw)
-    gid = srv.submit(prompt, max_new=30)                  # long greedy
-    sid = srv.submit(prompt, max_new=4, temperature=1.0,  # short sampled
-                     seed=3)
-    done = {}
-    for _ in range(10):      # sampled row retires; rounds run all-greedy
-        srv.step()
-        done.update({c.id: c.tokens for c in srv.poll()})
-        if sid in done:
-            break
-    assert sid in done and gid not in done
-    lid = srv.submit(prompt, max_new=6, temperature=1.0,  # late sampled
-                     seed=9)
-    done.update({c.id: c.tokens for c in srv.run_until_drained()})
-    assert done[gid] == expected(model, params, prompt, 30)
-
-    fresh = DecodeServer(model, params, **kw)
-    fid = fresh.submit(prompt, max_new=6, temperature=1.0, seed=9)
-    fresh_tokens = {c.id: c.tokens for c in fresh.run_until_drained()}
-    assert done[lid] == fresh_tokens[fid]
-
-
-def test_speculative_decoding_exact_and_fewer_dispatches(lm):
-    """Speculative decoding's contract: the committed stream is EXACTLY
-    the target's own greedy sequence, for any draft. With draft == target
-    every proposal is accepted, so each round commits draft_len+1 tokens
-    and dispatch count collapses accordingly."""
-    model, params = lm
-    rng = np.random.default_rng(9)
-    reqs = [([int(t) for t in rng.integers(0, VOCAB, size=n)], m)
-            for n, m in [(3, 12), (6, 9), (2, 14), (5, 8)]]
-
-    # draft == target: full acceptance, big dispatch win
-    srv = DecodeServer(model, params, slots=2, prompt_len=8, max_len=40,
-                       draft=(model, params), draft_len=3)
-    ids = {srv.submit(p, m): (p, m) for p, m in reqs}
-    done = srv.run_until_drained()
-    assert {c.id for c in done} == set(ids)
-    for c in done:
-        p, m = ids[c.id]
-        assert c.tokens == expected(model, params, p, m), \
-            f"speculative output diverged from target greedy (req {c.id})"
-    stats = srv.stats()
-    # 4 requests x ~11 avg tokens ≈ 43 generated; full acceptance commits
-    # draft_len+1 = 4/round/row → far fewer dispatches than tokens
-    assert stats["tokens_generated"] >= 40
-    assert stats["dispatches"] * 2 < stats["tokens_generated"], stats
-
-    # an unrelated (differently-initialized) draft: still EXACT, whatever
-    # its acceptance rate
-    weak = TransformerLM(vocab=VOCAB, dim=16, depth=1, num_heads=2)
-    weak_params = weak.init(jax.random.PRNGKey(42),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-    srv2 = DecodeServer(model, params, slots=2, prompt_len=8, max_len=40,
-                        draft=(weak, weak_params), draft_len=3)
-    ids2 = {srv2.submit(p, m): (p, m) for p, m in reqs}
-    for c in srv2.run_until_drained():
-        p, m = ids2[c.id]
-        assert c.tokens == expected(model, params, p, m), \
-            f"weak-draft speculative output diverged (req {c.id})"
-
-
 def test_prompt_buckets_exact_across_slot_reuse(lm):
     """Multi-bucket prefill: each admission uses the smallest bucket
     covering its prompt; outputs stay exact when a long-prompt request
@@ -348,67 +257,9 @@ def test_prompt_buckets_exact_across_slot_reuse(lm):
         assert c.tokens == expected(model, params, ids[c.id], 6), \
             f"bucketed prefill diverged for prompt len {len(ids[c.id])}"
 
-    # speculative + buckets compose
-    spec = DecodeServer(model, params, slots=2, prompt_len=8, max_len=24,
-                        prompt_buckets=(4, 8), draft=(model, params),
-                        draft_len=2)
-    ids2 = {}
-    for n in (3, 8, 2, 6):
-        p = [int(t) for t in rng.integers(0, VOCAB, size=n)]
-        ids2[spec.submit(p, max_new=5)] = p
-    for c in spec.run_until_drained():
-        assert c.tokens == expected(model, params, ids2[c.id], 5)
-
     with pytest.raises(ValueError, match="largest prompt bucket"):
         DecodeServer(model, params, slots=1, prompt_len=8, max_len=24,
                      prompt_buckets=(2, 4))
-
-
-def test_speculative_respects_eos(lm):
-    model, params = lm
-    prompt = [9, 21, 3]
-    full = expected(model, params, prompt, 12)
-    eos = full[len(prompt) + 5]
-    cut = full[:full.index(eos, len(prompt)) + 1]
-    srv = DecodeServer(model, params, slots=1, prompt_len=4, max_len=40,
-                       draft=(model, params), draft_len=3, eos_id=eos)
-    srv.submit(prompt, max_new=12)
-    assert srv.run_until_drained()[0].tokens == cut
-
-
-def test_speculative_validation(lm):
-    model, params = lm
-    srv = DecodeServer(model, params, slots=1, prompt_len=4, max_len=12,
-                       draft=(model, params), draft_len=3)
-    with pytest.raises(ValueError, match="headroom"):
-        srv.submit([1, 2], max_new=7)     # 2+7+4 > 12
-    srv.submit([1, 2], max_new=6)         # 2+6+4 = 12 fits
-    with pytest.raises(ValueError, match="decode_steps"):
-        DecodeServer(model, params, slots=1, prompt_len=4, max_len=16,
-                     draft=(model, params), decode_steps=0)
-    bad_vocab = TransformerLM(vocab=VOCAB + 1, dim=16, depth=1,
-                              num_heads=2)
-    with pytest.raises(ValueError, match="vocab"):
-        DecodeServer(model, params, slots=1, prompt_len=4, max_len=16,
-                     draft=(bad_vocab, params))
-    # MoE TARGETS are rejected: routed-FFN logits are batch-composition-
-    # dependent, so the chunked verify would silently diverge from the
-    # target's own per-token greedy stream
-    from idunno_tpu.models.moe import MoETransformerLM
-    moe = MoETransformerLM(vocab=VOCAB, dim=16, depth=1, num_heads=2,
-                           n_experts=2)
-    moe_params = moe.init(jax.random.PRNGKey(3),
-                          jnp.zeros((1, 4), jnp.int32))["params"]
-    with pytest.raises(ValueError, match="dense target"):
-        DecodeServer(moe, moe_params, slots=1, prompt_len=4, max_len=16,
-                     draft=(model, params))
-    # ...but an MoE DRAFT is fine (proposals are only guesses)
-    srv_moe_draft = DecodeServer(model, params, slots=1, prompt_len=4,
-                                 max_len=20, draft=(moe, moe_params),
-                                 draft_len=2)
-    srv_moe_draft.submit([1, 2], max_new=6)
-    got = srv_moe_draft.run_until_drained()[0]
-    assert got.tokens == expected(model, params, [1, 2], 6)
 
 
 def test_submit_validation(lm):
@@ -461,107 +312,11 @@ def test_service_time_excludes_queue_wait(lm):
     assert svc[-1] < 5.0 * svc[0], svc
 
 
-def test_spec_commit_distribution_exact():
-    """The fundamental speculative-sampling invariant (Leviathan/Chen):
-    whatever the draft distribution q, the FIRST committed token is
-    distributed exactly as the target distribution p. Monte-Carlo over the
-    pure `spec_commit` math with a deliberately skewed q."""
-    import jax
-    import jax.numpy as jnp
-
-    from idunno_tpu.engine.serve_lm import spec_commit
-
-    vocab, gamma, trials = 5, 3, 20_000
-    p = jnp.asarray([0.05, 0.45, 0.10, 0.25, 0.15])
-    q = jnp.asarray([0.50, 0.05, 0.20, 0.05, 0.20])    # very unlike p
-
-    def one_trial(key):
-        ks = jax.random.split(key, 2 * gamma + 1)
-        props = jnp.stack([jax.random.categorical(ks[j], jnp.log(q))
-                           for j in range(gamma)]).astype(jnp.int32)[None]
-        qd = jnp.broadcast_to(q, (1, gamma, vocab))
-        pd = jnp.broadcast_to(p, (1, gamma + 1, vocab))
-        tpred = jnp.argmax(pd, axis=-1).astype(jnp.int32)
-        u = jnp.stack([jax.random.uniform(ks[gamma + j])
-                       for j in range(gamma)])[None]
-        cand, _ = spec_commit(props, qd, pd, tpred,
-                              jnp.asarray([True]), u, ks[-1:][0][None])
-        return cand[0, 0]                 # first committed token
-
-    toks = jax.jit(jax.vmap(one_trial))(
-        jax.random.split(jax.random.PRNGKey(0), trials))
-    emp = np.bincount(np.asarray(toks), minlength=vocab) / trials
-    # 20k trials: binomial std ≤ ~0.0035 per bucket; 4 sigma ≈ 0.015
-    assert np.abs(emp - np.asarray(p)).max() < 0.02, (emp, p)
-
-
-def test_spec_commit_greedy_rows_unchanged():
-    """temperature-0 rows through the same code path commit exactly the
-    argmax-match prefix + target argmax bonus, independent of u/keys."""
-    import jax
-    import jax.numpy as jnp
-
-    from idunno_tpu.engine.serve_lm import spec_commit
-
-    vocab, gamma = 4, 2
-    props = jnp.asarray([[2, 1]], jnp.int32)
-    qd = jnp.full((1, gamma, vocab), 0.25)
-    # target argmaxes: pos0 → 2 (match), pos1 → 3 (mismatch), pos2 → 0
-    pd = jnp.asarray([[[0, 0, 1, 0], [0, 0, 0, 1],
-                       [1, 0, 0, 0]]], jnp.float32)
-    tpred = jnp.argmax(pd, axis=-1).astype(jnp.int32)
-    u = jnp.ones((1, gamma))              # would reject every sampled test
-    cand, acc = spec_commit(props, qd, pd, tpred,
-                            jnp.asarray([False]), u,
-                            jax.random.PRNGKey(0)[None])
-    assert int(acc[0]) == 1               # prefix: pos0 matched, pos1 not
-    assert cand[0, :2].tolist() == [2, 3]  # proposal, then target argmax
-
-
-def test_speculative_sampled_requests_complete(lm):
-    """Sampled traffic on a speculative pool: completes, in-vocab, seeded
-    reproducibly; a co-resident greedy request stays token-exact."""
-    model, params = lm
-    prompt = [3, 1, 4]
-
-    def run():
-        srv = DecodeServer(model, params, slots=2, prompt_len=4,
-                           max_len=40, draft=(model, params), draft_len=3)
-        rid_s = srv.submit(prompt, max_new=10, temperature=0.9, seed=123)
-        rid_g = srv.submit(prompt, max_new=10)
-        done = {c.id: c for c in srv.run_until_drained()}
-        return done[rid_s], done[rid_g]
-
-    s1, g1 = run()
-    s2, g2 = run()
-    assert g1.tokens == expected(model, params, prompt, 10)
-    assert g2.tokens == g1.tokens
-    assert len(s1.tokens) == len(prompt) + 10
-    assert all(0 <= t < VOCAB for t in s1.tokens)
-    assert s1.tokens == s2.tokens         # pinned seed → reproducible
-
-
-def test_nucleus_probs_masks_tail():
-    """`nucleus_probs` keeps exactly the smallest prefix of sorted mass
-    reaching top_p and renormalizes; top_p=1 is the identity."""
-    import jax.numpy as jnp
-
-    from idunno_tpu.ops.sampling import nucleus_probs
-
-    logits = jnp.log(jnp.asarray([[0.5, 0.3, 0.15, 0.05]]))
-    out = np.asarray(nucleus_probs(logits, jnp.asarray([0.6])))[0]
-    # nucleus = {0.5, 0.3} (0.5 alone < 0.6) → renormalized 0.625/0.375
-    assert np.allclose(out, [0.625, 0.375, 0.0, 0.0], atol=1e-6)
-    ident = np.asarray(nucleus_probs(logits, jnp.asarray([1.0])))[0]
-    assert np.allclose(ident, [0.5, 0.3, 0.15, 0.05], atol=1e-6)
-
-
 def test_logprobs_tracking(lm):
     """track_logprobs=True: every completion carries per-generated-token
     logprobs under the raw model distribution — cross-checked against a
     teacher-forced full forward over the completed sequence. Greedy and
-    sampled rows both covered; a spec pool reports the same values for
-    the same (greedy) stream; flag off → logprobs is None."""
+    sampled rows both covered; flag off → logprobs is None."""
     model, params = lm
     prompt = [5, 11, 17]
 
@@ -585,16 +340,6 @@ def test_logprobs_tracking(lm):
         want = teacher_forced_lps(c.tokens)
         np.testing.assert_allclose(c.logprobs, want, atol=2e-3,
                                    err_msg=f"request {c.id}")
-
-    # speculative pool, same greedy stream → same logprobs (within the
-    # chunked-verify vs per-token float divergence)
-    spec = DecodeServer(model, params, slots=1, prompt_len=4, max_len=24,
-                        draft=(model, params), draft_len=3,
-                        track_logprobs=True)
-    spec.submit(prompt, max_new=8)
-    sp = spec.run_until_drained()[0]
-    assert sp.tokens == g.tokens
-    np.testing.assert_allclose(sp.logprobs, g.logprobs, atol=2e-3)
 
     # flag off (the default): no logprob bookkeeping, field stays None
     off = DecodeServer(model, params, slots=1, prompt_len=4, max_len=24)
@@ -681,8 +426,7 @@ def test_prefix_cache(lm):
     """Shared-prefix pools (system prompt): the prefix is prefilled once
     at pool build; every admission prefills only its suffix from the
     spliced cache. Completions must be token-exact vs `generate` over
-    the FULL prefix+suffix prompt — plain, speculative, and int8-KV
-    pools — with prompt_len covering prefix+suffix (so the generated
+    the FULL prefix+suffix prompt — plain and int8-KV pools — with prompt_len covering prefix+suffix (so the generated
     region and logprob alignment are unchanged)."""
     import dataclasses as dc
 
@@ -703,13 +447,6 @@ def test_prefix_cache(lm):
         assert c.tokens == want(sfx), f"suffix {sfx} diverged"
         assert c.prompt_len == len(prefix) + len(sfx)
         assert len(c.logprobs) == 10          # generated region only
-
-    # speculative pool with a prefix: target AND draft ride their own
-    # prefix caches; greedy stays token-exact
-    spec = DecodeServer(model, params, slots=1, prompt_len=4, max_len=40,
-                        prefix=prefix, draft=(model, params), draft_len=3)
-    spec.submit([3, 1, 4], max_new=10)
-    assert spec.run_until_drained()[0].tokens == want([3, 1, 4])
 
     # int8 KV cache: prefix splice carries the scale leaves too
     m8 = dc.replace(model, kv_cache_dtype="int8")
@@ -734,8 +471,8 @@ def test_stop_sequences(lm):
     """Token-level stop sequences: the completion is the exact greedy
     rollout truncated at (and including) the earliest stop match in the
     GENERATED region; multi-sequence picks the earliest end; prompt-side
-    occurrences don't count; works on speculative pools (host-side
-    detection is mechanism-independent); unmatched stop = full length."""
+    occurrences don't count; works under fused dispatches (tokens decoded
+    past the stop are discarded); unmatched stop = full length."""
     model, params = lm
     prompt = [9, 21, 3]
     full = expected(model, params, prompt, 12)
@@ -745,12 +482,9 @@ def test_stop_sequences(lm):
     stop2 = [gen[4], gen[5]]
     want = full[:len(prompt) + 6]          # kept through the match
 
-    def serve(stop, draft=None, max_new=12):
-        kw = {}
-        if draft is not None:
-            kw = dict(draft=draft, draft_len=3)
+    def serve(stop, decode_steps=1, max_new=12):
         srv = DecodeServer(model, params, slots=2, prompt_len=4,
-                           max_len=48, **kw)
+                           max_len=48, decode_steps=decode_steps)
         rid = srv.submit(prompt, max_new=max_new, stop=stop)
         other = srv.submit(prompt, max_new=max_new)    # no-stop co-resident
         done = {c.id: c for c in srv.run_until_drained()}
@@ -772,8 +506,8 @@ def test_stop_sequences(lm):
     got3, _ = serve([[loner]])
     assert got3 == full
 
-    # speculative pool: same truncated stream
-    got4, other4 = serve([stop2], draft=(model, params))
+    # four tokens a dispatch: same truncated stream
+    got4, other4 = serve([stop2], decode_steps=4)
     assert got4 == want and other4 == full
 
     # a length-1 stop equal to the FIRST generated token (the
@@ -783,8 +517,8 @@ def test_stop_sequences(lm):
     got5, other5 = serve([[gen[0]]])
     assert got5 == full[:len(prompt) + 1], (got5, gen[0])
     assert other5 == full
-    # same case through the speculative pool (bigger per-dispatch bound)
-    got6, _ = serve([[gen[0]]], draft=(model, params))
+    # same case under four tokens a dispatch (a bigger bound)
+    got6, _ = serve([[gen[0]]], decode_steps=4)
     assert got6 == full[:len(prompt) + 1]
 
     with pytest.raises(ValueError, match="empty stop"):
@@ -798,8 +532,8 @@ def test_presence_frequency_penalties(lm):
     token-exact vs `generate` with the same penalties (the count
     bookkeeping agrees across tiers), a huge frequency penalty forbids
     any repeat, co-resident unpenalized rows are untouched, sampled
-    penalized streams are seed-reproducible, and the flag/spec guards
-    reject what they must."""
+    penalized streams are seed-reproducible, and the flag's guard
+    rejects what it must."""
     model, params = lm
     prompt = [3, 1, 4]
 
@@ -834,41 +568,10 @@ def test_presence_frequency_penalties(lm):
 
     assert sampled(11) == sampled(11)
 
-    # guards: penalized request needs the flag; spec pools reject the flag
+    # guard: a penalized request needs the flag
     off = DecodeServer(model, params, slots=1, prompt_len=4, max_len=24)
     with pytest.raises(ValueError, match="penalties"):
         off.submit(prompt, max_new=4, presence_penalty=0.5)
-    with pytest.raises(ValueError, match="speculative"):
-        DecodeServer(model, params, slots=1, prompt_len=4, max_len=24,
-                     penalties=True, draft=(model, params))
-
-
-def test_filtered_probs_top_k():
-    """filtered_probs: top_k keeps the k most probable (renormalized),
-    composes with the nucleus over the RENORMALIZED top-k distribution,
-    and k=0 / k>=vocab are the identity."""
-    import jax.numpy as jnp
-
-    from idunno_tpu.ops.sampling import filtered_probs, nucleus_probs
-
-    logits = jnp.log(jnp.asarray([[0.5, 0.3, 0.15, 0.05]]))
-    k2 = np.asarray(filtered_probs(logits, jnp.asarray([1.0]),
-                                   jnp.asarray([2])))[0]
-    assert np.allclose(k2, [0.625, 0.375, 0.0, 0.0], atol=1e-6)
-    off = np.asarray(filtered_probs(logits, jnp.asarray([1.0]),
-                                    jnp.asarray([0])))[0]
-    assert np.allclose(off, [0.5, 0.3, 0.15, 0.05], atol=1e-6)
-    big = np.asarray(filtered_probs(logits, jnp.asarray([1.0]),
-                                    jnp.asarray([99])))[0]
-    assert np.allclose(big, off, atol=1e-6)
-    # k=3 then top_p=0.6 on the renormalized {0.526, 0.316, 0.158}:
-    # nucleus = {0.526, 0.316} → 0.625/0.375
-    both = np.asarray(filtered_probs(logits, jnp.asarray([0.6]),
-                                     jnp.asarray([3])))[0]
-    assert np.allclose(both, [0.625, 0.375, 0.0, 0.0], atol=1e-4)
-    # pure-nucleus path unchanged by the refactor
-    nuc = np.asarray(nucleus_probs(logits, jnp.asarray([0.6])))[0]
-    assert np.allclose(nuc, [0.625, 0.375, 0.0, 0.0], atol=1e-6)
 
 
 def test_pool_top_k_sampling(lm):
@@ -900,31 +603,6 @@ def test_pool_top_k_sampling(lm):
         serve(-1)
 
 
-def test_speculative_top_k_requests_complete(lm):
-    """top_k on a speculative pool: q and p are both the k-filtered
-    distributions, so the rejection math carries over — completes,
-    seed-reproducible, greedy co-resident token-exact, and k=1 sampled
-    rows emit exactly the target's greedy stream through the spec path."""
-    model, params = lm
-    prompt = [3, 1, 4]
-
-    def run(top_k):
-        srv = DecodeServer(model, params, slots=2, prompt_len=4,
-                           max_len=40, draft=(model, params), draft_len=3)
-        rid_s = srv.submit(prompt, max_new=10, temperature=0.9,
-                           top_k=top_k, seed=7)
-        rid_g = srv.submit(prompt, max_new=10)
-        done = {c.id: c for c in srv.run_until_drained()}
-        return done[rid_s], done[rid_g]
-
-    s1, g1 = run(3)
-    s2, g2 = run(3)
-    assert g1.tokens == g2.tokens == expected(model, params, prompt, 10)
-    assert s1.tokens == s2.tokens
-    k1, _ = run(1)
-    assert k1.tokens == g1.tokens
-
-
 def test_pool_top_p_sampling(lm):
     """top_p in the pool: reproducible per seed, differs from top_p=1 on
     the same seed (the nucleus genuinely filters), greedy unaffected."""
@@ -950,104 +628,6 @@ def test_pool_top_p_sampling(lm):
         serve(0.0)
     with pytest.raises(ValueError, match="top_p"):
         serve(1.5)
-
-
-def test_speculative_top_p_requests_complete(lm):
-    """Nucleus-sampled requests on a speculative pool: q and p are both
-    the filtered distributions, so the rejection math carries over —
-    requests complete, are seed-reproducible, and greedy co-residents
-    stay token-exact."""
-    model, params = lm
-    prompt = [3, 1, 4]
-
-    def run():
-        srv = DecodeServer(model, params, slots=2, prompt_len=4,
-                           max_len=40, draft=(model, params), draft_len=3)
-        rid_s = srv.submit(prompt, max_new=10, temperature=0.9,
-                           top_p=0.8, seed=7)
-        rid_g = srv.submit(prompt, max_new=10)
-        done = {c.id: c for c in srv.run_until_drained()}
-        return done[rid_s], done[rid_g]
-
-    s1, g1 = run()
-    s2, g2 = run()
-    assert g1.tokens == g2.tokens == expected(model, params, prompt, 10)
-    assert s1.tokens == s2.tokens
-    assert len(s1.tokens) == len(prompt) + 10
-    assert all(0 <= t < VOCAB for t in s1.tokens)
-
-
-def _spec_commit_empirical(pf, qf, seed: int, gamma: int = 2,
-                           trials: int = 20_000) -> np.ndarray:
-    """Monte-Carlo distribution of the FIRST committed token when the
-    draft proposes from ``qf`` and the target distribution is ``pf``
-    (both already filtered identically) — the shared harness for the
-    filtered distribution-exactness tests."""
-    import jax
-    import jax.numpy as jnp
-
-    from idunno_tpu.engine.serve_lm import spec_commit
-
-    vocab = int(pf.shape[-1])
-
-    def one_trial(key):
-        ks = jax.random.split(key, 2 * gamma + 1)
-        props = jnp.stack([
-            jax.random.categorical(ks[j], jnp.log(qf + 1e-30))
-            for j in range(gamma)]).astype(jnp.int32)[None]
-        qd = jnp.broadcast_to(qf, (1, gamma, vocab))
-        pd = jnp.broadcast_to(pf, (1, gamma + 1, vocab))
-        tpred = jnp.argmax(pd, axis=-1).astype(jnp.int32)
-        u = jnp.stack([jax.random.uniform(ks[gamma + j])
-                       for j in range(gamma)])[None]
-        cand, _ = spec_commit(props, qd, pd, tpred,
-                              jnp.asarray([True]), u, ks[-1:][0][None])
-        return cand[0, 0]
-
-    toks = jax.jit(jax.vmap(one_trial))(
-        jax.random.split(jax.random.PRNGKey(seed), trials))
-    return np.bincount(np.asarray(toks), minlength=vocab) / trials
-
-
-def test_spec_commit_distribution_exact_with_nucleus():
-    """Distribution exactness under nucleus sampling: with q and p both
-    nucleus-FILTERED, the first committed token is distributed exactly as
-    the filtered target distribution."""
-    import jax.numpy as jnp
-
-    from idunno_tpu.ops.sampling import nucleus_probs
-
-    p_raw = jnp.log(jnp.asarray([0.05, 0.45, 0.10, 0.25, 0.15]))
-    q_raw = jnp.log(jnp.asarray([0.50, 0.05, 0.20, 0.05, 0.20]))
-    top_p = jnp.asarray([0.75])
-    pf = nucleus_probs(p_raw[None], top_p)[0]   # filtered target
-    qf = nucleus_probs(q_raw[None], top_p)[0]   # filtered draft
-
-    emp = _spec_commit_empirical(pf, qf, seed=1)
-    assert np.abs(emp - np.asarray(pf)).max() < 0.02, (emp, pf)
-    # tokens outside the nucleus are NEVER committed as the first token
-    assert emp[np.asarray(pf) == 0].max() == 0.0
-
-
-def test_spec_commit_distribution_exact_with_top_k():
-    """Distribution exactness under top-k (composed with a nucleus): with
-    q and p both run through the SAME filtered_probs, the first committed
-    token is distributed exactly as the filtered target distribution, and
-    k-excluded tokens are never committed."""
-    import jax.numpy as jnp
-
-    from idunno_tpu.ops.sampling import filtered_probs
-
-    p_raw = jnp.log(jnp.asarray([0.05, 0.45, 0.10, 0.25, 0.15]))
-    q_raw = jnp.log(jnp.asarray([0.50, 0.05, 0.20, 0.05, 0.20]))
-    top_p, top_k = jnp.asarray([0.9]), jnp.asarray([3])
-    pf = filtered_probs(p_raw[None], top_p, top_k)[0]
-    qf = filtered_probs(q_raw[None], top_p, top_k)[0]
-    assert (np.asarray(pf) == 0).sum() >= 2     # the filter genuinely cut
-
-    emp = _spec_commit_empirical(pf, qf, seed=2)
-    assert np.abs(emp - np.asarray(pf)).max() < 0.02, (emp, pf)
-    assert emp[np.asarray(pf) == 0].max() == 0.0
 
 
 def test_int8_kv_cache_pool_matches_its_own_generate(lm):
@@ -1092,7 +672,7 @@ def test_int8_kv_cache_pool_matches_its_own_generate(lm):
 
 def test_stats_reports_serving_config(lm):
     """`lm_stats` must tell an operator what the pool is actually running
-    (GQA width, cache dtype, weight quantization, speculative draft)."""
+    (GQA width, cache dtype, weight quantization)."""
     import dataclasses
 
     model, params = lm
@@ -1103,13 +683,9 @@ def test_stats_reports_serving_config(lm):
     assert cfg["kv_heads"] == 2 and cfg["heads"] == 4
     assert cfg["kv_cache_dtype"] == "int8"
     assert cfg["quantize"] == "int8"
-    assert cfg["speculative_draft_len"] is None
 
-    spec = DecodeServer(model, params, slots=1, prompt_len=4, max_len=20,
-                        draft=(model, params), draft_len=3)
-    cfg = spec.stats()["config"]
-    assert cfg["speculative_draft_len"] == 3
-    assert cfg["quantize"] == "none"
+    plain = DecodeServer(model, params, slots=1, prompt_len=4, max_len=20)
+    assert plain.stats()["config"]["quantize"] == "none"
 
 
 def test_handoff_lands_mid_serve_all_streams_exact(lm):
@@ -1308,8 +884,8 @@ def test_moe_pool_stays_unscanned_and_exact(lm):
 
 
 def test_warmup_pays_compiles_then_resets_the_pool(lm):
-    """`warmup()` runs a throwaway request through prefill+decode (and
-    the spec round, if any) so the one-time compile cost never lands in
+    """`warmup()` runs a throwaway request through prefill+decode so the
+    one-time compile cost never lands in
     a real request's service time or the fair-share signal — then resets
     ids and counters so the pool looks untouched. Streams after warm-up
     must match the `generate` oracle exactly (the warm-up must not leak

@@ -150,6 +150,22 @@ def test_shell_lm_and_train_commands(nodes):
         nodes_d["n1"].control.close()
 
 
+def test_lm_serve_answers_a_removed_option_and_sends_nothing():
+    """`draft=` is an option the shell no longer knows: the unknown-option
+    reply, and no `lm_serve` leaves the shell."""
+    from types import SimpleNamespace
+
+    sent = []
+    node = SimpleNamespace(control=SimpleNamespace(
+        _dispatch=lambda verb, payload: sent.append((verb, payload)) or {}))
+    sh = Shell(node, out=lambda _line: None)
+    assert sh.dispatch("lm-serve m 4 16 slots=2 draft=small draft_len=3") \
+        == "unknown lm-serve option(s): ['draft', 'draft_len']"
+    assert sent == []
+    assert "draft" not in sh.dispatch("lm-serve m")      # the usage text
+    assert "draft" not in sh.dispatch("help")
+
+
 def test_distributed_grep(nodes):
     cfg, net, nodes_d, tp = nodes
     # each node logs something distinctive through its own logger
