@@ -660,7 +660,13 @@ class Shell:
                     f"decode_steps={cfg['decode_steps']}"
                     + (f" n_model={cfg['n_model']} "
                        f"tp_bytes/step={cfg['tp_collective_bytes']}"
-                       if cfg.get("n_model", 1) > 1 else ""))
+                       if cfg.get("n_model", 1) > 1 else "")
+                    # the share of the slot cache's token axis the decode
+                    # steps read (1.00: every step read all of max_len)
+                    + (" context_read=%.2f" % (
+                        stats["decode_context_read"]
+                        / stats["decode_context_held"])
+                       if stats.get("decode_context_held") else ""))
 
         def prefix_line(stats: dict) -> str:
             pc = stats.get("prefix_cache")

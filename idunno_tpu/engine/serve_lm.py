@@ -43,8 +43,8 @@ import numpy as np
 from idunno_tpu.engine.generate import decode_model, init_cache
 from idunno_tpu.engine.kv_blocks import SLOT_LEAF_KEYS, concat_kv_prefix
 from idunno_tpu.models.hybrid import UnsupportedStack
-from idunno_tpu.models.transformer import (TransformerLM, decode_apply,
-                                           scan_compatible,
+from idunno_tpu.models.transformer import (TransformerLM, context_rungs,
+                                           decode_apply, scan_compatible,
                                            stack_block_params)
 from idunno_tpu.parallel.sharding import (sampling_collective_bytes,
                                           tp_collective_bytes)
@@ -813,6 +813,15 @@ class DecodeServer:
             self._stats.update(sparse_tokens_attended=0,
                                sparse_tokens_in_context=0,
                                prefix_skipped_recurrent=0)
+        # the context lengths the decode step can read
+        # (`MultiHeadAttention._decode_step`), None for a model that brings
+        # its own step; how far along the token axis the dispatches' steps
+        # read the slot cache, and how far it reaches: a token a slot a
+        # step (`_count_context`)
+        self._ladder = None
+        if getattr(model, "decode_apply", None) is None:
+            self._ladder = np.asarray(context_rungs(max_len))
+            self._stats.update(decode_context_read=0, decode_context_held=0)
         # flips True at the first decode dispatch and NEVER resets (the
         # warmup() stats reset must not re-mark a warmed pool cold):
         # requests admitted while False carry Request.cold → their
@@ -860,11 +869,26 @@ class DecodeServer:
     def _build_decode(self, n_steps: int):
         """The decode dispatch (`jit_run`): ``n_steps`` tokens for every
         slot, a `fori_loop` of (model step, fused sampling tail) over the
-        donated decode state."""
+        donated decode state.
+
+        The model step reads the slot cache as far as the deepest cursor
+        it is handed (`MultiHeadAttention._decode_step`'s context ladder),
+        so the cache is handed ``where(remaining > 0, cursors, 0)``: a dead
+        slot keeps the cursor of the request that left it and must not set
+        the bound. That is safe because a slot's rows have one reader, the
+        step itself, and only while the slot is live: a dead row writes
+        the K/V it computes (and nobody samples) at position 0 of its own
+        slot instead of at its stale cursor, the next `_insert` overwrites
+        the slot's rows from position 0 before the slot is live again, and
+        the block pool is written from prefill caches, never from a slot.
+        Tokens and the sampling tail keep the true cursors."""
         dec = self._dec
         track = self.track_logprobs     # static: traced once
         pen = self.penalties            # static: traced once
         paged = self._paged             # static: traced once
+        # a model that brings its own step reads what it reads, whatever
+        # the cursors: it is handed them as they are
+        bound_by_live = self._ladder is not None
 
         def run(params, tokens, cache, cursors, remaining, temps,
                 top_ps, top_ks, keys, logprobs, pres, freq, counts,
@@ -882,7 +906,10 @@ class DecodeServer:
             def body(_, carry):
                 (tokens, cache, cursors, remaining, keys, logprobs,
                  counts) = carry
-                cache = _set_cursors(cache, cursors)
+                # dead rows do not set how far the step reads (docstring)
+                cache = _set_cursors(
+                    cache, jnp.where(remaining > 0, cursors, 0)
+                    if bound_by_live else cursors)
                 tok = jnp.take_along_axis(tokens, cursors[:, None], axis=1)
                 # decode_apply: the scanned step (one lax.scan over the
                 # stacked layers, the cache its carry) on scan-compatible
@@ -1938,17 +1965,20 @@ class DecodeServer:
             parent = self._step_span.span_id
         return loop_span(self.spans, name, trace, parent, **attrs)
 
-    def _retire_synced(self, after: str, stops: bool = False) -> None:
-        """`_retire_finished`, after `_apply_stops` where a dispatch ran.
+    def _retire_synced(self, after: str, was=None) -> None:
+        """`_retire_finished`; where a dispatch ran (``was``: the cursors
+        the host held before it), after its counters and `_apply_stops`.
         With the cursors' host copy stale, the read-back returns only once
         the chip has finished everything enqueued before it: the one place
         in a step where the host waits for the device, and an
         `lm.step.sync` span (``after``: what made the copy stale)."""
         blocks = self._rc_cache is None and bool(self._live)
         with self._span("lm.step.sync", after=after) if blocks else NO_SPAN:
-            if stops:
+            if was is not None:
                 if self._recurrent:
                     self._count_attended()
+                if self._ladder is not None:
+                    self._count_context(was)
                 self._apply_stops()
             self._retire_finished()
 
@@ -1968,6 +1998,21 @@ class DecodeServer:
                     self.model.attended_tokens(ctx).sum())
                 self._seen_cursor[slot] = now
 
+    def _count_context(self, was: np.ndarray) -> None:
+        """After a dispatch: how far along the slot cache's token axis
+        each of its steps read (the rung that holds the deepest row live
+        at that step, as the program picks it: `context_rungs`) against
+        the whole axis, a token a slot a step. From the cursors the host
+        held before the dispatch and those it left: a row that advanced k
+        tokens was live in the first k steps."""
+        steps = np.arange(self.decode_steps)[:, None]
+        live = (self._remaining_cursors()[1] - was)[None, :] > steps
+        need = np.where(live, was[None, :] + steps, 0).max(axis=1) + 1
+        rung = self._ladder[np.searchsorted(self._ladder, need)]
+        self._stats["decode_context_read"] += int(rung.sum()) * self.slots
+        self._stats["decode_context_held"] += (
+            self.decode_steps * self.max_len * self.slots)
+
     def _step(self, st) -> int:
         admitted0 = self._stats["admitted"]
         self._retire_synced("cancel")
@@ -1983,6 +2028,7 @@ class DecodeServer:
         if self._live:
             pg = ((self._tables, self._plens,
                    self._block_pool.kv_pages()) if self._paged else ())
+            was = self._remaining_cursors()[1]    # the host's copy: no read
             with self._span("lm.decode_step", rows=rows) as sp:
                 for req in self._new_traced:
                     req.t_decode0 = sp.t_start
@@ -1997,7 +2043,7 @@ class DecodeServer:
             self._stats["dispatches"] += 1
             self._dispatched_ever = True
             self._rc_invalidate()         # the dispatch advanced the rows
-            self._retire_synced("dispatch", stops=True)
+            self._retire_synced("dispatch", was=was)
         self._new_traced.clear()
         if st is not None:
             st.attrs.update(

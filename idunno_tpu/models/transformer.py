@@ -95,6 +95,23 @@ def rope(x: jnp.ndarray, *, base: float = 10000.0,
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
+# the least tile of the decode step's context ladder: under it a shorter
+# read saves less than one more trip of the loop costs, and a cache no
+# longer than this has the one rung
+_MIN_TILE = 128
+
+
+def context_rungs(max_decode_len: int) -> tuple[int, ...]:
+    """The context lengths the per-row decode step can read, ascending:
+    whole tiles of an eighth of ``max_decode_len`` (of `_MIN_TILE` at
+    least), the last ``max_decode_len`` itself. A step reads the first
+    rung that holds every row it is handed
+    (`MultiHeadAttention._decode_step`), a tile at a time; a cache of
+    one rung is read whole, with no loop."""
+    tile = max(-(-max_decode_len // 8), _MIN_TILE)
+    return (*range(tile, max_decode_len, tile), max_decode_len)
+
+
 class MultiHeadAttention(nn.Module):
     """Pluggable-kernel attention; ``decode=True`` switches to single-token
     autoregressive serving with a KV cache in the flax "cache" collection
@@ -203,7 +220,19 @@ class MultiHeadAttention(nn.Module):
         attends over its slice of that leaf read where it lies — the
         slice is never a value the scan hands back. The arithmetic is the
         same either way: write, then attend over the cache that holds the
-        new token."""
+        new token.
+
+        The per-row step reads the LIVE context, not ``max_decode_len``:
+        with ``need = max(cursors) + t`` it reads the first rung of
+        `context_rungs` that holds ``need`` positions, for every row, a
+        tile at a time with a running softmax (the sum's order differs
+        from one softmax over the whole axis by float rounding; a masked
+        position weighs exactly 0 either way). The cursors it is handed
+        set the bound, so the caller hands a dead row 0
+        (`engine.serve_lm._build_decode`). The scalar-cursor shapes read
+        the whole axis at once, as ever: a prefill fills it, and
+        `engine.generate`'s step is the oracle the pool's streams are held
+        to."""
         if self.max_decode_len <= 0:
             raise ValueError("decode=True needs max_decode_len > 0")
         if not self.causal:
@@ -269,16 +298,20 @@ class MultiHeadAttention(nn.Module):
                 return var.value.at[sel].set(
                     jnp.where(ovr, var.value[sel], vals))
             nxt = i          # read, never advanced: the caller owns them
-            # [B, 1, t, T]: row r's chunk position j attends slots ≤ i[r]+j
-            ax = jnp.arange(self.max_decode_len)[None, None, :]
-            live = ax <= pos_bt[:, :, None]
-            if paged is not None:
-                # the paged interval is served through the block table —
-                # exclude it here so the merge never double-counts keys
-                live &= ~((ax >= paged.start)
-                          & (ax < paged.start + paged.lengths[:, None, None]))
-            mask = live[:, None, :, :]
+
+            def mask_of(lo, n):
+                # [B, 1, t, n]: row r's chunk position j attends slots
+                # ≤ i[r]+j, here those of [lo, lo + n)
+                ax = lo + jnp.arange(n)[None, None, :]
+                live = ax <= pos_bt[:, :, None]
+                if paged is not None:
+                    # the paged interval is served through the block table
+                    # — exclude it here so the merge never double-counts
+                    live &= ~((ax >= paged.start) & (
+                        ax < paged.start + paged.lengths[:, None, None]))
+                return live[:, None, :, :]
             poison = overflow[:, None, None, None, None]
+            rungs = context_rungs(self.max_decode_len)
         else:
             cur = self.variable("cache", "cursor",
                                 lambda: jnp.zeros((), jnp.int32))
@@ -311,6 +344,10 @@ class MultiHeadAttention(nn.Module):
                           & (ax < paged.start + paged.lengths[0]))
             mask = live[None, None, :, :]
             poison = overflow
+            rungs = (self.max_decode_len,)
+
+            def mask_of(lo, n):
+                return mask
         if quant:
             (k_st, k_sc), (v_st, v_sc) = q8(k), q8(v)
             written = [(ck, k_st), (cv, v_st), (ks, k_sc), (vs, v_sc)]
@@ -321,50 +358,112 @@ class MultiHeadAttention(nn.Module):
             for (var, _), leaf in zip(written, new):
                 var.value = leaf
             cur.value = nxt
-        if layer is not None:
-            # the layer's [B, T, ...] slice of the carried leaf, read where
-            # it lies: no copy of it is a value of the scan
-            new = [jax.lax.dynamic_index_in_dim(leaf, layer, 0,
-                                                keepdims=False)
-                   for leaf in new]
-        new_k, new_v, *scales = new
-        # grouped attention against the (possibly narrower) cache: query
-        # heads reshape to [.., kv_heads, group, d] so the einsum reads
-        # the small cache straight from HBM — no repeat materialization.
-        # group == 1 is exact MHA (identical contraction).
+
         group = h // kv_heads
-        if quant:
-            new_k = new_k.astype(jnp.float32) * scales[0][..., None]
-            new_v = new_v.astype(jnp.float32) * scales[1][..., None]
-        q5 = q.reshape(b, t, kv_heads, group, d)
-        # f32 casts on the operands: they FUSE into the dot reads (HBM
-        # traffic stays at the cache's stored width), and XLA:CPU's
-        # emulated-bf16 dots make a native-dtype einsum measurably slower
-        # in the test/dev loop — measured 2026-07-31, 103→116 ms/step
-        scores = jnp.einsum("bqhgd,bthd->bhgqt", q5.astype(jnp.float32),
-                            new_k.astype(jnp.float32)) / (d ** 0.5)
-        mask = mask[:, :, None]          # broadcast over the group axis
-        scores = jnp.where(poison, jnp.nan, scores)
-        scores = jnp.where(mask, scores, -jnp.inf)
-        if paged is None:
-            weights = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bhgqt,bthd->bqhgd", weights,
-                             new_v.astype(jnp.float32)).astype(self.dtype)
+
+        def span(lo, n):
+            """Positions [lo, lo + n) of this layer's rows: the grouped
+            queries, their masked float32 scores against the keys, and the
+            values."""
+            if n < self.max_decode_len:
+                if layer is None:
+                    part = [jax.lax.dynamic_slice_in_dim(leaf, lo, n, axis=1)
+                            for leaf in new]
+                else:
+                    part = [jax.lax.squeeze(jax.lax.dynamic_slice(
+                        leaf, (layer, 0, lo) + (0,) * (leaf.ndim - 3),
+                        (1, b, n) + leaf.shape[3:]), (0,)) for leaf in new]
+            elif layer is not None:
+                # the layer's [B, T, ...] slice of the carried leaf, read
+                # where it lies: no copy of it is a value of the scan
+                part = [jax.lax.dynamic_index_in_dim(leaf, layer, 0,
+                                                     keepdims=False)
+                        for leaf in new]
+            else:
+                part = new
+            new_k, new_v, *scales = part
+            # grouped attention against the (possibly narrower) cache: query
+            # heads reshape to [.., kv_heads, group, d] so the einsum reads
+            # the small cache straight from HBM — no repeat materialization.
+            # group == 1 is exact MHA (identical contraction).
+            if quant:
+                new_k = new_k.astype(jnp.float32) * scales[0][..., None]
+                new_v = new_v.astype(jnp.float32) * scales[1][..., None]
+            q5 = q.reshape(b, t, kv_heads, group, d)
+            # f32 casts on the operands: they FUSE into the dot reads (HBM
+            # traffic stays at the cache's stored width), and XLA:CPU's
+            # emulated-bf16 dots make a native-dtype einsum measurably slower
+            # in the test/dev loop — measured 2026-07-31, 103→116 ms/step
+            scores = jnp.einsum("bqhgd,bthd->bhgqt", q5.astype(jnp.float32),
+                                new_k.astype(jnp.float32)) / (d ** 0.5)
+            mask = mask_of(lo, n)[:, :, None]    # broadcast over the group
+            scores = jnp.where(poison, jnp.nan, scores)
+            scores = jnp.where(mask, scores, -jnp.inf)
+            return q5, scores, new_v
+
+        lse_l = None
+        if len(rungs) == 1:
+            q5, scores, new_v = span(0, rungs[0])
+            if paged is None:
+                weights = jax.nn.softmax(scores, axis=-1)
+                o_l = jnp.einsum("bhgqt,bthd->bqhgd", weights,
+                                 new_v.astype(jnp.float32))
+            else:
+                # explicit softmax so the local partial exposes its lse for
+                # the exact merge with the paged partial; the query's own
+                # chunk positions are always live locally, so m_l is finite
+                # (NaN poison still propagates — overflow stays loud)
+                m_l = jnp.max(scores, axis=-1, keepdims=True)
+                p_l = jnp.exp(scores - jax.lax.stop_gradient(m_l))
+                l_l = jnp.sum(p_l, axis=-1, keepdims=True)
+                # normalize BEFORE the value einsum — the exact op order of
+                # jax.nn.softmax + einsum above, so a row whose paged chain
+                # is empty reproduces the dense branch bit-for-bit
+                o_l = jnp.einsum("bhgqt,bthd->bqhgd", p_l / l_l,
+                                 new_v.astype(jnp.float32))
+                lse_l = jnp.transpose((m_l + jnp.log(l_l))[..., 0],
+                                      (0, 3, 1, 2))           # [b, t, kvh, g]
         else:
-            # explicit softmax so the local partial exposes its lse for
-            # the exact merge with the paged partial; the query's own
-            # chunk positions are always live locally, so m_l is finite
-            # (NaN poison still propagates — overflow stays loud)
-            m_l = jnp.max(scores, axis=-1, keepdims=True)
-            p_l = jnp.exp(scores - jax.lax.stop_gradient(m_l))
-            l_l = jnp.sum(p_l, axis=-1, keepdims=True)
-            # normalize BEFORE the value einsum — the exact op order of
-            # jax.nn.softmax + einsum above, so a row whose paged chain
-            # is empty reproduces the dense branch bit-for-bit
-            o_l = jnp.einsum("bhgqt,bthd->bqhgd", p_l / l_l,
-                             new_v.astype(jnp.float32))
-            lse_l = jnp.transpose((m_l + jnp.log(l_l))[..., 0],
-                                  (0, 3, 1, 2))           # [b, t, kvh, g]
+            # the context ladder, a tile at a time with a running softmax:
+            # as many tiles as the first rung that holds the deepest row's
+            # new tokens (an overflowing row takes them all and is
+            # poisoned). A masked position weighs exactly 0, so the tiles
+            # left unread change nothing. The loop reads the carried
+            # leaves where they lie; a `lax.switch` over the rungs had the
+            # compiler copy a whole leaf into every branch (PERF.md §6)
+            tile, top = rungs[0], self.max_decode_len
+
+            def one_tile(j, carry):
+                m, l, acc = carry
+                # the last rung may be no whole tile: its slice starts
+                # early, and leaves what the tile before it covered
+                lo = jnp.minimum(j * tile, top - tile)
+                _, scores, new_v = span(lo, tile)
+                fresh = lo + jnp.arange(tile) >= j * tile
+                scores = jnp.where(fresh, scores, -jnp.inf)
+                m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+                # a row with nothing live so far has m_new -inf
+                base = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+                p = jnp.exp(scores - base[..., None])
+                keep = jnp.exp(m - base)
+                return (m_new, l * keep + jnp.sum(p, axis=-1),
+                        acc * keep[..., None]
+                        + jnp.einsum("bhgqt,bthd->bhgqd", p,
+                                     new_v.astype(jnp.float32)))
+
+            stat = (b, kv_heads, group, t)
+            m_l, l_l, acc = jax.lax.fori_loop(
+                0, jnp.sum(jnp.max(i) + t > jnp.asarray(rungs[:-1])) + 1,
+                one_tile, (jnp.full(stat, -jnp.inf, jnp.float32),
+                           jnp.zeros(stat, jnp.float32),
+                           jnp.zeros(stat + (d,), jnp.float32)))
+            o_l = jnp.transpose(acc / l_l[..., None], (0, 3, 1, 2, 4))
+            if paged is not None:
+                q5 = q.reshape(b, t, kv_heads, group, d)
+                lse_l = jnp.transpose(m_l + jnp.log(l_l), (0, 3, 1, 2))
+        if paged is None:
+            out = o_l.astype(self.dtype)
+        else:
             o_p, lse_p = paged_attention_grouped(
                 q5.astype(jnp.float32), paged.k_pages, paged.v_pages,
                 paged.tables, paged.lengths,

@@ -26,8 +26,8 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 from idunno_tpu.engine.generate import init_cache
 from idunno_tpu.engine.kv_blocks import _WRITE_GROUP, _write_block
 from idunno_tpu.engine.serve_lm import _DECODE_DONATED, DecodeServer
-from idunno_tpu.models.transformer import (TransformerLM, decode_apply,
-                                           stack_block_params)
+from idunno_tpu.models.transformer import (TransformerLM, context_rungs,
+                                           decode_apply, stack_block_params)
 from idunno_tpu.ops.flash_attention import flash_attention
 from idunno_tpu.ops.paged_attention import paged_attention_grouped
 from idunno_tpu.ops.pallas_preprocess import preprocess_batch_pallas
@@ -312,6 +312,23 @@ def _in_place(compiled, cache_bytes: int, rows: int, temp_share=4):
     assert not _whole_slice_updates(compiled.as_text(), rows, _MAX_LEN)
 
 
+def _reads_by_the_tile(text: str, depth: int, rows: int):
+    """What ISSUE 32 holds `jit_run` to: a cache leaf is read a tile of the
+    context ladder at a time (`context_rungs`: a ``[1, rows, tile, kv,
+    128]`` slice of the stacked leaf), and nothing in the program but a
+    whole stacked leaf spans the token axis: no layer's ``[rows, 4096,
+    ...]`` slice is staged or copied, and no mask or score is that long."""
+    tile = context_rungs(_MAX_LEN)[0]
+    assert 1 < _MAX_LEN // tile <= 8
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\w+\[([\d,]+)\]", text)}
+    spans = sorted(d for d in shapes if len(d) > 2 and _MAX_LEN in d
+                   and d[:3] != (depth, rows, _MAX_LEN))
+    assert not spans, spans
+    assert [d for d in shapes
+            if len(d) == 5 and d[:3] == (1, rows, tile) and d[4] == 128]
+
+
 # s8 leaves with 2 kv heads: the chip lays the argument out with the
 # token axis minor (a tile holds 32 rows of a byte each: 2 heads would
 # pad 16 times) and the loop with the heads minor, so the dispatch copies
@@ -331,13 +348,15 @@ def test_decode_dispatch_updates_the_slot_cache_in_place(one_chip, widths,
                                                          quant):
     """The whole `jit_run` as `DecodeServer._build_decode` makes it (4
     steps in a `fori_loop` around the layer scan, with its donation), at
-    28 slots x 4096."""
+    28 slots x 4096: the cache updated where it lies, and read a tile of
+    the context ladder at a time."""
     srv, p_shapes = _pool_and_shapes(widths, quant)
     cache = _described(jax.eval_shape(
         lambda: init_cache(srv._dec, _SLOTS, _MAX_LEN)), one_chip)
     compiled = _compile_run(srv, _described(p_shapes, one_chip), cache,
                             one_chip)
     _in_place(compiled, _nbytes(cache), _SLOTS)
+    _reads_by_the_tile(compiled.as_text(), srv._dec.depth, _SLOTS)
 
 
 @pytest.mark.parametrize("widths,quant,rows,tokens", [
@@ -403,6 +422,7 @@ def test_tp_decode_dispatch_in_place_and_no_new_collectives(topo):
     # memory_analysis counts one device: half of the cache
     _in_place(compiled, _nbytes(cache) // 2, _SLOTS)
     text = compiled.as_text()
+    _reads_by_the_tile(text, srv._dec.depth, _SLOTS)
     counts = {op: len(re.findall(rf" {op}(?:-start)?\(", text))
               for op in _TP_COLLECTIVES}
     assert counts == _TP_COLLECTIVES, counts

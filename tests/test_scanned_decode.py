@@ -20,8 +20,9 @@ import numpy as np
 import pytest
 
 from idunno_tpu.engine.generate import decode_model, init_cache
-from idunno_tpu.models.transformer import (TransformerLM, decode_apply,
-                                           scan_compatible,
+from idunno_tpu.models import transformer
+from idunno_tpu.models.transformer import (TransformerLM, context_rungs,
+                                           decode_apply, scan_compatible,
                                            stack_block_params)
 from idunno_tpu.ops.quantize import dequantize_tree, quantize_tree
 
@@ -261,7 +262,8 @@ _SHAPES = {"per-row-step": (3, 1, (9, 13, 8)),
            "scalar-chunk": (1, 5, 9)}
 
 
-def _carried_case(shape: str, quant: bool, paged: bool, cursors=None):
+def _carried_case(shape: str, quant: bool, paged: bool, cursors=None,
+                  max_len: int = _MAX, kv_heads: int = 2):
     """(decode twin, stacked params, a cache full of noise with its cursors
     set, tokens, paged context or None)."""
     from idunno_tpu.ops.paged_attention import PagedContext
@@ -269,11 +271,11 @@ def _carried_case(shape: str, quant: bool, paged: bool, cursors=None):
     rows, t, cur = _SHAPES[shape]
     cur = cur if cursors is None else cursors
     model = TransformerLM(vocab=VOCAB, dim=32, depth=_DEPTH, num_heads=4,
-                          num_kv_heads=2,
+                          num_kv_heads=kv_heads,
                           kv_cache_dtype="int8" if quant else "native")
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
-    dec = dataclasses.replace(decode_model(model, _MAX), scan_layers=True,
+    dec = dataclasses.replace(decode_model(model, max_len), scan_layers=True,
                               decode_per_row=rows > 1)
     keys = iter(jax.random.split(jax.random.PRNGKey(7), 16))
 
@@ -285,7 +287,7 @@ def _carried_case(shape: str, quant: bool, paged: bool, cursors=None):
                                       jnp.int32).astype(jnp.int8)
         x = jax.random.normal(next(keys), leaf.shape, jnp.float32)
         return jnp.abs(x) / 64 if leaf.ndim == 4 else x   # scales: 4-D
-    cache = jax.tree.map(noise, init_cache(dec, rows, _MAX))
+    cache = jax.tree.map(noise, init_cache(dec, rows, max_len))
     ctx = None
     if paged:
         store = cache["attn"]
@@ -401,3 +403,93 @@ def test_overflow_leaves_the_carried_cache_untouched(shape, cursors, quant):
         assert not mask[:, over].any()
         if (~over).any():
             assert (after[mask] != before[mask]).any()
+
+
+# -- the context ladder: the per-row step reads the deepest row's rung --------
+
+@pytest.mark.parametrize("max_len,want", [
+    (4096, (512, 1024, 1536, 2048, 2560, 3072, 3584, 4096)),
+    (512, (128, 256, 384, 512)),
+    (300, (128, 256, 300)), (128, (128,)), (24, (24,)), (1, (1,))])
+def test_context_rungs_ascend_to_max_len(max_len, want):
+    """Whole tiles of an eighth of the cache (128 at least), the last rung
+    the cache itself; a cache of one tile or less has the one rung."""
+    rungs = context_rungs(max_len)
+    assert rungs == want
+    assert list(rungs) == sorted(set(rungs)) and rungs[-1] == max_len
+
+
+_LADDER = {"native-gqa": (False, False, 2), "native-mha": (False, False, 4),
+           "int8-gqa": (True, False, 2), "int8-mha": (True, False, 4),
+           "paged-native": (False, True, 2), "paged-int8": (True, True, 2)}
+
+
+def _whole_axis(fn, *args):
+    """``fn`` traced with a one-rung ladder: the per-row step reads the
+    whole token axis in one piece, which is what it did before it had a
+    ladder."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(transformer, "context_rungs", lambda n: (n,))
+        return jax.jit(fn)(*args)
+
+
+@pytest.mark.parametrize("shape", ["per-row-step", "per-row-chunk"])
+@pytest.mark.parametrize("kind", list(_LADDER))
+@pytest.mark.parametrize("max_len,deepest", [
+    (512, 9), (512, 127), (512, 128), (512, 300), (512, 400), (512, 500),
+    (300, 200), (300, 290)])
+def test_every_rung_answers_as_the_whole_axis(max_len, deepest, kind, shape):
+    """Rows placed so that each rung is taken in turn (the deepest row
+    just under a rung, on it, in the last rung that is no whole tile):
+    logits and cache equal those of the same step reading the whole axis
+    at once. The rows behind the deepest are shallower, one of them with a
+    paged chain longer than its first tile holds live."""
+    quant, paged, kv = _LADDER[kind]
+    t = _SHAPES[shape][1]
+    deepest = min(deepest, max_len - t)
+    cursors = (9, deepest, 140 if deepest > 140 else 8)
+    dec, params, cache, tok, ctx = _carried_case(
+        shape, quant, paged, cursors=cursors, max_len=max_len, kv_heads=kv)
+    rungs = context_rungs(max_len)
+    assert len(rungs) > 1
+
+    def step(p, c, t_, g):
+        return decode_apply(dec, p, c, t_, paged=g)
+    want_logits, want = _whole_axis(step, params, cache, tok, ctx)
+    logits, new = jax.jit(step)(params, cache, tok, ctx)
+    assert np.isfinite(np.asarray(logits)).all()
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits),
+                               rtol=2e-5, atol=2e-5)
+    for name, ref in want["attn"].items():
+        np.testing.assert_allclose(
+            np.asarray(new["attn"][name]).astype(np.float32),
+            np.asarray(ref).astype(np.float32), rtol=1e-5,
+            atol=1.0 if ref.dtype == jnp.int8 else 1e-5)
+
+
+def test_the_ladder_reads_no_further_than_the_deepest_row():
+    """Noise past the deepest row's rung does not reach the logits, NaN
+    there included: the tiles beyond the rung are not read at all (a
+    masked read of a NaN key would still poison the product)."""
+    dec, params, cache, tok, _ = _carried_case(
+        "per-row-step", False, False, cursors=(9, 200, 140), max_len=512)
+    logits, _ = decode_apply(dec, params, cache, tok)
+
+    def beyond(leaf):
+        if leaf.dtype == jnp.int32:
+            return leaf
+        return leaf.at[:, :, 256:].set(jnp.nan)
+    poisoned, _ = decode_apply(dec, params, jax.tree.map(beyond, cache), tok)
+    np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(logits))
+
+
+def test_the_per_layer_loop_takes_the_ladder_too():
+    """The flax per-layer loop (models with an `ffn_factory`) runs the same
+    branch with no stacked leaf: its logits on a middle rung are the
+    scan's."""
+    dec, params, cache, tok, _ = _carried_case(
+        "per-row-step", False, False, cursors=(9, 300, 140), max_len=512)
+    want_logits, _ = _per_layer_loop(dec, params, cache, tok, None)
+    logits, _ = decode_apply(dec, params, cache, tok)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits),
+                               rtol=1e-5, atol=1e-5)

@@ -1130,3 +1130,135 @@ def test_tp_rejects_bad_shapes(lm, eight_devices):
     with pytest.raises(ValueError, match="scanned"):
         DecodeServer(moe_like, params, slots=2, prompt_len=4, max_len=8,
                      n_model=2)
+
+
+# -- the context ladder: a dispatch reads the deepest live row's rung ---------
+
+_LADDER_POOLS = {
+    "native": {},
+    "int8": {"kv_cache_dtype": "int8"},
+    "gqa": {"num_kv_heads": 2},
+    "radix-gathered": {"pool": {"kv_block_size": 4, "kv_cache_blocks": 160}},
+    "radix-paged": {"pool": {"kv_block_size": 4, "kv_cache_blocks": 160,
+                             "paged_kernel": "xla"}},
+}
+
+
+def _ladder_pool(lm, kind, **kw):
+    import dataclasses
+
+    model, params = lm
+    spec = dict(_LADDER_POOLS[kind])
+    pool_kw = spec.pop("pool", {})
+    model = dataclasses.replace(model, **spec)
+    if "num_kv_heads" in spec:                    # narrower K/V kernels
+        params = model.init(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    kw = dict(dict(slots=3, prompt_len=320, max_len=512, decode_steps=4,
+                   prompt_buckets=(8, 320)), **pool_kw, **kw)
+    return model, params, DecodeServer(model, params, **kw)
+
+
+@pytest.mark.parametrize("kind", list(_LADDER_POOLS))
+def test_rows_on_every_rung_serve_generates_streams(lm, kind):
+    """max_len 512 has four rungs of 128. Rows that start at depths 3, 130
+    and 300 and grow across rung borders (one of them from 126 to 140)
+    draw, token for token, what `generate` draws with its one read of the
+    whole axis; the int8 pool, whose streams may drift from the
+    native-cache model's, is held to its own one-rung self."""
+    from idunno_tpu.models import transformer
+
+    rng = np.random.default_rng(5)
+    reqs = [([int(t) for t in rng.integers(0, VOCAB, size=n)], m)
+            for n, m in [(3, 9), (126, 14), (300, 90), (130, 6), (250, 12)]]
+    if "radix" in kind:           # a shared head, so that later rows hit
+        head = reqs[1][0][:64]
+        reqs = [(head + p[64:] if len(p) > 64 else p, m) for p, m in reqs]
+
+    def serve():
+        model, params, srv = _ladder_pool(lm, kind)
+        ids = {srv.submit(p, m): (p, m) for p, m in reqs}
+        return model, params, srv, ids, srv.run_until_drained()
+    model, params, srv, ids, done = serve()
+    stats = srv.stats()
+    assert 0 < stats["decode_context_read"] < stats["decode_context_held"]
+    if kind == "int8":
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(transformer, "context_rungs", lambda n: (n,))
+            want = {c.id: c.tokens for c in serve()[-1]}
+    else:
+        want = {rid: expected(model, params, p, m)
+                for rid, (p, m) in ids.items()}
+    assert {c.id: c.tokens for c in done} == want
+
+
+def test_slot_reused_after_a_deep_request_serves_a_fresh_pools_stream(lm):
+    """A dead slot is handed cursor 0, so the step writes its discarded
+    K/V at position 0 of that slot and the slot's old rows stay as the
+    deep request left them. The next request admitted there must draw
+    what a fresh pool draws, while the other slot decodes on."""
+    model, params, srv = _ladder_pool(lm, "native", slots=2)
+    rng = np.random.default_rng(9)
+    deep = [int(t) for t in rng.integers(0, VOCAB, size=310)]
+    other = [int(t) for t in rng.integers(0, VOCAB, size=5)]
+    late = [int(t) for t in rng.integers(0, VOCAB, size=6)]
+    rid_deep = srv.submit(deep, max_new=10)
+    rid_other = srv.submit(other, max_new=60)
+    done = {}
+    while rid_deep not in done:                   # the deep row retires...
+        srv.step()
+        done.update((c.id, c) for c in srv.poll())
+    for _ in range(3):                            # ...its slot idles, dead,
+        srv.step()                                # beside a live row
+    rid_late = srv.submit(late, max_new=20)       # and is taken again
+    done.update((c.id, c) for c in srv.run_until_drained())
+    for rid, (p, m) in {rid_deep: (deep, 10), rid_other: (other, 60),
+                        rid_late: (late, 20)}.items():
+        assert done[rid].tokens == expected(model, params, p, m)
+    fresh = _ladder_pool(lm, "native", slots=2)[2]
+    fresh.submit(late, max_new=20)
+    assert fresh.run_until_drained()[0].tokens == done[rid_late].tokens
+
+
+def test_a_row_that_ends_mid_dispatch_stops_holding_the_bound(lm):
+    """Four steps a dispatch; a row at depth 300 with two tokens left and a
+    row at depth 5: the first two steps read the third rung (384), the
+    last two the first (128): the dead row's stale cursor no longer
+    counts. Then the shallow row alone: every step the first rung."""
+    model, params, srv = _ladder_pool(lm, "native", slots=2)
+    rng = np.random.default_rng(3)
+    deep = [int(t) for t in rng.integers(0, VOCAB, size=300)]
+    srv.submit(deep, max_new=3)        # one token at admission, two left
+    srv.submit([7, 8, 9, 10, 11], max_new=12)
+    srv.step()
+    s = srv.stats()
+    assert s["dispatches"] == 1
+    assert s["decode_context_held"] == 4 * 512 * 2
+    assert s["decode_context_read"] == (2 * 384 + 2 * 128) * 2
+    srv.step()
+    s = srv.stats()
+    assert s["decode_context_read"] == (2 * 384 + 6 * 128) * 2
+    done = srv.run_until_drained()
+    assert sorted(len(c.tokens) for c in done) == [17, 303]
+
+
+def test_context_counters_read_one_for_a_row_at_the_end_of_the_cache(lm):
+    """A pool of short rows reads a share well under 1; one row at
+    ``max_len - decode_steps`` puts every step on the last rung: 1.0. A
+    stack that brings its own step has no such counters."""
+    model, params, srv = _ladder_pool(lm, "native")
+    for n in (3, 5, 8):
+        srv.submit(list(range(1, n + 1)), max_new=9)
+    srv.run_until_drained()
+    s = srv.stats()
+    assert s["decode_context_read"] / s["decode_context_held"] == 0.25
+
+    model, params, srv = _ladder_pool(lm, "native", prompt_len=507,
+                                      prompt_buckets=(8, 507))
+    rng = np.random.default_rng(1)
+    srv.submit([int(t) for t in rng.integers(0, VOCAB, size=507)],
+               max_new=5)                        # cursor 507 = 512 - 4 - 1
+    srv.run_until_drained()
+    s = srv.stats()
+    assert s["dispatches"] == 1
+    assert s["decode_context_read"] == s["decode_context_held"] > 0
