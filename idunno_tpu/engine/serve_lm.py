@@ -42,7 +42,7 @@ import numpy as np
 
 from idunno_tpu.engine.generate import decode_model, init_cache
 from idunno_tpu.engine.kv_blocks import SLOT_LEAF_KEYS, concat_kv_prefix
-from idunno_tpu.models.hybrid import UnsupportedStack
+from idunno_tpu.models.hybrid import SPARSE, UnsupportedStack
 from idunno_tpu.models.transformer import (TransformerLM, context_rungs,
                                            decode_apply, scan_compatible,
                                            stack_block_params)
@@ -191,17 +191,25 @@ class Completion:
 _DECODE_DONATED = (1, 2, 3, 4, 8, 9, 12)
 
 
+def _set_leaf(cache: Any, key: str, value) -> Any:
+    """Overwrite every leaf named ``key`` with ``value``, broadcast to the
+    leaf's shape and cast to its type; a cache with no such leaf comes
+    back as it is."""
+    def f(path, leaf):
+        if path and getattr(path[-1], "key", None) == key:
+            return jnp.broadcast_to(jnp.asarray(value, leaf.dtype),
+                                    leaf.shape)
+        return leaf
+    return jax.tree_util.tree_map_with_path(f, cache)
+
+
 def _set_cursors(cache: Any, cursors: jnp.ndarray) -> Any:
     """Overwrite every per-layer ``cursors`` leaf with the server's single
     source of truth (the layers never disagree; per-row cursors are
     caller-owned — `MultiHeadAttention._decode_step`). Broadcast covers
     both layouts: per-block [S] leaves and the scanned cache's [L, S]
     stacked leaf."""
-    def f(path, leaf):
-        if path and getattr(path[-1], "key", None) == "cursors":
-            return jnp.broadcast_to(cursors, leaf.shape)
-        return leaf
-    return jax.tree_util.tree_map_with_path(f, cache)
+    return _set_leaf(cache, "cursors", cursors)
 
 
 def _set_valid(cache: Any, n) -> Any:
@@ -209,11 +217,7 @@ def _set_valid(cache: Any, n) -> Any:
     scalar ``valid`` leaf of a stack with recurrent layers
     (`models/hybrid.py`), which keeps a bucket's padding out of the state
     it carries. A cache with no such leaf comes back as it is."""
-    def f(path, leaf):
-        if path and getattr(path[-1], "key", None) == "valid":
-            return jnp.broadcast_to(jnp.asarray(n, jnp.int32), leaf.shape)
-        return leaf
-    return jax.tree_util.tree_map_with_path(f, cache)
+    return _set_leaf(cache, "valid", n)
 
 
 @partial(jax.jit, static_argnames=("model", "prompt_len"))
@@ -237,12 +241,7 @@ def _set_scalar_cursor(cache: Any, value) -> Any:
     """Overwrite the scalar ``cursor`` leaves of a batch-1 decode cache
     (the chunked-prefill twin of `_set_cursors`; broadcast covers the
     scanned cache's [L] stacked cursor leaf)."""
-    def f(path, leaf):
-        if path and getattr(path[-1], "key", None) == "cursor":
-            return jnp.broadcast_to(jnp.asarray(value, jnp.int32),
-                                    leaf.shape)
-        return leaf
-    return jax.tree_util.tree_map_with_path(f, cache)
+    return _set_leaf(cache, "cursor", value)
 
 
 @partial(jax.jit, static_argnames=("model", "prefix_len", "prompt_len"))
@@ -448,6 +447,16 @@ def _insert(tokens: jnp.ndarray, cache: Any, row_cache: Any,
     row = row.at[true_len].set(first_tok)
     tokens = tokens.at[slot].set(row)
     return tokens, _splice_rows(cache, row_cache, slot, stacked)
+
+
+@jax.jit
+def _expert_counters(cache: Any) -> tuple[list, list]:
+    """Copies of an expert stack's counters, a run of layers each: what
+    `DecodeServer.stats()` reads while the cache they came from is already
+    donated to the next dispatch."""
+    runs = [v for k, v in cache.items() if k.startswith("run")]
+    return ([jnp.copy(r["expert_load"]) for r in runs],
+            [jnp.copy(r["expert_steps"]) for r in runs])
 
 
 class DecodeServer:
@@ -806,13 +815,21 @@ class DecodeServer:
         # hybrid stacks only: each live slot's cursor as the host last saw
         # it, from which a dispatch's contexts are counted without a read
         # of the device; tokens its sparse layers' queries attended and had
-        # in context, summed over live rows and decode steps; admissions
-        # that skipped the radix lookup
+        # in context, summed over live rows and decode steps (a stack with
+        # block-sparse layers alone: the model says which kinds it has);
+        # admissions that skipped the radix lookup
         self._seen_cursor: dict[int, int] = {}
+        self._sparse = self._recurrent and model.has(SPARSE)
         if self._recurrent:
+            self._stats["prefix_skipped_recurrent"] = 0
+        if self._sparse:
             self._stats.update(sparse_tokens_attended=0,
-                               sparse_tokens_in_context=0,
-                               prefix_skipped_recurrent=0)
+                               sparse_tokens_in_context=0)
+        # an expert stack's counters (`expert_load`, `expert_steps`: the
+        # slot cache's leaves without a slot axis) as the last dispatch left
+        # them: a copy on the device, because the cache itself is donated to
+        # the next dispatch while another thread may be asking `stats()`
+        self._expert_counts = None
         # the context lengths the decode step can read
         # (`MultiHeadAttention._decode_step`), None for a model that brings
         # its own step; how far along the token axis the dispatches' steps
@@ -910,6 +927,10 @@ class DecodeServer:
                 cache = _set_cursors(
                     cache, jnp.where(remaining > 0, cursors, 0)
                     if bound_by_live else cursors)
+                # an expert stack routes a dead row nowhere and counts
+                # the live ones (`models/hybrid.py`); no other cache has
+                # the leaf
+                cache = _set_leaf(cache, "live", remaining > 0)
                 tok = jnp.take_along_axis(tokens, cursors[:, None], axis=1)
                 # decode_apply: the scanned step (one lax.scan over the
                 # stacked layers, the cache its carry) on scan-compatible
@@ -1154,9 +1175,31 @@ class DecodeServer:
                    config=config)
         if self._recurrent:
             out["recurrent_state_bytes"] = self.model.state_bytes(self.slots)
+        if self._expert_counts is not None:
+            out.update(self._expert_stats())
         if self._radix is not None:
             out["prefix_cache"] = self.prefix_cache_stats()
         return out
+
+    def _expert_stats(self) -> dict:
+        """An expert stack's counters over the decode steps so far, read
+        from the device now: the token-picks that fell on the experts held
+        here and those offered (live tokens x experts a token, a layer);
+        the picks on the busiest held expert of each layer, summed, and the
+        mean over a layer's held experts, summed; the held experts that
+        took a pick, summed over steps and layers, and how many there were
+        to take one."""
+        load, steps = (np.concatenate([np.asarray(x, np.int64) for x in c])
+                       for c in self._expert_counts)     # [layers, ...]
+        held = load.shape[1]
+        return {
+            "expert_tokens_routed": int(load.sum()),
+            "expert_tokens_offered": int(
+                steps[:, 2].sum() * self.model.experts_per_token),
+            "expert_load_max": int(load.max(axis=1).sum()),
+            "expert_load_mean": float(load.mean(axis=1).sum()),
+            "experts_touched": int(steps[:, 0].sum()),
+            "experts_touchable": int(steps[:, 1].sum() * held)}
 
     def prefix_cache_stats(self) -> dict:
         """Radix prefix-cache gauges (only meaningful on kv_block_size
@@ -1853,8 +1896,8 @@ class DecodeServer:
             first, jnp.int32(true_len), jnp.int32(slot), bucket,
             stacked=self._scan)
         if self._recurrent:
-            # the row's state and pooled keys went into the slot with its
-            # K/V, whole: nothing of the slot's last tenant is left
+            # the row's state, window and pooled keys went into the slot
+            # with its K/V, whole: nothing of the slot's last tenant is left
             self._seen_cursor[slot] = true_len
             self._child_span(span, "state.splice", t_splice,
                              state_bytes=self.model.state_bytes(1))
@@ -1975,7 +2018,7 @@ class DecodeServer:
         blocks = self._rc_cache is None and bool(self._live)
         with self._span("lm.step.sync", after=after) if blocks else NO_SPAN:
             if was is not None:
-                if self._recurrent:
+                if self._sparse:
                     self._count_attended()
                 if self._ladder is not None:
                     self._count_context(was)
@@ -2042,6 +2085,8 @@ class DecodeServer:
                     self._counts, *pg)
             self._stats["dispatches"] += 1
             self._dispatched_ever = True
+            if "live" in self._cache:     # an expert stack: see __init__
+                self._expert_counts = _expert_counters(self._cache)
             self._rc_invalidate()         # the dispatch advanced the rows
             self._retire_synced("dispatch", was=was)
         self._new_traced.clear()

@@ -1,8 +1,8 @@
-"""A hybrid LM stack described by data: layers of two kinds in any order.
+"""A hybrid LM stack described by data: layers of several kinds in any order.
 
 `TransformerLM` is one block repeated, one cache shape a layer. This module
 serves stacks whose layers differ in kind (`mixers`, one name a layer) and
-whose caches differ with them:
+whose caches differ with them. Four kinds of mixer:
 
   ``minicpm4``        block-sparse softmax attention (InfLLM-v2): grouped
                       query heads without RoPE over `cached_k`/`cached_v`,
@@ -16,11 +16,29 @@ whose caches differ with them:
   ``lightning-attn``  decayed linear attention: a float32 `state`
                       [heads, d, d] a row, `S_t = exp(-s) S_{t-1} + k_t^T
                       v_t`, `o_t = q_t S_t / sqrt(d)`; no token axis.
+  ``mamba2``          a state-space layer (Mamba-2): one in-projection
+                      split into gate / convolution input / step sizes, a
+                      depthwise causal convolution whose last `ssm_conv - 1`
+                      inputs are the row's `conv` window, the selective
+                      scan `S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t`,
+                      `y_t = S_t C_t + D x_t` over a float32 `state`
+                      [heads, P, N] a row (in chunks of `ssm_chunk` tokens
+                      in prefill, one update a decode step), a gated
+                      RMSNorm, the out-projection; no token axis.
+  ``attention``       plain grouped-query softmax attention over
+                      `cached_k`/`cached_v`: no positional embedding, no
+                      q/k norm, no gate, no selection, scale `attn_scale`.
 
-Both sit in one pre-norm block (RMSNorm, bias-free projections, q/k
-RMSNorm, sigmoid output gate, gated SiLU MLP) with muP multipliers
-(`scale_emb`, `scale_depth / sqrt(published depth)`, logits over
-`logit_div`). Consecutive layers of one kind are ONE `lax.scan` over their
+and two kinds of feed-forward, one a stack (`ffn`): ``dense``, a gated SiLU
+MLP, and ``moe``, routed experts of which this chip holds a share
+(`models.moe.routed_experts`: dropless top-k over the whole router, the
+held experts' terms alone) plus a shared expert every token takes.
+
+The first two mixers sit in MiniCPM's pre-norm block (RMSNorm, bias-free
+projections, q/k RMSNorm, sigmoid output gate); every layer is `h += a *
+Mixer(RMSNorm(h)); h += a * FFN(RMSNorm(h))` with `a = scale_depth /
+sqrt(published_depth)`, the embedding times `scale_emb` and the logits over
+`logit_div`. Consecutive layers of one kind are ONE `lax.scan` over their
 stacked parameters and cache, so a stack of 1 + 6 + 2 + 3 layers is four
 scans in one program, not twelve dispatches.
 
@@ -30,10 +48,17 @@ description, `init_cache` and `decode_apply` are its two entry points, and
 to them, so `engine/generate.py` and `DecodeServer` stay layout-blind. The
 cache follows the scanned layout's rules (leaves named by kind, depth
 leading, the slot axis second): `cached_k`/`cached_v` [L, B, T, kvh, d],
-`comp_k` [L, B, T/stride, kvh, d] float32, `state` [L, B, H, d, d] float32,
-one `cursor`/`cursors` leaf and, in the scalar-cursor (prefill) shape, one
+`comp_k` [L, B, T/stride, kvh, d] float32, `state` [L, B, H, d, d] or
+[L, B, H, P, N] float32, `conv` [L, B, ssm_conv - 1, width], one
+`cursor`/`cursors` leaf and, in the scalar-cursor (prefill) shape, one
 `valid` leaf: positions at or past it are padding and enter neither the
-state nor the pooled keys.
+state, the window nor the pooled keys. A per-row (decode) cache of a
+``moe`` stack also carries `live` [B] (the rows that hold a request: the
+dispatch sets it, a dead row is routed nowhere) and, a run, the counters
+`expert_load` [L, held] and `expert_steps` [L, 3] (int32: picks on each
+held expert; held experts touched, steps and tokens, summed over the steps
+that had a live row). They have no slot axis and are read when
+`DecodeServer.stats()` is.
 """
 from __future__ import annotations
 
@@ -44,18 +69,32 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from idunno_tpu.models.moe import routed_experts
 from idunno_tpu.models.transformer import rope
 
 SPARSE, LINEAR = "minicpm4", "lightning-attn"
+MAMBA, ATTENTION = "mamba2", "attention"
+DENSE, MOE = "dense", "moe"
 _HI = jax.lax.Precision.HIGHEST
 _NO_LIMIT = np.iinfo(np.int32).max
 _LIN_BLOCK = 256        # tokens a lightning sub-block (intra-chunk product)
 _KEY_TILE = 1024        # keys a tile of the chunked sparse prefill
+_QUERY_TILE = 512       # queries a tile of the plain attention's prefill
+# below this many tokens an expert layer multiplies every token by every
+# held expert; from it on, tokens are grouped by expert (`routed_experts`)
+_GROUP_FROM = 256
+# the cache leaves of a run that ride through its scan as carry, whole and
+# depth-stacked, and are written where they lie
+_CARRIED = {ATTENTION: ("cached_k", "cached_v"), MAMBA: ("state",)}
+# the parameters of a run that its scan does not slice a layer: the routed
+# experts, which the grouped product reads from the whole stack
+_WHOLE = ("w1", "w2")
 
 
 class UnsupportedStack(ValueError):
     """A serving feature that a stack with recurrent or block-sparse layers
-    cannot use yet (a radix hit restores keys and values only)."""
+    cannot use yet (a radix hit restores keys and values only), or a shape
+    of such a stack that this module does not run."""
 
 
 @dataclass(frozen=True)
@@ -63,14 +102,14 @@ class HybridLM:
     vocab: int
     dim: int
     mlp_dim: int
-    mixers: tuple            # one of SPARSE / LINEAR a layer
+    mixers: tuple            # one of SPARSE / LINEAR / MAMBA / ATTENTION
     layer_ids: tuple         # each layer's PUBLISHED index (its slopes)
     published_depth: int
-    num_heads: int           # sparse layers: query heads
-    num_kv_heads: int        # sparse layers: KV heads
+    num_heads: int           # sparse and attention layers: query heads
+    num_kv_heads: int        # sparse and attention layers: KV heads
     head_dim: int
-    lightning_heads: int
-    lightning_head_dim: int
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
     scale_emb: float = 1.0
     scale_depth: float = 1.0
     logit_div: float = 1.0   # hidden_size / dim_model_base
@@ -83,6 +122,19 @@ class HybridLM:
     init_blocks: int = 1
     window_size: int = 2048
     dense_len: int = 8192
+    attn_scale: float | None = None    # attention: None = 1 / sqrt(d)
+    ssm_heads: int = 0       # mamba2: heads H, each of `ssm_head_dim` (P)
+    ssm_head_dim: int = 0
+    ssm_state: int = 0       # N
+    ssm_groups: int = 1      # B and C are shared by the heads of a group
+    ssm_conv: int = 4        # taps of the depthwise convolution
+    ssm_chunk: int = 256     # tokens a chunk of the prefill scan
+    ffn: str = DENSE         # DENSE (width `mlp_dim`) or MOE
+    experts: int = 0         # moe: the router's width (all the experts)
+    experts_per_token: int = 0
+    experts_held: tuple = (0, 0)       # (first, count) held by this chip
+    shared_dim: int = 0      # the shared expert's width; `mlp_dim` is a
+    #                          routed expert's
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     decode: bool = False
@@ -97,15 +149,34 @@ class HybridLM:
     def __post_init__(self):
         if len(self.mixers) != len(self.layer_ids):
             raise ValueError("one published index a layer")
-        bad = set(self.mixers) - {SPARSE, LINEAR}
+        bad = set(self.mixers) - {SPARSE, LINEAR, MAMBA, ATTENTION}
         if bad:
             raise ValueError(f"unknown mixer kinds {sorted(bad)}")
-        if (self.block_size % self.kernel_stride
-                or self.kernel_size % self.kernel_stride):
+        if self.ffn not in (DENSE, MOE):
+            raise ValueError(f"unknown feed-forward kind {self.ffn!r}")
+        if self.has(SPARSE) and (self.block_size % self.kernel_stride
+                                 or self.kernel_size % self.kernel_stride):
             raise ValueError("block_size and kernel_size must be multiples "
                              "of kernel_stride")
-        if self.num_heads % self.num_kv_heads:
+        if ((self.has(SPARSE) or self.has(ATTENTION))
+                and self.num_heads % self.num_kv_heads):
             raise ValueError("query heads must be a multiple of KV heads")
+        if self.has(MAMBA) and self.ssm_heads % self.ssm_groups:
+            raise ValueError("state-space heads must be a multiple of "
+                             "their groups")
+        if self.ffn == MOE:
+            first, held = self.experts_held
+            if not (0 <= first and 0 < held
+                    and first + held <= self.experts):
+                raise ValueError(
+                    f"experts_held {self.experts_held} is no share of "
+                    f"{self.experts} experts")
+            if not 0 < self.experts_per_token <= self.experts:
+                raise ValueError("experts_per_token must be 1..experts")
+
+    def has(self, kind: str) -> bool:
+        """Whether the stack has a layer of ``kind``."""
+        return kind in self.mixers
 
     @property
     def depth(self) -> int:
@@ -152,40 +223,74 @@ class HybridLM:
         sparse = (init + picked) * bs + (n - w0 * bs)
         return np.where(n > self.dense_len, sparse, n)
 
+    @property
+    def residual_scale(self) -> float:
+        return self.scale_depth / self.published_depth ** 0.5
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels of a mamba2 layer's convolution: x, B and C."""
+        return (self.ssm_heads * self.ssm_head_dim
+                + 2 * self.ssm_groups * self.ssm_state)
+
     def state_bytes(self, batch: int) -> int:
-        """Bytes of recurrent state ``batch`` rows hold."""
-        n = sum(1 for m in self.mixers if m == LINEAR)
-        return 4 * n * batch * self.lightning_heads * self.lightning_head_dim ** 2
+        """Bytes of recurrent state ``batch`` rows hold: every leaf without
+        a token axis (the float32 states, the convolution windows)."""
+        per_row = 0
+        for kind in self.mixers:
+            if kind == LINEAR:
+                per_row += 4 * self.lightning_heads * self.lightning_head_dim ** 2
+            elif kind == MAMBA:
+                per_row += (4 * self.ssm_heads * self.ssm_head_dim
+                            * self.ssm_state
+                            + (self.ssm_conv - 1) * self.ssm_conv_width
+                            * jnp.dtype(self.dtype).itemsize)
+        return batch * per_row
 
     def init_cache(self, batch: int) -> dict:
         """Zeroed cache for ``batch`` rows of ``max_decode_len`` tokens."""
         if self.max_decode_len <= 0:
             raise ValueError("decode=True needs max_decode_len > 0")
         tm = self.max_decode_len
-        if tm % self.block_size:
+        if self.has(SPARSE) and tm % self.block_size:
             raise ValueError(
                 f"cache length {tm} must be a multiple of the selection's "
                 f"block_size {self.block_size}")
         nk = max(1, (tm - self.kernel_size) // self.kernel_stride + 1)
+        counted = self.ffn == MOE and self.decode_per_row
         cache: dict = {}
         if self.decode_per_row:
             cache["cursors"] = jnp.zeros((batch,), jnp.int32)
+            if counted:
+                cache["live"] = jnp.ones((batch,), jnp.bool_)
         else:
             cache["cursor"] = jnp.zeros((), jnp.int32)
             cache["valid"] = jnp.full((), _NO_LIMIT, jnp.int32)
+        kv = (batch, tm, self.num_kv_heads, self.head_dim)
         for r, (kind, ids) in enumerate(self.runs()):
             n = len(ids)
-            if kind == SPARSE:
-                kv = (n, batch, tm, self.num_kv_heads, self.head_dim)
-                cache[f"run{r}"] = {
-                    "cached_k": jnp.zeros(kv, self.dtype),
-                    "cached_v": jnp.zeros(kv, self.dtype),
-                    "comp_k": jnp.zeros((n, batch, nk) + kv[3:],
-                                        jnp.float32)}
+            if kind in (SPARSE, ATTENTION):
+                run = {k: jnp.zeros((n,) + kv, self.dtype)
+                       for k in ("cached_k", "cached_v")}
+                if kind == SPARSE:
+                    run["comp_k"] = jnp.zeros((n, batch, nk) + kv[2:],
+                                              jnp.float32)
+            elif kind == MAMBA:
+                run = {"state": jnp.zeros(
+                    (n, batch, self.ssm_heads, self.ssm_head_dim,
+                     self.ssm_state), jnp.float32),
+                       "conv": jnp.zeros(
+                    (n, batch, self.ssm_conv - 1, self.ssm_conv_width),
+                    self.dtype)}
             else:
                 d = self.lightning_head_dim
-                cache[f"run{r}"] = {"state": jnp.zeros(
+                run = {"state": jnp.zeros(
                     (n, batch, self.lightning_heads, d, d), jnp.float32)}
+            if counted:
+                run["expert_load"] = jnp.zeros((n, self.experts_held[1]),
+                                               jnp.int32)
+                run["expert_steps"] = jnp.zeros((n, 3), jnp.int32)
+            cache[f"run{r}"] = run
         return cache
 
     def decode_apply(self, params, cache, tokens, paged=None):
@@ -379,45 +484,204 @@ def _linear_block(q, k, v, m, state, slope):
     return intra + inter, state
 
 
+def _cut(x, size: int):
+    """[B, T, ...] -> [T / size, B, size, ...], the tail zero-padded."""
+    b, t = x.shape[:2]
+    n = -(-t // size)
+    x = jnp.pad(x, [(0, 0), (0, n * size - t)] + [(0, 0)] * (x.ndim - 2))
+    return jnp.moveaxis(x.reshape((b, n, size) + x.shape[2:]), 1, 0)
+
+
 def _linear_mix(q, k, v, m, state, slope):
     """The recurrence over [B, T, H, d] in sub-blocks of `_LIN_BLOCK`."""
     b, t, h, d = q.shape
     if t <= _LIN_BLOCK:
         return _linear_block(q, k, v, m, state, slope)
-    n = -(-t // _LIN_BLOCK)
-    pad = n * _LIN_BLOCK - t
-
-    def cut(x):
-        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-        return jnp.moveaxis(
-            x.reshape((b, n, _LIN_BLOCK) + x.shape[2:]), 1, 0)
 
     def body(state, xs):
         o, state = _linear_block(*xs, state, slope)
         return state, o
 
-    state, o = jax.lax.scan(body, state, (cut(q), cut(k), cut(v), cut(m)))
-    o = jnp.moveaxis(o, 0, 1).reshape(b, n * _LIN_BLOCK, h, d)
+    state, o = jax.lax.scan(
+        body, state, tuple(_cut(x, _LIN_BLOCK) for x in (q, k, v, m)))
+    o = jnp.moveaxis(o, 0, 1).reshape(b, -1, h, d)
     return o[:, :t], state
+
+
+# -- the selective scan (mamba2) ---------------------------------------------
+
+def _ssd_block(xs, dt, a, bm, cm, state):
+    """One chunk of `S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t`, `y_t =
+    S_t C_t`, in its chunked (quadratic inside, recurrent across) form.
+    ``xs`` [B, L, G, E, P], ``dt`` [B, L, G, E] (0 at a padded position:
+    it neither decays the state nor adds to it), ``a`` [G, E] (negative),
+    ``bm``/``cm`` [B, L, G, N], ``state`` [B, G, E, P, N]; float32."""
+    cum = jnp.cumsum(dt * a, axis=1)                             # [B,L,G,E]
+    n = xs.shape[1]
+    tri = (jnp.arange(n)[:, None] >= jnp.arange(n)[None, :])[None, :, :,
+                                                              None, None]
+    seg = cum[:, :, None] - cum[:, None, :]                  # [B,i,j,G,E]
+    decay = jnp.where(tri, jnp.exp(jnp.where(tri, seg, 0.0)), 0.0)
+    scores = jnp.einsum("bign,bjgn->bijg", cm, bm, precision=_HI)
+    w = scores[..., None] * decay * dt[:, None]
+    y = jnp.einsum("bijge,bjgep->bigep", w, xs, precision=_HI)
+    y = y + jnp.einsum("bign,bgepn->bigep", cm, state, precision=_HI
+                       ) * jnp.exp(cum)[..., None]
+    last = cum[:, -1]                                            # [B,G,E]
+    into = (jnp.exp(last[:, None] - cum) * dt)[..., None] * xs
+    state = (state * jnp.exp(last)[..., None, None]
+             + jnp.einsum("bjgep,bjgn->bgepn", into, bm, precision=_HI))
+    return y, state
+
+
+def _ssd(xs, dt, a, bm, cm, state, chunk: int):
+    """The scan over [B, T, ...]: one update for a single token, else
+    chunks of ``chunk`` tokens (the tail padded with dt = 0)."""
+    b, t = xs.shape[:2]
+    if t == 1:
+        # elementwise, so that the state is read and written once
+        state = (state * jnp.exp(dt[:, 0] * a)[..., None, None]
+                 + (dt[:, 0, ..., None] * xs[:, 0])[..., None]
+                 * bm[:, 0, :, None, None, :])
+        y = jnp.sum(state * cm[:, 0, :, None, None, :], axis=-1)
+        return y[:, None], state
+    if t <= chunk:
+        return _ssd_block(xs, dt, a, bm, cm, state)
+
+    def body(state, part):
+        y, state = _ssd_block(*part[:2], a, *part[2:], state)
+        return state, y
+
+    state, y = jax.lax.scan(
+        body, state, tuple(_cut(x, chunk) for x in (xs, dt, bm, cm)))
+    y = jnp.moveaxis(y, 0, 1).reshape((b, -1) + y.shape[3:])
+    return y[:, :t], state
+
+
+def _mamba_layer(model: HybridLM, p, c, held, i, x, mask):
+    """Layer ``i`` of a run whose stacked float32 states (``held``) ride
+    through the scan as carry (updated where they lie; as the scan's
+    ``xs``/``ys`` every layer's state was copied out and back a step: 5.5
+    ms of a 28 ms step at granite-4.0-h-small's widths). ``mask``
+    [B, T]: the real tokens (a prefix of a row, or none of it). What it
+    leaves out enters neither the window nor the state."""
+    b, t, _ = x.shape
+    h, pd, n, g = (model.ssm_heads, model.ssm_head_dim, model.ssm_state,
+                   model.ssm_groups)
+    di, width, taps = h * pd, model.ssm_conv_width, model.ssm_conv
+    hn = _rms(x, p["ln1"], model.eps, model.dtype)
+    z, xc, dt = jnp.split(hn @ p["w_in"], (di, di + width), axis=-1)
+    seq = jnp.concatenate([c["conv"], xc.astype(c["conv"].dtype)], axis=1)
+    conv = p["conv_b"].astype(jnp.float32) + sum(
+        seq[:, j:j + t].astype(jnp.float32)
+        * p["conv_w"][j].astype(jnp.float32) for j in range(taps))
+    conv = jax.nn.silu(conv)
+    # the window the next token sees: the last inputs before the padding
+    kept = jnp.sum(mask, axis=1)                                 # [B]
+    window = jax.vmap(lambda s, k: jax.lax.dynamic_slice_in_dim(
+        s, k, taps - 1, axis=0))(seq, kept)
+    xs = conv[..., :di].reshape(b, t, g, h // g, pd)
+    bm = conv[..., di:di + g * n].reshape(b, t, g, n)
+    cm = conv[..., di + g * n:].reshape(b, t, g, n)
+    dt = jnp.where(mask[..., None], jax.nn.softplus(
+        dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)), 0.0)
+    a = -jnp.exp(p["A_log"].astype(jnp.float32)).reshape(g, h // g)
+    states = held["state"]
+    state = jax.lax.dynamic_index_in_dim(
+        states, i, 0, keepdims=False).reshape(b, g, h // g, pd, n)
+    y, state = _ssd(xs, dt.reshape(b, t, g, h // g), a, bm, cm, state,
+                    model.ssm_chunk)
+    states = jax.lax.dynamic_update_index_in_dim(
+        states, state.reshape(states.shape[1:]), i, 0)
+    y = y + p["D"].astype(jnp.float32).reshape(g, h // g)[..., None] * xs
+    y = y.reshape(b, t, di) * jax.nn.silu(z.astype(jnp.float32))
+    out = _rms(y, p["norm"], model.eps, model.dtype) @ p["w_out"]
+    return out, {"state": states}, {"conv": window}
+
+
+# -- plain attention ---------------------------------------------------------
+
+def _attend(q, kc, vc, pos, scale):
+    """``q`` [B, T, K, G, d] at positions ``pos`` [B, T] over the cache
+    [B, S, K, d]: causal softmax attention, float32 scores."""
+    s = jnp.einsum("btkgd,bskd->bkgts", q, kc,
+                   preferred_element_type=jnp.float32) * scale
+    seen = jnp.arange(kc.shape[1])[None, None, :] <= pos[:, :, None]
+    w = jax.nn.softmax(jnp.where(seen[:, None, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bkgts,bskd->btkgd", w.astype(vc.dtype), vc)
+
+
+def _attention_layer(model: HybridLM, p, kv, i, x, pos):
+    """Layer ``i`` of a run whose stacked K/V ``kv`` ride through the scan
+    as carry: the new tokens' rows are written where they lie, and the
+    layer's slice is read from there (as `scanned_apply` does)."""
+    b, t, _ = x.shape
+    kvh, d = model.num_kv_heads, model.head_dim
+    hn = _rms(x, p["ln1"], model.eps, model.dtype)
+    q = _proj(hn, p["wq"]).reshape(b, t, kvh, model.num_heads // kvh, d)
+    p0 = pos[:, 0]
+    kv = {"cached_k": _write_kv(kv["cached_k"], _proj(hn, p["wk"]), p0,
+                                model.decode_per_row, layer=i),
+          "cached_v": _write_kv(kv["cached_v"], _proj(hn, p["wv"]), p0,
+                                model.decode_per_row, layer=i)}
+    kc, vc = (jax.lax.dynamic_index_in_dim(kv[k], i, 0, keepdims=False)
+              for k in ("cached_k", "cached_v"))
+    scale = d ** -0.5 if model.attn_scale is None else model.attn_scale
+    if t > _QUERY_TILE:
+        # a long chunk a tile of queries at a time: the float32 scores of
+        # 2048 queries over 4096 keys would be a gigabyte
+        o = jax.lax.map(lambda qp: _attend(qp[0], kc, vc, qp[1], scale),
+                        (_cut(q, _QUERY_TILE), _cut(pos, _QUERY_TILE)))
+        o = jnp.moveaxis(o, 0, 1).reshape((b, -1) + q.shape[2:])[:, :t]
+    else:
+        o = _attend(q, kc, vc, pos, scale)
+    out = jnp.einsum("bthk,hkd->btd",
+                     o.reshape(b, t, model.num_heads, d), p["wo"])
+    return out, kv, {}
 
 
 # -- the stack -------------------------------------------------------------
 
-def _mlp(model: HybridLM, p, x):
+def _ffn(model: HybridLM, p, experts, i, c, x, mask):
+    """The layer's feed-forward over the normed ``x``, and what it counted
+    (the leaves of ``c`` it updates: an expert stack's, in a decode
+    cache). ``experts``: the run's routed experts, stacked, of which this
+    is layer ``i`` (`_WHOLE`)."""
     hn = _rms(x, p["ln2"], model.eps, model.dtype)
-    act = jax.nn.silu(hn @ p["wg"]) * (hn @ p["wu"])
-    return act @ p["wd"]
+    if model.ffn == DENSE:
+        return (jax.nn.silu(hn @ p["wg"]) * (hn @ p["wu"])) @ p["wd"], {}
+    b, t, d = hn.shape
+    y, load = routed_experts(
+        hn.reshape(b * t, d), p["router"], experts["w1"], experts["w2"],
+        top_k=model.experts_per_token, experts_held=model.experts_held,
+        mask=mask.reshape(-1), dense=b * t < _GROUP_FROM, layer=i)
+    up = hn @ p["ws1"]
+    shared = (jax.nn.silu(up[..., :model.shared_dim])
+              * up[..., model.shared_dim:]) @ p["ws2"]
+    counted = {}
+    if "expert_load" in c:
+        any_live = jnp.any(mask).astype(jnp.int32)
+        counted = {
+            "expert_load": c["expert_load"] + load,
+            "expert_steps": c["expert_steps"] + jnp.stack(
+                [jnp.sum(load > 0, dtype=jnp.int32), any_live,
+                 jnp.sum(mask, dtype=jnp.int32)])}
+    return y.reshape(b, t, d) + shared, counted
 
 
-def _write_kv(cache_leaf, new, p0, per_row: bool):
-    """Cache ``new`` [B, T, K, d] at each row's positions p0.. ."""
+def _write_kv(cache_leaf, new, p0, per_row: bool, layer=None):
+    """Cache ``new`` [B, T, K, d] at each row's positions p0.. ; with
+    ``layer`` the leaf is a run's stack [L, B, Tm, K, d] and the rows of
+    that layer are written where they lie."""
     new = new.astype(cache_leaf.dtype)
+    at = () if layer is None else (layer,)
     if per_row:
         rows = jnp.arange(new.shape[0])[:, None]
         slot = jnp.clip(p0[:, None] + jnp.arange(new.shape[1])[None, :], 0,
-                        cache_leaf.shape[1] - 1)
-        return cache_leaf.at[rows, slot].set(new)
-    return jax.lax.dynamic_update_slice(cache_leaf, new, (0, p0[0], 0, 0))
+                        cache_leaf.shape[-3] - 1)
+        return cache_leaf.at[at + (rows, slot)].set(new)
+    return jax.lax.dynamic_update_slice(
+        cache_leaf, new[(None,) * len(at)], at + (0, p0[0], 0, 0))
 
 
 def _qkv_gate(model: HybridLM, p, x):
@@ -481,26 +745,55 @@ def hybrid_apply(model: HybridLM, params, cache, tokens, paged=None):
         p0 = jnp.broadcast_to(cache["cursor"], (b,))
         valid = cache["valid"]
     pos = p0[:, None] + jnp.arange(t)[None, :]
-    a = model.scale_depth / model.published_depth ** 0.5
+    # the real tokens: before `valid` in a prefill, a live row's in decode
+    mask = (jnp.broadcast_to(cache["live"][:, None], pos.shape)
+            if "live" in cache else pos < valid)
+    a = model.residual_scale
     x = (params["embed"][tokens] * model.scale_emb).astype(model.dtype)
     new_cache = dict(cache)
     for r, (kind, ids) in enumerate(model.runs()):
-        def body(h, layer, kind=kind):
-            p_l, c_l, slope = layer
-            if kind == SPARSE:
-                mix, c_l = _sparse_layer(model, p_l, c_l, h, pos, valid)
-            else:
-                mix, c_l = _linear_layer(model, p_l, c_l, slope, h, pos,
-                                         valid)
-            h = h + (a * mix).astype(model.dtype)
-            h = h + (a * _mlp(model, p_l, h)).astype(model.dtype)
-            return h, c_l
+        # a plain attention run's K/V and a state-space run's states are
+        # the scan's carry (written in place); every other leaf is sliced
+        # a layer and written back
+        c_r, p_r = cache[f"run{r}"], params["runs"][r]
+        held = {k: c_r[k] for k in _CARRIED.get(kind, ())}
+        rest = {k: v for k, v in c_r.items() if k not in held}
+        experts = {k: p_r[k] for k in _WHOLE if k in p_r}
 
-        slopes = jnp.asarray(np.stack([model.slopes(i) for i in ids]))
-        x, new_cache[f"run{r}"] = jax.lax.scan(
-            body, x, (params["runs"][r], cache[f"run{r}"], slopes))
+        def body(carry, layer, kind=kind):
+            h, held = carry
+            i, p_l, c_l, slope = layer
+            if kind == SPARSE:
+                mix, new = _sparse_layer(model, p_l, c_l, h, pos, valid)
+            elif kind == LINEAR:
+                mix, new = _linear_layer(model, p_l, c_l, slope, h, pos,
+                                         valid)
+            elif kind == MAMBA:
+                mix, held, new = _mamba_layer(model, p_l, c_l, held, i, h,
+                                              mask)
+            else:
+                mix, held, new = _attention_layer(model, p_l, held, i, h,
+                                                  pos)
+            h = h + (a * mix).astype(model.dtype)
+            out, counted = _ffn(model, p_l, experts, i, c_l, h, mask)
+            h = h + (a * out).astype(model.dtype)
+            return (h, held), {**new, **counted}
+
+        slopes = (jnp.asarray(np.stack([model.slopes(i) for i in ids]))
+                  if kind == LINEAR else None)
+        (x, held), rest = jax.lax.scan(
+            body, (x, held),
+            (jnp.arange(len(ids)),
+             {k: v for k, v in p_r.items() if k not in experts}, rest,
+             slopes))
+        new_cache[f"run{r}"] = {**held, **rest}
     if not model.decode_per_row:
         new_cache["cursor"] = cache["cursor"] + t
     hn = _rms(x, params["norm_f"], model.eps, jnp.float32) / model.logit_div
-    logits = hn.astype(model.dtype) @ params["head"]
+    if "head" in params:
+        logits = hn.astype(model.dtype) @ params["head"]
+    else:       # tied: the embedding is the head, the logits float32
+        logits = jnp.einsum("btd,vd->btv", hn.astype(model.dtype),
+                            params["embed"],
+                            preferred_element_type=jnp.float32)
     return logits.astype(jnp.float32), new_cache

@@ -18,6 +18,11 @@ Training: top-1 routing collapses without pressure toward balance, so the
 layer sows the Switch-Transformer auxiliary load-balancing loss
 (E · Σ_e frac_routed_e · mean_prob_e) into the ``"losses"`` collection;
 ``moe_aux_loss`` sums it for adding to the task loss.
+
+`routed_experts` is the serving-side layer of a stack that holds one
+chip's share of a wider router (`models/hybrid.py`'s ``moe`` feed-forward
+kind): dropless top-k over the whole router, gates over the chosen, only
+the held experts' terms computed, tokens grouped by expert.
 """
 from __future__ import annotations
 
@@ -127,6 +132,86 @@ class SwitchFFN(nn.Module):
         # capacity would otherwise guarantee dropped streams
         return max(self.k, int(self.capacity_factor * tokens_per_shard
                                / self.n_experts))
+
+
+def routed_experts(x, router, w1, w2, *, top_k: int,
+                   experts_held: tuple[int, int], mask=None,
+                   dense: bool = False, layer=None):
+    """One chip's share of a dropless top-k expert layer.
+
+    ``x`` [T, d] tokens, ``router`` [d, E] over ALL ``E`` experts, ``w1``
+    [held, d, 2f] and ``w2`` [held, f, d] the gated SiLU experts
+    ``first .. first + held`` (``experts_held`` = (first, held)). Every
+    token takes the ``top_k`` largest of its ``E`` router logits (float32)
+    and a softmax over those ``top_k`` alone, held here or not; the result
+    is ``sum gate_e * expert_e(x)`` over the chosen experts THAT ARE HELD:
+    what the other chips of the layer would add is left out, and nothing
+    stands in for them. No capacity, no token dropped. Tokens where
+    ``mask`` [T] is False (padding, a dead row) are routed nowhere. With
+    ``layer`` (an index, traced or not) ``w1`` and ``w2`` are a run of
+    layers' experts stacked, [L, held, ...], and the layer's are taken
+    where they lie: the grouped product is handed the whole stack as
+    L x held groups of which only this layer's hold rows, because a slice
+    of the stack handed to it would be copied first (0.68 GB a layer at
+    granite-4.0-h-small's widths).
+
+    Grouped (the default): the (token, pick) pairs are sorted by expert and
+    go through two `jax.lax.ragged_dot`s, so the work is that of the picks
+    that fell here, whatever their spread over the experts. ``dense``
+    multiplies every token by every held expert and weights by the gates
+    (zero where not picked): `held / picks-here` times the operations, one
+    batched product; for a handful of rows, where the experts' bytes bound
+    the layer either way.
+
+    Returns (y [T, d] in ``x``'s type, load [held] int32: the picks each
+    held expert took). This is what an expert-parallel mesh calls a chip
+    (`idunno_tpu.parallel.expert`), between its two exchanges."""
+    t, d = x.shape
+    first, held = experts_held
+    f = w2.shape[-2]
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    top, idx = jax.lax.top_k(logits, top_k)                      # [T, k]
+    gates = jax.nn.softmax(top, axis=-1)
+    here = (idx >= first) & (idx < first + held)
+    if mask is not None:
+        here = here & mask[:, None]
+    local = jnp.where(here, idx - first, held)       # `held`: not computed
+    # comparisons and sorts, no scatter: the chip scatters an element at a
+    # time
+    picked = local[..., None] == jnp.arange(held)            # [T, k, held]
+    load = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
+
+    def gated(h):
+        return jax.nn.silu(h[..., :f]) * h[..., f:]
+
+    sizes = load
+    if layer is not None and dense:
+        w1, w2 = (jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+                  for w in (w1, w2))
+    elif layer is not None:
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((w1.shape[0], held), jnp.int32), load[None],
+            (layer, 0)).reshape(-1)
+        w1, w2 = (w.reshape((-1,) + w.shape[2:]) for w in (w1, w2))
+    if dense:
+        g = jnp.sum(jnp.where(picked, gates[..., None], 0.0), axis=1)
+        h = gated(jnp.einsum("td,edf->etf", x, w1)) * g.T.astype(
+            x.dtype)[..., None]
+        return jnp.einsum("etf,efd->td", h, w2), load
+    order = jnp.argsort(local.reshape(-1), stable=True)          # [T k]
+    back = jnp.argsort(order)
+    rows = jnp.take(x, order // top_k, axis=0)
+    out = jax.lax.ragged_dot(gated(jax.lax.ragged_dot(rows, w1, sizes)),
+                             w2, sizes)
+    # back in (token, pick) order; a pick that fell on no held expert sits
+    # past the last group: whatever the grouped product left in its row is
+    # dropped, not weighted
+    out = jnp.take(out, back, axis=0).reshape(t, top_k, d)
+    y = jnp.sum(jnp.where(here[..., None], out.astype(jnp.float32)
+                          * gates[..., None], 0.0), axis=1)
+    return y.astype(x.dtype), load
 
 
 def switch_ffn_factory(n_experts: int, capacity_factor: float = 2.0,

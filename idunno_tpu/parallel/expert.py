@@ -14,6 +14,12 @@ order.
 Used by `idunno_tpu.models.moe.SwitchFFN`, which also provides the dense
 (every-device-holds-every-expert) path for single-device runs and as the
 ground truth the EP path is tested against.
+
+The dropless layer of a chip that holds a share of a wider router is
+`idunno_tpu.models.moe.routed_experts` (``experts_held`` = (first, count)):
+the part of an expert-parallel layer that runs between its two exchanges,
+one chip's. A serving stack calls it on one chip without the exchange
+(`models/hybrid.py`); no mesh code calls it yet.
 """
 from __future__ import annotations
 

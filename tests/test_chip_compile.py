@@ -222,6 +222,110 @@ def test_hybrid_stack_steps_at_published_widths(one_chip, which):
             + mem.output_size_in_bytes) < 12e9
 
 
+def _granite_layer(sds, kind: str) -> dict:
+    """Shapes of one stacked layer of granite-4.0-h-small at its published
+    widths, 36 of its 72 experts held."""
+    ffn = {"ln2": sds((1, 4096)), "router": sds((1, 4096, 72)),
+           "w1": sds((1, 36, 4096, 1536)), "w2": sds((1, 36, 768, 4096)),
+           "ws1": sds((1, 4096, 3072)), "ws2": sds((1, 1536, 4096))}
+    if kind == "attention":
+        return {"ln1": sds((1, 4096)), "wq": sds((1, 4096, 32, 128)),
+                "wk": sds((1, 4096, 8, 128)), "wv": sds((1, 4096, 8, 128)),
+                "wo": sds((1, 32, 128, 4096)), **ffn}
+    return {"ln1": sds((1, 4096)), "w_in": sds((1, 4096, 16768)),
+            "conv_w": sds((1, 4, 8448)), "conv_b": sds((1, 8448)),
+            "dt_bias": sds((1, 128), jnp.float32),
+            "A_log": sds((1, 128), jnp.float32), "D": sds((1, 128)),
+            "norm": sds((1, 8192)), "w_out": sds((1, 8192, 4096)), **ffn}
+
+
+@pytest.mark.parametrize("which", ["decode", "chunk"])
+@pytest.mark.parametrize("kind", ["mamba2", "attention"])
+def test_granite_layers_at_published_widths(one_chip, kind, which):
+    """One layer of each new kind of `models/hybrid.py` with its routed and
+    shared experts at granite-4.0-h-small's published widths (a selective
+    scan over 128 x 64 x 128 states, GQA 32/8 x 128 without positions,
+    top-10 of 72 with 36 held): the decode step over the benchmark's 48
+    slots x 4864 (every token by every held expert) and a 2048-token
+    prefill chunk of a 4096-token row (the chunked scan, attention a tile
+    of queries at a time, tokens grouped by expert: the chip's own
+    grouped product, a custom call) pass the chip's compiler, and their
+    temporaries stay bounded."""
+    from idunno_tpu.models.hybrid import MOE, HybridLM
+
+    dt = jnp.bfloat16
+    model = HybridLM(
+        vocab=100352, dim=4096, mlp_dim=768, mixers=(kind,), layer_ids=(0,),
+        published_depth=1, scale_depth=0.22, num_heads=32, num_kv_heads=8,
+        head_dim=128, attn_scale=0.0078125, scale_emb=12.0, logit_div=16.0,
+        eps=1e-5, ssm_heads=128, ssm_head_dim=64, ssm_state=128, ffn=MOE,
+        experts=72, experts_per_token=10, experts_held=(0, 36),
+        shared_dim=1536, dtype=dt, param_dtype=dt)
+
+    def sds(shape, dtype=dt):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"embed": sds((100352, 4096)),
+              "runs": (_granite_layer(sds, kind),), "norm_f": sds((4096,))}
+    if which == "decode":
+        dec = dataclasses.replace(model, decode=True, decode_per_row=True,
+                                  max_decode_len=4864)
+        rows, tokens = 48, 1
+    else:
+        dec = dataclasses.replace(model, decode=True, max_decode_len=4096)
+        rows, tokens = 1, 2048
+    cache = jax.tree.map(lambda s: sds(s.shape, s.dtype),
+                         jax.eval_shape(lambda: dec.init_cache(rows)))
+    compiled = jax.jit(dec.decode_apply).lower(
+        params, cache, sds((rows, tokens), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    # the grouped product is there where tokens are many, and only there
+    assert ("ragged-dot" in compiled.as_text()) == (which == "chunk")
+    assert mem.temp_size_in_bytes < 3e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 8e9
+
+
+def test_granite_dispatch_updates_states_and_kv_in_place(one_chip):
+    """`DecodeServer._build_decode`'s `jit_run` over a stack of two mamba2
+    layers and an attention layer at granite-4.0-h-small's widths, 48 slots
+    x 4864 (a toy pool whose `_dec` is widened after the build): the new
+    cache IS the donated one, the program's own memory holds no second
+    copy of the scan states (their run's scan carries them and a layer
+    updates its own where it lies), and no whole stacked state is copied."""
+    from benchmark import manifest, system
+
+    man = manifest.Manifest()
+    cfg = man.config(man.cell("granite-4.0-h-small.chat"))
+    fam = man.family(cfg)
+    cut = dict(layer_types=["mamba", "mamba", "attention"],
+               num_hidden_layers=3)
+    cfg = dict(cfg, **cut)
+    toy = dict(system.model_config(cfg, True, fam), **cut,
+               vocab_size=cfg["vocab_size"])
+    model, params, _kw = fam.program.build(
+        toy, fam.weights.make_weights(toy, 1))
+    slots, max_len = 48, 4864
+    srv = DecodeServer(model, params, slots=slots, prompt_len=128,
+                       max_len=max_len, decode_steps=4,
+                       prompt_buckets=(128,), kv_block_size=64,
+                       kv_cache_blocks=4)
+    srv._dec = dataclasses.replace(
+        fam.program.model_of(cfg), decode=True, decode_per_row=True,
+        max_decode_len=max_len)
+    wide = _described(fam.program.program_params(jax.eval_shape(
+        lambda: fam.weights.make_weights(cfg, 1))), one_chip)
+    cache = _described(jax.eval_shape(
+        lambda: srv._dec.init_cache(slots)), one_chip)
+    compiled = _compile_run(srv, wide, cache, one_chip)
+    mem = compiled.memory_analysis()
+    states = 2 * slots * 128 * 64 * 128 * 4
+    assert mem.alias_size_in_bytes >= _nbytes(cache)
+    assert mem.temp_size_in_bytes < states // 4
+    assert not re.findall(r"= f32\[2,48,128,64,128\]\S* copy\(",
+                          compiled.as_text())
+
+
 # -- the decode programs over the slot cache, at the benchmark's widths ------
 
 _SLOTS, _MAX_LEN, _VOCAB = 28, 4096, 49152
