@@ -738,6 +738,7 @@ class Shell:
         return (f"{args[0]}: live={s['live']}/{s['slots']} "
                 f"queued={s['queued']} inbox={s['inbox']} "
                 f"unpolled={s['unpolled']} admitted={s['admitted']} "
+                f"overlapped={s.get('admissions_overlapped', 0)} "
                 f"completed={s['completed']} "
                 f"tokens_generated={s['tokens_generated']} "
                 f"dispatches={s['dispatches']}" + config_line(s)

@@ -161,10 +161,11 @@ class Completion:
     cold_start: bool = False
     # the pool's own stamps (one clock, `DecodeServer.clock`): submit, slot
     # admission, the end of the step that first showed the host tokens of
-    # this request (``n_first`` of them: the prefill's and one dispatch's),
-    # the end of the step that showed its last. A step ends once the rows'
-    # cursors are read back, the moment a streaming client could see the
-    # tokens. None where the request never got that far.
+    # this request (``n_first`` of them: the prefill's one; the row joins
+    # the next step's dispatch), the end of the step that showed its last.
+    # A step ends once the rows' cursors are read back, the moment a
+    # streaming client could see the tokens. None where the request never
+    # got that far.
     t_submit: float | None = None
     t_admit: float | None = None
     t_first: float | None = None
@@ -447,6 +448,16 @@ def _insert(tokens: jnp.ndarray, cache: Any, row_cache: Any,
     row = row.at[true_len].set(first_tok)
     tokens = tokens.at[slot].set(row)
     return tokens, _splice_rows(cache, row_cache, slot, stacked)
+
+
+@jax.jit
+def _set_rows(arrays: tuple, at: tuple, values: tuple) -> tuple:
+    """``a.at[i].set(v)`` for every array of a pool's per-slot state, as
+    ONE program. An admission sets a dozen of them; done eagerly each is a
+    handful of tiny programs, and the runtime lets the host have only so
+    many programs in flight (32): behind a decode dispatch in flight the
+    admission's enqueues then stall until that dispatch has finished."""
+    return tuple(a.at[i].set(v) for a, i, v in zip(arrays, at, values))
 
 
 @jax.jit
@@ -790,13 +801,22 @@ class DecodeServer:
         self._live: dict[int, Request] = {}       # slot → request
         self._done: list[Completion] = []
         # (completion, request) of the rows the running step retired,
-        # until its end stamps them; traced admissions awaiting the
-        # stamp of their first dispatch
+        # until its end stamps them; traced admissions awaiting their
+        # first dispatch (the next step's), whose start `lm.decode` takes
         self._retired: list[tuple[Completion, Request]] = []
         self._new_traced: list[Request] = []
+        # while the running step's decode dispatch is in flight: the
+        # cursors the host held before it (an admission queued behind it
+        # puts its slot's new cursor there: the slot took no part in the
+        # dispatch); None when the step enqueued none
+        self._in_flight: np.ndarray | None = None
         self._next_id = 0
         self._cancelled: set[int] = set()     # ids cancelled while live
-        self._stats = {"dispatches": 0, "admitted": 0, "completed": 0,
+        self._stats = {"dispatches": 0, "admitted": 0,
+                       # admissions whose slot splice (a chunked one's
+                       # last chunk with it) was enqueued behind a decode
+                       # dispatch in flight; the rest met an empty pool
+                       "admissions_overlapped": 0, "completed": 0,
                        "tokens_generated": 0, "cancelled": 0,
                        # padded suffix tokens actually computed by
                        # admission prefills — the work the prefix cache
@@ -1615,6 +1635,8 @@ class DecodeServer:
             # admissions stay FIFO behind it (`step` advances it by one
             # chunk per call, decode dispatches landing in between)
             return
+        # a row the dispatch in flight may finish still holds its slot:
+        # the host has not read that dispatch back
         free = [s for s in range(self.slots) if s not in self._live]
         while free and self._queue:
             slot = free.pop(0)
@@ -1768,15 +1790,17 @@ class DecodeServer:
                 hit_chain=hit_chain, per_req=per_req, pl=pl,
                 suffix_true=suffix_true, suffix_bucket=suffix_bucket,
                 suffix=suffix, span=sp)
-            # max_new == 1: the prefill's token was the only one; the next
-            # _retire_finished pass (step() runs one post-admission)
-            # retires the row before any decode dispatch
+            # max_new == 1: the prefill's token was the only one; the step's
+            # one `_retire_finished` pass, at its end, retires the row
+            # before any decode dispatch has it
 
     def _advance_prefill(self) -> None:
         """Apply ONE chunk of the pending chunked admission. Called once
-        per `step` (before `_admit`), so every chunk of a long prompt has
-        a decode dispatch of the resident rows between it and the next —
-        the fairness property `tests/test_serve_lm.py` asserts."""
+        per `step`, after the step's decode dispatch is enqueued and
+        before `_admit`, so every chunk of a long prompt has a decode
+        dispatch of the resident rows between it and the next — the
+        fairness property `tests/test_serve_lm.py` asserts — and queues
+        behind that dispatch on the chip."""
         p = self._pending
         n = min(self.prefill_chunk, p["bucket"] - p["off"])
         tok = jnp.asarray(p["suffix"][:, p["off"]:p["off"] + n])
@@ -1859,6 +1883,9 @@ class DecodeServer:
                 cp.publish(per_req, len(chain),
                            lambda j: self._block_pool.read_block(
                                chain[j].block))
+        # (attribute, index, value): the slot's entries of the pool's
+        # per-slot arrays, all set by one program at the end (`_set_rows`)
+        sets = []
         if self._paged:
             nb = hit // self.kv_block_size
             tab = np.zeros((self._max_chain,), np.int32)
@@ -1869,8 +1896,7 @@ class DecodeServer:
                 # counts
                 self._stats["kv_gather_bytes_saved"] += (
                     nb * self._block_pool.bytes_per_block)
-            self._tables = self._tables.at[slot].set(jnp.asarray(tab))
-            self._plens = self._plens.at[slot].set(hit)
+            sets += [("_tables", slot, tab), ("_plens", slot, hit)]
         if hit or self.prefix:
             # downstream state (tokens row, cursors, prompt_len,
             # stop/logprob regions) sees the FULL prompt
@@ -1895,35 +1921,40 @@ class DecodeServer:
             self._tokens, self._cache, row_cache, jnp.asarray(prompt),
             first, jnp.int32(true_len), jnp.int32(slot), bucket,
             stacked=self._scan)
+        if self._in_flight is not None:
+            # the slot sat out the dispatch in flight: over it the cursor
+            # did not move (`_count_context` differences the two)
+            self._in_flight[slot] = true_len
         if self._recurrent:
             # the row's state, window and pooled keys went into the slot
             # with its K/V, whole: nothing of the slot's last tenant is left
             self._seen_cursor[slot] = true_len
             self._child_span(span, "state.splice", t_splice,
                              state_bytes=self.model.state_bytes(1))
-        self._cursors = self._cursors.at[slot].set(true_len)
-        self._temps = self._temps.at[slot].set(temp)
-        self._top_ps = self._top_ps.at[slot].set(topp)
-        self._top_ks = self._top_ks.at[slot].set(topk)
-        self._keys = self._keys.at[slot].set(key)
-        if self.track_logprobs:   # the prefill-picked token's logprob
-            lp0 = jax.nn.log_softmax(
-                last_logits.astype(jnp.float32))[first]
-            self._logprobs = self._logprobs.at[slot, true_len].set(lp0)
-        if self.penalties:   # fresh row; the first token counts.
-            # validate() guarantees zero penalties off-flag, so the
-            # buffers are only ever touched when the kernel reads them
-            self._pres = self._pres.at[slot].set(
-                jnp.float32(req.presence_penalty))
-            self._freq = self._freq.at[slot].set(
-                jnp.float32(req.frequency_penalty))
-            self._counts = self._counts.at[slot].set(0)
-            self._counts = self._counts.at[slot, first].set(1)
         rem = req.max_new - 1
         if self.eos_id is not None and int(first) == self.eos_id:
             rem = 0                   # the prompt's very next token
-        self._remaining = self._remaining.at[slot].set(rem)
+        sets += [("_cursors", slot, true_len), ("_remaining", slot, rem),
+                 ("_temps", slot, temp), ("_top_ps", slot, topp),
+                 ("_top_ks", slot, topk), ("_keys", slot, key)]
+        if self.track_logprobs:   # the prefill-picked token's logprob
+            lp0 = jax.nn.log_softmax(
+                last_logits.astype(jnp.float32))[first]
+            sets.append(("_logprobs", (slot, true_len), lp0))
+        if self.penalties:   # fresh row; the first token counts.
+            # validate() guarantees zero penalties off-flag, so the
+            # buffers are only ever touched when the kernel reads them
+            sets += [("_pres", slot, req.presence_penalty),
+                     ("_freq", slot, req.frequency_penalty),
+                     ("_counts", slot, jax.nn.one_hot(
+                         first, self.model.vocab, dtype=jnp.int32))]
+        names, at, values = zip(*sets)
+        for name, new in zip(names, _set_rows(
+                tuple(getattr(self, n) for n in names), at, values)):
+            setattr(self, name, new)
         self._rc_invalidate()
+        # the step's own dispatch is counted already: the row's first is
+        # the next step's
         req.dispatch0 = self._stats["dispatches"]
         if span is not None:
             # the span opened at admission closes here; a chunked one has
@@ -1935,9 +1966,8 @@ class DecodeServer:
             self._new_traced.append(req)
         self._live[slot] = req
         self._stats["admitted"] += 1
-            # max_new == 1: the prefill's token was the only one; the next
-            # _retire_finished pass (step() runs one post-admission) retires
-            # the row before any decode dispatch
+        if self._in_flight is not None:
+            self._stats["admissions_overlapped"] += 1
 
     def _apply_stops(self) -> None:
         """Host-side stop-sequence pass (after a dispatch, before
@@ -1984,8 +2014,17 @@ class DecodeServer:
             self._rc_invalidate()
 
     def step(self) -> int:
-        """Retire finished rows, admit queued prompts into free slots, run
-        one decode dispatch (``decode_steps`` tokens for every live row).
+        """One turn of the pool, the decode dispatch first: enqueue one
+        dispatch (``decode_steps`` tokens for every row live now) and do
+        not wait for it; admit queued prompts into the slots that were
+        free before it (one chunk of a pending chunked admission first),
+        the admission's host work running while the chip runs the
+        dispatch and its programs queueing behind it; then wait for the
+        chip ONCE, retire what finished and stamp. A row admitted here has
+        its prefill's token at the step's end and joins the NEXT step's
+        dispatch; with no row live there is no dispatch to hide behind and
+        the step admits, waits and stamps. The served tokens do not depend
+        on the order: rows are independent in every program.
         Returns live rows + still-queued requests — 0 means drained (a
         max_new=1 admission can retire instantly, leaving 0 live rows with
         the queue non-empty, so live alone would end a client loop early)."""
@@ -1994,7 +2033,7 @@ class DecodeServer:
             try:
                 return self._step(st)
             finally:
-                self._step_span = None
+                self._step_span = self._in_flight = None
 
     def _span(self, name: str, **attrs):
         """A span of the pool's own timeline, under the running `lm.step`
@@ -2008,20 +2047,23 @@ class DecodeServer:
             parent = self._step_span.span_id
         return loop_span(self.spans, name, trace, parent, **attrs)
 
-    def _retire_synced(self, after: str, was=None) -> None:
-        """`_retire_finished`; where a dispatch ran (``was``: the cursors
-        the host held before it), after its counters and `_apply_stops`.
-        With the cursors' host copy stale, the read-back returns only once
-        the chip has finished everything enqueued before it: the one place
-        in a step where the host waits for the device, and an
-        `lm.step.sync` span (``after``: what made the copy stale)."""
+    def _retire_synced(self, after: str) -> None:
+        """`_retire_finished`; where the step's dispatch is in flight
+        (`_in_flight`), after its counters and `_apply_stops`. With the
+        cursors' host copy stale, the read-back returns only once the chip
+        has finished everything enqueued before it. A step calls it twice: at its start
+        (``after`` "cancel": it waits only if a cancel made the copy stale,
+        and nothing is in flight then) and at its end ("dispatch": the
+        step's one wait for the chip, covering the dispatch and every
+        admission program queued behind it, or an empty pool's admission
+        alone). Each wait is an `lm.step.sync` span."""
         blocks = self._rc_cache is None and bool(self._live)
         with self._span("lm.step.sync", after=after) if blocks else NO_SPAN:
-            if was is not None:
+            if self._in_flight is not None:
                 if self._sparse:
                     self._count_attended()
                 if self._ladder is not None:
-                    self._count_context(was)
+                    self._count_context(self._in_flight)
                 self._apply_stops()
             self._retire_finished()
 
@@ -2059,22 +2101,18 @@ class DecodeServer:
     def _step(self, st) -> int:
         admitted0 = self._stats["admitted"]
         self._retire_synced("cancel")
-        if self._pending is not None:
-            # one chunk of the in-flight long admission, THEN the decode
-            # dispatch below — resident rows advance between chunks
-            self._advance_prefill()
-        self._admit()
-        self._retire_synced("admit")      # max_new == 1 admissions
-        # rows retired so far got no token from this step's dispatch
+        # rows retired so far get no token from this step's dispatch
         early = len(self._retired)
         rows = len(self._live)
         if self._live:
             pg = ((self._tables, self._plens,
                    self._block_pool.kv_pages()) if self._paged else ())
-            was = self._remaining_cursors()[1]    # the host's copy: no read
+            # the host's copy: no read
+            self._in_flight = self._remaining_cursors()[1].copy()
             with self._span("lm.decode_step", rows=rows) as sp:
-                for req in self._new_traced:
+                for req in self._new_traced:    # admitted a step ago
                     req.t_decode0 = sp.t_start
+                self._new_traced.clear()
                 (self._tokens, self._cache, self._cursors,
                  self._remaining, self._keys, self._logprobs,
                  self._counts) = self._decode(
@@ -2087,9 +2125,18 @@ class DecodeServer:
             self._dispatched_ever = True
             if "live" in self._cache:     # an expert stack: see __init__
                 self._expert_counts = _expert_counters(self._cache)
-            self._rc_invalidate()         # the dispatch advanced the rows
-            self._retire_synced("dispatch", was=was)
-        self._new_traced.clear()
+            self._rc_invalidate()         # the dispatch advances the rows
+        # the chip is busy with the dispatch: the admission's host work
+        # costs it nothing, and its programs (prefill, block writes, the
+        # slot splice and per-slot sets, which take the dispatch's outputs
+        # as they are handed on) run behind it in the order enqueued
+        if self._pending is not None:
+            # one chunk of the pending long admission a step: resident rows
+            # advance between chunks
+            self._advance_prefill()
+        self._admit()
+        # the step's one wait; max_new == 1 admissions retire here
+        self._retire_synced("dispatch")
         if st is not None:
             st.attrs.update(
                 rows=rows, queued=len(self._queue),
@@ -2102,11 +2149,11 @@ class DecodeServer:
     def _stamp(self, early: int) -> None:
         """The end of a step: the cursors are on the host, a streaming
         client could see every token they cover. One clock read stamps the
-        first sight of each live request and the last of each request the
-        step retired; of those, the first ``early`` retired before the
-        dispatch (cancelled, or whole after the prefill's one token) and
-        keep the last stamp they had. No device read: the cursors are the
-        copy the last `_retire_finished` fetched."""
+        first sight of each live request (a row admitted in the step shows
+        its prefill's one token) and the last of each request the step
+        retired; of those, the first ``early`` retired before the dispatch
+        (cancelled) and keep the last stamp they had. No device read: the
+        cursors are the copy the last `_retire_finished` fetched."""
         now = self.clock()
         if self._live:
             remaining = self._remaining_cursors()[0]

@@ -160,6 +160,31 @@ def test_prefill_then_decode_through_the_slot_cache(fam, weights, built):
     assert one.tolist() == [20, 32, 8 + 8 + 17, 8 + 2 * 8 + 20]
 
 
+def test_sparse_counters_add_up_request_by_request(built):
+    """Rows admitted while others decode join the next step's dispatch;
+    over the schedule the sparse layers' counters are what each request's
+    own decode steps come to: contexts prompt + 1 .. prompt + max_new - 1
+    (the prefill's token is no decode step), whatever rows shared them."""
+    srv = _server(built)
+    plan = {0: [(70, 9)], 2: [(21, 24)], 3: [(45, 1)], 5: [(40, 14)],
+            6: [(50, 6)]}
+    attended = in_context = n = 0
+    for i in range(60):
+        for size, max_new in plan.get(i, ()):
+            srv.submit(_tokens(size, 40 + n), max_new=max_new)
+            n += 1
+            ctx = np.arange(size + 1, size + max_new)
+            in_context += int(ctx.sum())
+            attended += int(srv.model.attended_tokens(ctx).sum())
+        srv.step()
+    assert srv.pending() == 0 and len(srv.poll()) == n == 5
+    st = srv.stats()
+    assert (st["sparse_tokens_attended"], st["sparse_tokens_in_context"]) \
+        == (attended, in_context)
+    assert 0 < attended < in_context
+    assert 0 < st["admissions_overlapped"] < st["admitted"] == 5
+
+
 def test_the_same_prompt_twice_is_served_the_same(fam, weights, built):
     """No radix hit on a stack with recurrent layers: the second admission
     of a prompt prefills it whole again, is counted as skipped, and gives

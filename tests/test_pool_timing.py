@@ -88,18 +88,19 @@ def test_stamps_are_the_end_of_the_admitting_and_of_the_retiring_step(lm):
 
     rid = srv.submit([1, 2, 3], max_new=9)
     t_submit = clk.seen[-1]
-    srv.step()                      # prefill's token + one dispatch of 4
+    srv.step()                      # an empty pool: the prefill's token
     end_of_first = clk.seen[-1]
+    srv.step()                      # its first dispatch of 4
     assert srv.poll() == []
     srv.step()                      # the other 4: retired
-    end_of_second = clk.seen[-1]
+    end_of_third = clk.seen[-1]
     (c,) = srv.poll()
     assert c.id == rid and len(c.tokens) - c.prompt_len == 9
-    assert (c.t_first, c.n_first, c.t_last) == (end_of_first, 5,
-                                                end_of_second)
+    assert (c.t_first, c.n_first, c.t_last) == (end_of_first, 1,
+                                                end_of_third)
     assert c.t_submit == t_submit < c.t_admit < c.t_first
     assert c.ttft_s() == end_of_first - t_submit
-    assert c.tpot_s() == (end_of_second - end_of_first) / 4
+    assert c.tpot_s() == (end_of_third - end_of_first) / 8
 
     # one token: first and last sight in one step, nothing to divide by
     srv.submit([4, 5], max_new=1)
@@ -116,12 +117,14 @@ def test_a_cancelled_row_keeps_the_stamps_it_had(lm):
     srv.step()
     first = clk.seen[-1]
     srv.step()
+    srv.step()
     last = clk.seen[-1]
     assert srv.cancel(rid) == "live"
     srv.step()                      # retires it before any dispatch
     (c,) = srv.poll()
     assert c.cancelled and len(c.tokens) - c.prompt_len == 9
-    assert (c.t_first, c.n_first, c.t_last) == (first, 5, last)
+    assert (c.t_first, c.n_first, c.t_last) == (first, 1, last)
+    assert srv.stats()["dispatches"] == 2
     # cancelled before a slot took it: the submit stamp alone
     srv.submit([1, 2], max_new=4)
     srv.submit([3, 4], max_new=4)
@@ -130,6 +133,55 @@ def test_a_cancelled_row_keeps_the_stamps_it_had(lm):
     (q,) = srv.poll()
     assert q.t_submit is not None and q.t_first is None
     assert q.ttft_s() is None and q.tpot_s() is None
+
+
+@pytest.mark.parametrize("what, prompt, max_new", [
+    ("one-shot", [4, 5], 6),
+    ("chunked", [1, 2, 3, 4, 5, 6, 7, 8], 6),
+    ("one-token", [4, 5], 1),
+])
+def test_a_step_enqueues_its_dispatch_first_and_waits_for_the_chip_once(
+        lm, what, prompt, max_new):
+    """A busy pool and an arrival: the step enqueues the dispatch, then
+    the admission's programs behind it, then reads the device back, once."""
+    srv = _pool(lm, prompt_buckets=(4, 8), prefill_chunk=4)
+    srv.submit([1, 2, 3], max_new=24)
+    srv.step()
+    srv.step()
+    events = []
+
+    def noting(name, inner, when=lambda: True):
+        def call(*a, **kw):
+            if when():
+                events.append(name)
+            return inner(*a, **kw)
+        return call
+    srv._decode = noting("dispatch", srv._decode)
+    srv._advance_prefill = noting("chunk", srv._advance_prefill)
+    srv._finish_admission = noting("splice", srv._finish_admission)
+    srv._remaining_cursors = noting("read", srv._remaining_cursors,
+                                    lambda: srv._rc_cache is None)
+    rid = srv.submit(prompt, max_new)
+    before = srv.stats()
+    srv.step()
+    after = srv.stats()
+    assert after["dispatches"] == before["dispatches"] + 1
+    if what == "chunked":
+        assert events == ["dispatch", "chunk", "read"]
+        assert srv._pending is not None and after["admitted"] == 1
+        srv.step()                  # the last chunk and the splice
+        assert events[3:] == ["dispatch", "chunk", "splice", "read"]
+        after = srv.stats()
+    else:
+        assert events == ["dispatch", "splice", "read"]
+    assert (after["admitted"], after["admissions_overlapped"]) == (2, 1)
+    if what == "one-token":         # retired by the step's one read
+        (c,) = srv.poll()
+        assert c.id == rid and (c.n_first, c.t_first) == (1, c.t_last)
+    else:
+        assert srv.poll() == [] and len(srv._live) == 2
+        new = next(r for r in srv._live.values() if r.id == rid)
+        assert new.n_first == 1 and new.dispatch0 == after["dispatches"]
 
 
 def test_stamps_equal_the_benchmarks_step_clock(lm, monkeypatch):
@@ -293,27 +345,30 @@ def test_slot_wait_covers_the_steps_spent_in_the_queue(lm):
     srv.spans, srv.clock = store, store.clock
     a = srv.submit([1, 2, 3], 9, trace=("t:a", "root"))
     b = srv.submit([4, 5, 6], 5, trace=("t:b", "root"))
-    for _ in range(4):              # A: steps at 1 and 2; B: from 3 on
+    # A: admitted at 1, dispatched at 2 and 3, where it retires once the
+    # dispatch is read back: the slot is free for B from 4 on
+    for _ in range(5):
         store.clock.advance(1.0)
         srv.step()
     assert {c.id for c in srv.poll()} == {a, b}
     waits = {s["attrs"]["id"]: (s["t_start"], s["t_end"], s["parent"])
              for s in store.dump() if s["name"] == "lm.slot_wait"}
-    assert waits == {a: (0.0, 1.0, "root"), b: (0.0, 3.0, "root")}
+    assert waits == {a: (0.0, 1.0, "root"), b: (0.0, 4.0, "root")}
     decodes = {s["attrs"]["id"]: s for s in store.dump()
                if s["name"] == "lm.decode"}
     assert decodes[a]["attrs"] == {"id": a, "tokens": 9, "steps": 2,
-                                   "t_first": 1.0, "n_first": 5}
-    assert (decodes[a]["t_start"], decodes[a]["t_end"]) == (1.0, 2.0)
+                                   "t_first": 1.0, "n_first": 1}
+    assert (decodes[a]["t_start"], decodes[a]["t_end"]) == (2.0, 3.0)
     assert decodes[b]["attrs"]["steps"] == 1
+    assert (decodes[b]["t_start"], decodes[b]["t_end"]) == (5.0, 5.0)
     prefills = {s["attrs"]["id"]: s for s in store.dump()
                 if s["name"] == "lm.prefill"}
     assert decodes[b]["parent"] == prefills[b]["span_id"]
     # driven bare, the steps root the pool's timeline themselves
     bare = store.dump(trace_id="t:n0:loop:bare")
     steps = [s for s in bare if s["name"] == "lm.step"]
-    assert len(steps) == 4 and all(s["parent"] is None for s in steps)
-    assert prefills[b]["attrs"]["step"] == steps[2]["span_id"]
+    assert len(steps) == 5 and all(s["parent"] is None for s in steps)
+    assert prefills[b]["attrs"]["step"] == steps[3]["span_id"]
 
 
 def test_no_store_no_span_and_no_profiler_annotation(lm, monkeypatch):

@@ -1230,6 +1230,8 @@ def test_a_row_that_ends_mid_dispatch_stops_holding_the_bound(lm):
     deep = [int(t) for t in rng.integers(0, VOCAB, size=300)]
     srv.submit(deep, max_new=3)        # one token at admission, two left
     srv.submit([7, 8, 9, 10, 11], max_new=12)
+    srv.step()                         # an empty pool: admissions alone
+    assert srv.stats()["dispatches"] == 0
     srv.step()
     s = srv.stats()
     assert s["dispatches"] == 1
@@ -1262,3 +1264,184 @@ def test_context_counters_read_one_for_a_row_at_the_end_of_the_cache(lm):
     s = srv.stats()
     assert s["dispatches"] == 1
     assert s["decode_context_read"] == s["decode_context_held"] > 0
+
+
+# -- the decode dispatch goes first (ISSUE 35) ---------------------------------
+
+_ORDER_POOLS = {
+    "plain": {},
+    "radix-gathered": {"kv_block_size": 2, "kv_cache_blocks": 64},
+    "radix-paged": {"kv_block_size": 2, "kv_cache_blocks": 64,
+                    "paged_kernel": "xla"},
+}
+
+
+@pytest.mark.parametrize("kind", list(_ORDER_POOLS))
+def test_a_mixed_schedule_serves_generates_tokens_with_stamps_in_order(
+        lm, kind):
+    """Arrivals into an empty pool and into a busy one, a chunked
+    admission beside the dispatches, a one-token request, a cancel of a
+    live row and of a pending chunked admission: every stream is
+    `generate`'s, every first stamp shows the prefill's one token, and
+    `admissions_overlapped` counts the admissions of the steps that
+    dispatched."""
+    import itertools
+
+    model, params = lm
+    srv = DecodeServer(model, params, slots=3, prompt_len=8, max_len=40,
+                       prompt_buckets=(4, 8), prefill_chunk=4,
+                       **_ORDER_POOLS[kind])
+    ticks = itertools.count(1)
+    srv.clock = lambda: float(next(ticks))
+    rng = np.random.default_rng(35)
+    reqs, done, behind = {}, {}, []
+
+    def submit(n, max_new):
+        prompt = [int(t) for t in rng.integers(0, VOCAB, size=n)]
+        rid = srv.submit(prompt, max_new)
+        reqs[rid] = (prompt, max_new)
+        return rid
+
+    def step():
+        before = srv.stats()
+        left = srv.step()
+        after = srv.stats()
+        if after["dispatches"] > before["dispatches"]:
+            behind.append(after["admitted"] - before["admitted"])
+        done.update((c.id, c) for c in srv.poll())
+        return left
+
+    a = submit(3, 30)
+    step()                                    # an empty pool: no dispatch
+    assert srv.stats()["dispatches"] == 0 and len(srv._live) == 1
+    b = submit(2, 30)                         # one-shot, behind a's dispatch
+    c = submit(8, 6)                          # two chunks
+    step()
+    assert srv._pending is not None and len(srv._live) == 2
+    step()                                    # the second chunk: c is in
+    assert srv._pending is None and len(srv._live) == 3
+    one = submit(4, 1)                        # no slot is free yet
+    assert srv.cancel(b) == "live"
+    step()                  # b leaves at the step's start, `one` at its end
+    assert done[b].cancelled and one in done and len(srv._live) == 2
+    e = submit(8, 5)
+    step()                                    # e's first chunk
+    assert srv._pending is not None and srv.cancel(e) == "queued"
+    f = submit(5, 7)
+    while step():
+        pass
+    g = submit(3, 2)                          # an empty pool again
+    while step():
+        pass
+
+    assert set(done) == set(reqs)
+    for rid, (prompt, max_new) in reqs.items():
+        d, want = done[rid], expected(model, params, prompt, max_new)
+        if rid == e:                          # it never had a slot
+            assert d.cancelled and d.tokens == prompt and d.t_first is None
+            continue
+        if rid == b:
+            assert len(prompt) < len(d.tokens) < len(want)
+        else:
+            assert not d.cancelled and len(d.tokens) == len(want)
+        assert d.tokens == want[:len(d.tokens)], rid
+        assert d.t_submit < d.t_admit < d.t_first <= d.t_last, rid
+        assert d.n_first == 1, rid
+    assert done[one].t_first == done[one].t_last
+    st = srv.stats()
+    assert st["admitted"] == len(reqs) - 1
+    # a and g met an empty pool; b, c, one and f queued behind a dispatch
+    assert st["admissions_overlapped"] == sum(behind) == 4
+    assert done[a].cold_start and not done[g].cold_start
+    assert f in done
+
+
+def test_context_and_decode_spans_count_the_dispatches_a_row_took_part_in(
+        lm):
+    """Rows admitted mid-flight join the NEXT step's dispatch: what
+    `decode_context_read` adds up, and the `steps` of each `lm.decode`
+    span, equal a reckoning from the rows live BEFORE each step (four
+    steps a dispatch, rungs of 128, three slots)."""
+    from idunno_tpu.utils.spans import SpanStore
+
+    model, params, srv = _ladder_pool(lm, "native")
+    srv.spans = store = SpanStore("n0")
+    rng = np.random.default_rng(11)
+    plan = {0: [(300, 7)], 1: [(3, 10)], 2: [(130, 3), (4, 1)],
+            4: [(250, 6), (5, 9)], 5: [(126, 12)]}
+    max_new, read, held, dispatches = {}, 0, 0, 0
+    for i in range(30):
+        for n, m in plan.get(i, ()):
+            rid = srv.submit([int(t) for t in rng.integers(0, VOCAB, size=n)],
+                             m, trace=(f"t:{len(max_new)}", "root"))
+            max_new[rid] = m
+        rows = [(len(r["tokens"]) - 1,
+                 max_new[r["id"]] - (len(r["tokens"]) - r["prompt_len"]))
+                for r in srv.snapshot()]
+        if rows:
+            assert all(left > 0 for _cur, left in rows)
+            dispatches += 1
+            held += 4 * 512 * 3
+            for j in range(4):
+                need = max((cur + j for cur, left in rows if left > j),
+                           default=0) + 1
+                read += -(-need // 128) * 128 * 3
+        srv.step()
+    assert srv.pending() == 0 and len(srv.poll()) == len(max_new) == 7
+    st = srv.stats()
+    assert st["dispatches"] == dispatches
+    assert (st["decode_context_read"], st["decode_context_held"]) \
+        == (read, held)
+    steps = {s["attrs"]["id"]: s["attrs"] for s in store.dump()
+             if s["name"] == "lm.decode"}
+    # the prefill gives a row its first token, each dispatch four more; a
+    # row whose one token was the prefill's never met a dispatch
+    assert {rid: a["steps"] for rid, a in steps.items()} \
+        == {rid: -(-(m - 1) // 4) for rid, m in max_new.items() if m > 1}
+    assert all(a["n_first"] == 1 and a["tokens"] == max_new[rid]
+               for rid, a in steps.items())
+
+
+@pytest.mark.parametrize("pool_kw", [
+    {},
+    {"track_logprobs": True, "penalties": True, "kv_block_size": 2,
+     "kv_cache_blocks": 32, "paged_kernel": "xla"},
+], ids=["plain", "logprobs-penalties-paged"])
+def test_an_admission_sets_its_slots_state_with_one_program(
+        lm, monkeypatch, pool_kw):
+    """Every per-slot array an admission touches goes through one
+    `_set_rows` call (done eagerly they were dozens of tiny programs, and
+    behind a dispatch in flight the host ran into the runtime's limit on
+    programs in flight), and the slot reads what the eager sets left."""
+    from idunno_tpu.engine import serve_lm
+
+    model, params = lm
+    calls = []
+    inner = serve_lm._set_rows
+    monkeypatch.setattr(serve_lm, "_set_rows",
+                        lambda *a: calls.append(len(a[0])) or inner(*a))
+    srv = DecodeServer(model, params, slots=3, prompt_len=8, max_len=24,
+                       **pool_kw)
+    srv.submit([9, 8, 7], max_new=12)
+    srv.step()
+    srv.submit([1, 2, 3, 4, 5], max_new=6, temperature=0.7, top_p=0.9,
+               top_k=5, seed=3,
+               **({"presence_penalty": 0.5, "frequency_penalty": 0.25}
+                  if pool_kw else {}))
+    srv.step()                                # behind the first's dispatch
+    assert calls == [12 if pool_kw else 6] * 2
+    (slot,) = [s for s, r in srv._live.items() if r.id == 1]
+    first = int(np.asarray(srv._tokens)[slot, 5])
+    assert (int(srv._cursors[slot]), int(srv._remaining[slot])) == (5, 5)
+    assert (float(srv._temps[slot]), float(srv._top_ps[slot]),
+            int(srv._top_ks[slot])) == (np.float32(0.7), np.float32(0.9), 5)
+    if pool_kw:
+        counts = np.asarray(srv._counts)[slot]
+        assert counts.sum() == 1 and counts[first] == 1
+        assert (float(srv._pres[slot]), float(srv._freq[slot])) \
+            == (0.5, 0.25)
+        assert np.asarray(srv._logprobs)[slot, 5] < 0.0
+        assert int(srv._plens[slot]) == 0
+    done = {c.id: c for c in srv.run_until_drained()}
+    assert done[0].tokens == expected(model, params, [9, 8, 7], 12)
+    assert len(done[1].tokens) == 11 and done[1].tokens[5] == first

@@ -529,7 +529,9 @@ def test_two_node_cluster_collects_lm_trace(tmp_path):
         assert decode["parent"] == prefill["span_id"]
         assert decode["attrs"]["steps"] == 5
         assert decode["attrs"]["tokens"] == 6
-        assert decode["attrs"]["n_first"] == 2
+        # the first stamp shows the prefill's token alone: the row joins
+        # the next step's dispatch
+        assert decode["attrs"]["n_first"] == 1
         finish = by_name["lm.finish"][0]
         assert finish["parent"] == admit["span_id"]
         # the stamps ride the finish span, on the same injected clock
