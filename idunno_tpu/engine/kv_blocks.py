@@ -72,9 +72,11 @@ KV_LEAF_KEYS = ("cached_k", "cached_v", "k_scale", "v_scale")
 # recurrent layers (`models/hybrid.py`): the sparse layers' pooled keys
 # (a token axis of its own, 1 / kernel_stride of K's), the linear and
 # state-space layers' state and the latter's convolution window (no token
-# axis). They are spliced into a slot whole at
-# admission and are never cut into blocks: the pool's filter above leaves
-# them out, so a chain of blocks cannot rebuild such a slot
+# axis). A layer that runs an attention and a state-space mixer side by
+# side holds leaves of BOTH tuples under one layer index. They are spliced
+# into a slot whole at admission and are never cut into blocks: the pool's
+# filter above leaves them out, so a chain of blocks cannot rebuild such a
+# slot
 STATE_LEAF_KEYS = ("comp_k", "state", "conv")
 SLOT_LEAF_KEYS = KV_LEAF_KEYS + STATE_LEAF_KEYS
 

@@ -41,7 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from idunno_tpu.engine.generate import decode_model, init_cache
-from idunno_tpu.engine.kv_blocks import SLOT_LEAF_KEYS, concat_kv_prefix
+from idunno_tpu.engine.kv_blocks import (KV_LEAF_KEYS, SLOT_LEAF_KEYS,
+                                         concat_kv_prefix)
 from idunno_tpu.models.hybrid import SPARSE, UnsupportedStack
 from idunno_tpu.models.transformer import (TransformerLM, context_rungs,
                                            decode_apply, scan_compatible,
@@ -233,6 +234,10 @@ def _prefill(model: TransformerLM, params: Any, prompt: jnp.ndarray,
     params = dequantize_tree(params)     # no-op for full-precision trees
     logits, cache = decode_apply(dec, params, cache,
                                  prompt.astype(jnp.int32))
+    if getattr(model, "last_logits", False):
+        # such a stack computed the last real position's row alone
+        # (`models/hybrid.py`, from the cache's `valid`)
+        return cache, logits[0, 0]
     last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, axis=0,
                                         keepdims=False)     # [vocab]
     return cache, last
@@ -761,6 +766,11 @@ class DecodeServer:
             self._cache = jax.tree.map(
                 lambda s: zeros(s.shape, s.dtype, stacked=self._scan),
                 cache_shapes)
+        # the keys and values the slot cache holds (a gauge of `stats()`)
+        self._kv_cache_bytes = sum(
+            leaf.size * leaf.dtype.itemsize for path, leaf
+            in jax.tree_util.tree_flatten_with_path(cache_shapes)[0]
+            if getattr(path[-1], "key", None) in KV_LEAF_KEYS)
         self._cursors = zeros((slots,), jnp.int32)
         self._remaining = zeros((slots,), jnp.int32)
         # paged decode state: per-slot block table + paged-region length
@@ -1192,7 +1202,7 @@ class DecodeServer:
         }
         out = dict(self._stats, live=len(self._live),
                    queued=len(self._queue), slots=self.slots,
-                   config=config)
+                   kv_cache_bytes=self._kv_cache_bytes, config=config)
         if self._recurrent:
             out["recurrent_state_bytes"] = self.model.state_bytes(self.slots)
         if self._expert_counts is not None:
@@ -1821,7 +1831,9 @@ class DecodeServer:
         # capture them from whichever chunk covers that position
         t = p["true"]
         if p["off"] <= t - 1 < p["off"] + n:
-            p["last"] = logits[0, t - 1 - p["off"]]
+            # (one row where the stack computed that position's alone)
+            p["last"] = logits[0, min(t - 1 - p["off"],
+                                      logits.shape[1] - 1)]
         p["chunks"] += 1
         self._stats["prefill_chunks"] += 1
         if p["span"] is not None:

@@ -2,7 +2,7 @@
 
 `TransformerLM` is one block repeated, one cache shape a layer. This module
 serves stacks whose layers differ in kind (`mixers`, one name a layer) and
-whose caches differ with them. Four kinds of mixer:
+whose caches differ with them. Five kinds of mixer:
 
   ``minicpm4``        block-sparse softmax attention (InfLLM-v2): grouped
                       query heads without RoPE over `cached_k`/`cached_v`,
@@ -26,13 +26,25 @@ whose caches differ with them. Four kinds of mixer:
                       in prefill, one update a decode step), a gated
                       RMSNorm, the out-projection; no token axis.
   ``attention``       plain grouped-query softmax attention over
-                      `cached_k`/`cached_v`: no positional embedding, no
-                      q/k norm, no gate, no selection, scale `attn_scale`.
+                      `cached_k`/`cached_v`: no q/k norm, no gate, no
+                      selection, scale `attn_scale`; no positional
+                      embedding, or (`attn_rope`) rotary positions at
+                      `rope_theta` over the whole head; the keys times
+                      `key_mult`.
+  ``attention+mamba2`` both of the last two in ONE layer, side by side on
+                      one normed input (Falcon-H1): `h += ssm_out_mult *
+                      Mamba2(ssm_in_mult * u) + attn_out_mult *
+                      Attention(attn_in_mult * u)`, `u = RMSNorm(h)`. The
+                      layer's cache holds `cached_k`/`cached_v` (a token
+                      axis) AND `state` and `conv` (none). A name with a
+                      `+` is the kinds it joins: `has("mamba2")` and
+                      `has("attention")` hold of such a stack.
 
 and two kinds of feed-forward, one a stack (`ffn`): ``dense``, a gated SiLU
-MLP, and ``moe``, routed experts of which this chip holds a share
-(`models.moe.routed_experts`: dropless top-k over the whole router, the
-held experts' terms alone) plus a shared expert every token takes.
+MLP (its gate and its output times `mlp_mults`), and ``moe``, routed experts
+of which this chip holds a share (`models.moe.routed_experts`: dropless
+top-k over the whole router, the held experts' terms alone) plus a shared
+expert every token takes.
 
 The first two mixers sit in MiniCPM's pre-norm block (RMSNorm, bias-free
 projections, q/k RMSNorm, sigmoid output gate); every layer is `h += a *
@@ -52,9 +64,12 @@ leading, the slot axis second): `cached_k`/`cached_v` [L, B, T, kvh, d],
 [L, B, H, P, N] float32, `conv` [L, B, ssm_conv - 1, width], one
 `cursor`/`cursors` leaf and, in the scalar-cursor (prefill) shape, one
 `valid` leaf: positions at or past it are padding and enter neither the
-state, the window nor the pooled keys. A per-row (decode) cache of a
-``moe`` stack also carries `live` [B] (the rows that hold a request: the
-dispatch sets it, a dead row is routed nowhere) and, a run, the counters
+state, the window nor the pooled keys. A stack with `last_logits` computes
+a prefill's logits at the last real position alone ([B, 1, vocab]: a
+2048-token chunk's float32 logits over 261120 rows would be 2.1 GB). A
+per-row (decode) cache of a ``moe`` stack also carries `live` [B] (the rows
+that hold a request: the dispatch sets it, a dead row is routed nowhere)
+and, a run, the counters
 `expert_load` [L, held] and `expert_steps` [L, 3] (int32: picks on each
 held expert; held experts touched, steps and tokens, summed over the steps
 that had a live row). They have no slot axis and are read when
@@ -74,6 +89,7 @@ from idunno_tpu.models.transformer import rope
 
 SPARSE, LINEAR = "minicpm4", "lightning-attn"
 MAMBA, ATTENTION = "mamba2", "attention"
+PARALLEL = ATTENTION + "+" + MAMBA     # both mixers of one layer, summed
 DENSE, MOE = "dense", "moe"
 _HI = jax.lax.Precision.HIGHEST
 _NO_LIMIT = np.iinfo(np.int32).max
@@ -85,7 +101,8 @@ _QUERY_TILE = 512       # queries a tile of the plain attention's prefill
 _GROUP_FROM = 256
 # the cache leaves of a run that ride through its scan as carry, whole and
 # depth-stacked, and are written where they lie
-_CARRIED = {ATTENTION: ("cached_k", "cached_v"), MAMBA: ("state",)}
+_CARRIED = {ATTENTION: ("cached_k", "cached_v"), MAMBA: ("state",),
+            PARALLEL: ("cached_k", "cached_v", "state")}
 # the parameters of a run that its scan does not slice a layer: the routed
 # experts, which the grouped product reads from the whole stack
 _WHOLE = ("w1", "w2")
@@ -102,7 +119,7 @@ class HybridLM:
     vocab: int
     dim: int
     mlp_dim: int
-    mixers: tuple            # one of SPARSE / LINEAR / MAMBA / ATTENTION
+    mixers: tuple            # SPARSE / LINEAR / MAMBA / ATTENTION / PARALLEL
     layer_ids: tuple         # each layer's PUBLISHED index (its slopes)
     published_depth: int
     num_heads: int           # sparse and attention layers: query heads
@@ -123,10 +140,23 @@ class HybridLM:
     window_size: int = 2048
     dense_len: int = 8192
     attn_scale: float | None = None    # attention: None = 1 / sqrt(d)
+    attn_rope: bool = False  # attention: rotary positions at `rope_theta`
+    key_mult: float = 1.0    # attention: the keys' multiplier
+    # a PARALLEL layer's branch multipliers, on the normed input and on the
+    # out-projection's output of each mixer
+    attn_in_mult: float = 1.0
+    attn_out_mult: float = 1.0
+    ssm_in_mult: float = 1.0
+    ssm_out_mult: float = 1.0
+    # mamba2: multipliers of the in-projection's output, a segment each
+    # (gate, x, B, C, step sizes); () = none
+    ssm_mults: tuple = ()
+    mlp_mults: tuple = (1.0, 1.0)      # dense MLP: on the gate, on the output
     ssm_heads: int = 0       # mamba2: heads H, each of `ssm_head_dim` (P)
     ssm_head_dim: int = 0
     ssm_state: int = 0       # N
-    ssm_groups: int = 1      # B and C are shared by the heads of a group
+    ssm_groups: int = 1      # B and C are shared by the heads of a group,
+    #                          and the gated norm is over a group's channels
     ssm_conv: int = 4        # taps of the depthwise convolution
     ssm_chunk: int = 256     # tokens a chunk of the prefill scan
     ffn: str = DENSE         # DENSE (width `mlp_dim`) or MOE
@@ -135,6 +165,8 @@ class HybridLM:
     experts_held: tuple = (0, 0)       # (first, count) held by this chip
     shared_dim: int = 0      # the shared expert's width; `mlp_dim` is a
     #                          routed expert's
+    last_logits: bool = False          # a prefill's logits: the last real
+    #                                    position's alone, [B, 1, vocab]
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     decode: bool = False
@@ -149,7 +181,7 @@ class HybridLM:
     def __post_init__(self):
         if len(self.mixers) != len(self.layer_ids):
             raise ValueError("one published index a layer")
-        bad = set(self.mixers) - {SPARSE, LINEAR, MAMBA, ATTENTION}
+        bad = set(self.mixers) - {SPARSE, LINEAR, MAMBA, ATTENTION, PARALLEL}
         if bad:
             raise ValueError(f"unknown mixer kinds {sorted(bad)}")
         if self.ffn not in (DENSE, MOE):
@@ -164,6 +196,12 @@ class HybridLM:
         if self.has(MAMBA) and self.ssm_heads % self.ssm_groups:
             raise ValueError("state-space heads must be a multiple of "
                              "their groups")
+        if len(self.ssm_mults) not in (0, 5) or len(self.mlp_mults) != 2:
+            raise ValueError("ssm_mults is one multiplier a segment (gate, "
+                             "x, B, C, step sizes), mlp_mults two")
+        if self.has(PARALLEL) and self.ffn != DENSE:
+            raise ValueError("a layer of both mixers takes the dense "
+                             "feed-forward")
         if self.ffn == MOE:
             first, held = self.experts_held
             if not (0 <= first and 0 < held
@@ -175,8 +213,9 @@ class HybridLM:
                 raise ValueError("experts_per_token must be 1..experts")
 
     def has(self, kind: str) -> bool:
-        """Whether the stack has a layer of ``kind``."""
-        return kind in self.mixers
+        """Whether the stack has a layer of ``kind``, alone or as one of
+        the mixers a layer joins."""
+        return any(kind in (m, *m.split("+")) for m in self.mixers)
 
     @property
     def depth(self) -> int:
@@ -240,7 +279,7 @@ class HybridLM:
         for kind in self.mixers:
             if kind == LINEAR:
                 per_row += 4 * self.lightning_heads * self.lightning_head_dim ** 2
-            elif kind == MAMBA:
+            elif kind in (MAMBA, PARALLEL):
                 per_row += (4 * self.ssm_heads * self.ssm_head_dim
                             * self.ssm_state
                             + (self.ssm_conv - 1) * self.ssm_conv_width
@@ -269,20 +308,21 @@ class HybridLM:
         kv = (batch, tm, self.num_kv_heads, self.head_dim)
         for r, (kind, ids) in enumerate(self.runs()):
             n = len(ids)
-            if kind in (SPARSE, ATTENTION):
+            run = {}
+            if kind in (SPARSE, ATTENTION, PARALLEL):
                 run = {k: jnp.zeros((n,) + kv, self.dtype)
                        for k in ("cached_k", "cached_v")}
                 if kind == SPARSE:
                     run["comp_k"] = jnp.zeros((n, batch, nk) + kv[2:],
                                               jnp.float32)
-            elif kind == MAMBA:
-                run = {"state": jnp.zeros(
+            if kind in (MAMBA, PARALLEL):
+                run.update(state=jnp.zeros(
                     (n, batch, self.ssm_heads, self.ssm_head_dim,
                      self.ssm_state), jnp.float32),
-                       "conv": jnp.zeros(
+                           conv=jnp.zeros(
                     (n, batch, self.ssm_conv - 1, self.ssm_conv_width),
-                    self.dtype)}
-            else:
+                    self.dtype))
+            if kind == LINEAR:
                 d = self.lightning_head_dim
                 run = {"state": jnp.zeros(
                     (n, batch, self.lightning_heads, d, d), jnp.float32)}
@@ -558,19 +598,37 @@ def _ssd(xs, dt, a, bm, cm, state, chunk: int):
     return y[:, :t], state
 
 
-def _mamba_layer(model: HybridLM, p, c, held, i, x, mask):
-    """Layer ``i`` of a run whose stacked float32 states (``held``) ride
-    through the scan as carry (updated where they lie; as the scan's
-    ``xs``/``ys`` every layer's state was copied out and back a step: 5.5
-    ms of a 28 ms step at granite-4.0-h-small's widths). ``mask``
-    [B, T]: the real tokens (a prefix of a row, or none of it). What it
-    leaves out enters neither the window nor the state."""
-    b, t, _ = x.shape
+def _gated_norm(model: HybridLM, y, scale):
+    """RMSNorm of the gated scan output ``y`` [B, T, d_inner], float32: over
+    all of it, or, with several `ssm_groups`, over each group's channels
+    (a group's heads are consecutive)."""
+    g = model.ssm_groups
+    if g == 1:
+        return _rms(y, scale, model.eps, model.dtype)
+    grouped = y.shape[:-1] + (g, y.shape[-1] // g)
+    return _rms(y.reshape(grouped), scale.reshape(grouped[-2:]), model.eps,
+                model.dtype).reshape(y.shape)
+
+
+def _mamba_mix(model: HybridLM, p, c, held, i, hn, mask):
+    """The state-space mixer of layer ``i`` over the normed ``hn``; the
+    run's stacked float32 states (``held``) ride through the scan as
+    carry (updated where they lie; as the scan's ``xs``/``ys`` every
+    layer's state was copied out and back a step: 5.5 ms of a 28 ms step at
+    granite-4.0-h-small's widths). ``mask`` [B, T]: the real tokens (a
+    prefix of a row, or none of it). What it leaves out enters neither the
+    window nor the state."""
+    b, t, _ = hn.shape
     h, pd, n, g = (model.ssm_heads, model.ssm_head_dim, model.ssm_state,
                    model.ssm_groups)
     di, width, taps = h * pd, model.ssm_conv_width, model.ssm_conv
-    hn = _rms(x, p["ln1"], model.eps, model.dtype)
-    z, xc, dt = jnp.split(hn @ p["w_in"], (di, di + width), axis=-1)
+    proj = hn @ p["w_in"]
+    if model.ssm_mults:
+        # one multiplier a segment of the in-projection's output
+        proj = proj * jnp.asarray(np.repeat(
+            np.asarray(model.ssm_mults, np.float32),
+            (di, di, g * n, g * n, h)), proj.dtype)
+    z, xc, dt = jnp.split(proj, (di, di + width), axis=-1)
     seq = jnp.concatenate([c["conv"], xc.astype(c["conv"].dtype)], axis=1)
     conv = p["conv_b"].astype(jnp.float32) + sum(
         seq[:, j:j + t].astype(jnp.float32)
@@ -595,8 +653,14 @@ def _mamba_layer(model: HybridLM, p, c, held, i, x, mask):
         states, state.reshape(states.shape[1:]), i, 0)
     y = y + p["D"].astype(jnp.float32).reshape(g, h // g)[..., None] * xs
     y = y.reshape(b, t, di) * jax.nn.silu(z.astype(jnp.float32))
-    out = _rms(y, p["norm"], model.eps, model.dtype) @ p["w_out"]
+    out = _gated_norm(model, y, p["norm"]) @ p["w_out"]
     return out, {"state": states}, {"conv": window}
+
+
+def _mamba_layer(model: HybridLM, p, c, held, i, x, mask):
+    """Layer ``i`` of a state-space run: the mixer over its own norm."""
+    hn = _rms(x, p["ln1"], model.eps, model.dtype)
+    return _mamba_mix(model, p, c, held, i, hn, mask)
 
 
 # -- plain attention ---------------------------------------------------------
@@ -611,16 +675,28 @@ def _attend(q, kc, vc, pos, scale):
     return jnp.einsum("bkgts,bskd->btkgd", w.astype(vc.dtype), vc)
 
 
-def _attention_layer(model: HybridLM, p, kv, i, x, pos):
-    """Layer ``i`` of a run whose stacked K/V ``kv`` ride through the scan
-    as carry: the new tokens' rows are written where they lie, and the
-    layer's slice is read from there (as `scanned_apply` does)."""
-    b, t, _ = x.shape
+def _attention_mix(model: HybridLM, p, kv, i, hn, pos):
+    """Plain attention of layer ``i`` over the normed ``hn``; the run's
+    stacked K/V ``kv`` ride through the scan as carry: the new tokens' rows
+    are written where they lie, and the layer's slice is read from there
+    (as `scanned_apply` does)."""
+    b, t, _ = hn.shape
     kvh, d = model.num_kv_heads, model.head_dim
-    hn = _rms(x, p["ln1"], model.eps, model.dtype)
-    q = _proj(hn, p["wq"]).reshape(b, t, kvh, model.num_heads // kvh, d)
+
+    def turned(x):
+        if not model.attn_rope:
+            return x
+        return rope(x, base=model.rope_theta,
+                    positions=pos.astype(jnp.float32))
+
+    q = turned(_proj(hn, p["wq"])).reshape(
+        b, t, kvh, model.num_heads // kvh, d)
     p0 = pos[:, 0]
-    kv = {"cached_k": _write_kv(kv["cached_k"], _proj(hn, p["wk"]), p0,
+    k = _proj(hn, p["wk"])
+    if model.key_mult != 1.0:
+        k = k * model.key_mult
+    k = turned(k)
+    kv = {"cached_k": _write_kv(kv["cached_k"], k, p0,
                                 model.decode_per_row, layer=i),
           "cached_v": _write_kv(kv["cached_v"], _proj(hn, p["wv"]), p0,
                                 model.decode_per_row, layer=i)}
@@ -637,7 +713,27 @@ def _attention_layer(model: HybridLM, p, kv, i, x, pos):
         o = _attend(q, kc, vc, pos, scale)
     out = jnp.einsum("bthk,hkd->btd",
                      o.reshape(b, t, model.num_heads, d), p["wo"])
-    return out, kv, {}
+    return out, kv
+
+
+def _attention_layer(model: HybridLM, p, kv, i, x, pos):
+    """Layer ``i`` of a plain attention run: the mixer over its own norm."""
+    hn = _rms(x, p["ln1"], model.eps, model.dtype)
+    return (*_attention_mix(model, p, kv, i, hn, pos), {})
+
+
+def _parallel_layer(model: HybridLM, p, c, held, i, x, pos, mask):
+    """Layer ``i`` of a run whose layers hold BOTH mixers: each reads the
+    one normed input times its `*_in_mult`, and their out-projections,
+    times `*_out_mult`, are summed. ``held`` carries the run's K/V and
+    its states alike."""
+    hn = _rms(x, p["ln1"], model.eps, model.dtype)
+    ssm, state, new = _mamba_mix(model, p, c, held, i,
+                                 hn * model.ssm_in_mult, mask)
+    att, kv = _attention_mix(model, p, held, i, hn * model.attn_in_mult,
+                             pos)
+    mix = ssm * model.ssm_out_mult + att * model.attn_out_mult
+    return mix, {**kv, **state}, new
 
 
 # -- the stack -------------------------------------------------------------
@@ -649,7 +745,12 @@ def _ffn(model: HybridLM, p, experts, i, c, x, mask):
     is layer ``i`` (`_WHOLE`)."""
     hn = _rms(x, p["ln2"], model.eps, model.dtype)
     if model.ffn == DENSE:
-        return (jax.nn.silu(hn @ p["wg"]) * (hn @ p["wu"])) @ p["wd"], {}
+        gate_mult, out_mult = model.mlp_mults
+        gate = hn @ p["wg"]
+        if gate_mult != 1.0:
+            gate = gate * gate_mult
+        out = (jax.nn.silu(gate) * (hn @ p["wu"])) @ p["wd"]
+        return (out if out_mult == 1.0 else out * out_mult), {}
     b, t, d = hn.shape
     y, load = routed_experts(
         hn.reshape(b * t, d), p["router"], experts["w1"], experts["w2"],
@@ -752,9 +853,9 @@ def hybrid_apply(model: HybridLM, params, cache, tokens, paged=None):
     x = (params["embed"][tokens] * model.scale_emb).astype(model.dtype)
     new_cache = dict(cache)
     for r, (kind, ids) in enumerate(model.runs()):
-        # a plain attention run's K/V and a state-space run's states are
-        # the scan's carry (written in place); every other leaf is sliced
-        # a layer and written back
+        # a plain attention run's K/V and a state-space run's states (a
+        # run of both mixers: both) are the scan's carry (written in
+        # place); every other leaf is sliced a layer and written back
         c_r, p_r = cache[f"run{r}"], params["runs"][r]
         held = {k: c_r[k] for k in _CARRIED.get(kind, ())}
         rest = {k: v for k, v in c_r.items() if k not in held}
@@ -771,6 +872,9 @@ def hybrid_apply(model: HybridLM, params, cache, tokens, paged=None):
             elif kind == MAMBA:
                 mix, held, new = _mamba_layer(model, p_l, c_l, held, i, h,
                                               mask)
+            elif kind == PARALLEL:
+                mix, held, new = _parallel_layer(model, p_l, c_l, held, i,
+                                                 h, pos, mask)
             else:
                 mix, held, new = _attention_layer(model, p_l, held, i, h,
                                                   pos)
@@ -789,6 +893,11 @@ def hybrid_apply(model: HybridLM, params, cache, tokens, paged=None):
         new_cache[f"run{r}"] = {**held, **rest}
     if not model.decode_per_row:
         new_cache["cursor"] = cache["cursor"] + t
+    if model.last_logits and not model.decode_per_row:
+        # a prefill: the head over the last real position alone (the
+        # chunk's last where the prompt goes on)
+        x = jax.lax.dynamic_slice_in_dim(
+            x, jnp.clip(valid - 1 - p0[0], 0, t - 1), 1, axis=1)
     hn = _rms(x, params["norm_f"], model.eps, jnp.float32) / model.logit_div
     if "head" in params:
         logits = hn.astype(model.dtype) @ params["head"]

@@ -326,6 +326,88 @@ def test_granite_dispatch_updates_states_and_kv_in_place(one_chip):
                           compiled.as_text())
 
 
+def _falcon_pool(slots: int, max_len: int):
+    """A toy pool of the family `falcon_h1` (its rehearsal's widths, the
+    published vocabulary's logits aside) and the benchmark's configuration:
+    the pool's `_dec` is widened by the caller, nothing of the real size is
+    allocated."""
+    from benchmark import manifest, system
+
+    man = manifest.Manifest()
+    cfg = man.config(man.cell("falcon-h1-34b-instruct.reasoning"))
+    fam = man.family(cfg)
+    toy = system.model_config(cfg, True, fam)
+    model, params, _kw = fam.program.build(
+        toy, fam.weights.make_weights(toy, 1))
+    srv = DecodeServer(model, params, slots=slots, prompt_len=128,
+                       max_len=max_len, decode_steps=4,
+                       prompt_buckets=(128,), kv_block_size=64,
+                       kv_cache_blocks=4)
+    return cfg, fam, srv
+
+
+def test_falcon_h1_dispatch_updates_kv_and_states_in_place(one_chip):
+    """`DecodeServer._build_decode`'s `jit_run` over Falcon-H1's six layers
+    of BOTH mixers at the published widths, 32 slots x 4096 (a toy pool
+    whose `_dec` is widened after the build): the new cache IS the donated
+    one (K/V 1.6 GB and float32 states 0.8 GB, both the carry of the one
+    scan), the program's own memory holds no second copy of either, and no
+    whole stacked leaf is copied."""
+    slots, max_len = 32, 4096
+    cfg, fam, srv = _falcon_pool(slots, max_len)
+    srv._dec = dataclasses.replace(
+        fam.program.model_of(cfg), decode=True, decode_per_row=True,
+        max_decode_len=max_len)
+    wide = _described(fam.program.program_params(jax.eval_shape(
+        lambda: fam.weights.make_weights(cfg, 1))), one_chip)
+    cache = _described(jax.eval_shape(
+        lambda: srv._dec.init_cache(slots)), one_chip)
+    compiled = _compile_run(srv, wide, cache, one_chip)
+    mem = compiled.memory_analysis()
+    states = 6 * slots * 32 * 128 * 256 * 4
+    assert _nbytes(cache) > 2.4e9 and states > 0.8e9
+    assert mem.alias_size_in_bytes >= _nbytes(cache)
+    # 0.79 GB of temporaries, none a cache: the in-projection's stack
+    # [6, 5120, 9248] re-laid out once a dispatch (0.57 GB: 9248 is no
+    # multiple of 128 and the chip holds the argument with 5120 minor) and
+    # one layer's K or V [32, 4096, 4, 128] with the heads before the tokens
+    # (0.13 GB: PERF.md section 7)
+    assert mem.temp_size_in_bytes < 1e9 < _nbytes(cache) // 2
+    text = compiled.as_text()
+    assert not re.findall(r"= f32\[6,32,32,128,256\]\S* copy\(", text)
+    assert not re.findall(r"= bf16\[6,32,4096,4,128\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("bucket, tokens", [(2048, 2048), (2048, 256)],
+                         ids=["prefill2048", "chunk256-of-2048"])
+def test_falcon_h1_prefill_at_published_widths(one_chip, bucket, tokens):
+    """A 2048-token prefill of Falcon-H1's six layers at the published
+    widths, whole and as a 256-token chunk of a 2048-token row (the cell's
+    `prefill_chunk`): the chunked
+    scan over [32, 128, 256] states, attention a tile of queries at a time
+    and the head over ONE position (every position's float32 logits over
+    261120 rows would be 2.1 GB): temporaries under 3 GB beside 10.5 GB of
+    weights, the row's cache updated where it lies."""
+    from benchmark import manifest
+
+    man = manifest.Manifest()
+    cfg = man.config(man.cell("falcon-h1-34b-instruct.reasoning"))
+    fam = man.family(cfg)
+    dec = dataclasses.replace(fam.program.model_of(cfg), decode=True,
+                              max_decode_len=bucket)
+    wide = _described(fam.program.program_params(jax.eval_shape(
+        lambda: fam.weights.make_weights(cfg, 1))), one_chip)
+    cache = _described(jax.eval_shape(lambda: dec.init_cache(1)), one_chip)
+    compiled = jax.jit(dec.decode_apply, donate_argnums=(1,)).lower(
+        wide, cache, jax.ShapeDtypeStruct((1, tokens), jnp.int32,
+                                          sharding=one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert 10.4e9 < mem.argument_size_in_bytes < 10.7e9
+    assert mem.temp_size_in_bytes < 3e9
+    assert mem.alias_size_in_bytes >= _nbytes(cache)
+    assert mem.output_size_in_bytes < _nbytes(cache) + 2 * 261120 * 4
+
+
 # -- the decode programs over the slot cache, at the benchmark's widths ------
 
 _SLOTS, _MAX_LEN, _VOCAB = 28, 4096, 49152
