@@ -44,8 +44,8 @@ from idunno_tpu.engine.generate import decode_model, init_cache
 from idunno_tpu.engine.kv_blocks import (KV_LEAF_KEYS, SLOT_LEAF_KEYS,
                                          concat_kv_prefix)
 from idunno_tpu.models.hybrid import SPARSE, UnsupportedStack
-from idunno_tpu.models.transformer import (TransformerLM, context_rungs,
-                                           decode_apply, scan_compatible,
+from idunno_tpu.models.transformer import (TransformerLM, decode_apply,
+                                           scan_compatible,
                                            stack_block_params)
 from idunno_tpu.parallel.sharding import (sampling_collective_bytes,
                                           tp_collective_bytes)
@@ -860,14 +860,15 @@ class DecodeServer:
         # them: a copy on the device, because the cache itself is donated to
         # the next dispatch while another thread may be asking `stats()`
         self._expert_counts = None
-        # the context lengths the decode step can read
-        # (`MultiHeadAttention._decode_step`), None for a model that brings
-        # its own step; how far along the token axis the dispatches' steps
-        # read the slot cache, and how far it reaches: a token a slot a
-        # step (`_count_context`)
-        self._ladder = None
-        if getattr(model, "decode_apply", None) is None:
-            self._ladder = np.asarray(context_rungs(max_len))
+        # the context lengths the decode step can read, as the model says
+        # (`decode_context_rungs`: `MultiHeadAttention._decode_step`, a
+        # hybrid stack's `_attend_live`), None for a model whose step reads
+        # what it reads whatever the cursors; how far along the token axis
+        # the dispatches' steps read the slot cache, and how far it
+        # reaches: a token a slot a step (`_count_context`)
+        rungs = model.decode_context_rungs(max_len, slots)
+        self._ladder = None if rungs is None else np.asarray(rungs)
+        if self._ladder is not None:
             self._stats.update(decode_context_read=0, decode_context_held=0)
         # flips True at the first decode dispatch and NEVER resets (the
         # warmup() stats reset must not re-mark a warmed pool cold):
@@ -928,13 +929,20 @@ class DecodeServer:
         slot instead of at its stale cursor, the next `_insert` overwrites
         the slot's rows from position 0 before the slot is live again, and
         the block pool is written from prefill caches, never from a slot.
-        Tokens and the sampling tail keep the true cursors."""
+        Tokens and the sampling tail keep the true cursors. The same holds
+        of a hybrid stack whose attention takes the ladder
+        (`models/hybrid.py:_attend_live`): `_splice_rows` lands EVERY leaf
+        of a slot whole (K/V, scan state, window) before the slot is live
+        again, so what a dead row at cursor 0 writes into its own K/V and
+        state is overwritten the same way. A model that answers
+        `decode_context_rungs` with None (a block-sparse or linear stack)
+        is handed the cursors as they are."""
         dec = self._dec
         track = self.track_logprobs     # static: traced once
         pen = self.penalties            # static: traced once
         paged = self._paged             # static: traced once
-        # a model that brings its own step reads what it reads, whatever
-        # the cursors: it is handed them as they are
+        # a model without a ladder reads what it reads, whatever the
+        # cursors: it is handed them as they are
         bound_by_live = self._ladder is not None
 
         def run(params, tokens, cache, cursors, remaining, temps,

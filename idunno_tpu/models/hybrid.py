@@ -30,7 +30,10 @@ whose caches differ with them. Five kinds of mixer:
                       selection, scale `attn_scale`; no positional
                       embedding, or (`attn_rope`) rotary positions at
                       `rope_theta` over the whole head; the keys times
-                      `key_mult`.
+                      `key_mult`. The per-row decode step reads the LIVE
+                      context: the first rung of `context_rungs` that
+                      holds the deepest cursor it is handed, a tile at a
+                      time over the carried K/V (`_attend_live`).
   ``attention+mamba2`` both of the last two in ONE layer, side by side on
                       one normed input (Falcon-H1): `h += ssm_out_mult *
                       Mamba2(ssm_in_mult * u) + attn_out_mult *
@@ -57,7 +60,10 @@ scans in one program, not twelve dispatches.
 `HybridLM` is a frozen dataclass, not a flax module: it is the jit-static
 description, `init_cache` and `decode_apply` are its two entry points, and
 `engine.generate.init_cache` / `models.transformer.decode_apply` hand over
-to them, so `engine/generate.py` and `DecodeServer` stay layout-blind. The
+to them, so `engine/generate.py` and `DecodeServer` stay layout-blind.
+`decode_context_rungs` tells the pool whether the stack's decode step
+bounds its read by the cursors (the `attention` kinds, without a
+block-sparse layer): the pool then hands a dead row's cursor as 0. The
 cache follows the scanned layout's rules (leaves named by kind, depth
 leading, the slot axis second): `cached_k`/`cached_v` [L, B, T, kvh, d],
 `comp_k` [L, B, T/stride, kvh, d] float32, `state` [L, B, H, d, d] or
@@ -85,7 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from idunno_tpu.models.moe import routed_experts
-from idunno_tpu.models.transformer import rope
+from idunno_tpu.models.transformer import context_rungs, rope
 
 SPARSE, LINEAR = "minicpm4", "lightning-attn"
 MAMBA, ATTENTION = "mamba2", "attention"
@@ -99,6 +105,15 @@ _QUERY_TILE = 512       # queries a tile of the plain attention's prefill
 # below this many tokens an expert layer multiplies every token by every
 # held expert; from it on, tokens are grouped by expert (`routed_experts`)
 _GROUP_FROM = 256
+# a trip of the decode step's context ladder (`_attend_live`) stages one
+# tile of K and one of V, every slot's, before the products read them (the
+# chip's compiler fuses no dynamic slice into a product); up to this many
+# bytes a tile both stay in the chip's fast memory, so the cache is read
+# from HBM once. At granite-4.0-h-small's 60 MB (48 slots x 608 tokens x 8
+# heads) only one did, and the step cost 0.5 ms MORE than a read of the
+# whole axis, which that stack's one-layer run needs no slice for
+# (PERF.md section 6, PR 37)
+_STAGED_TILE_BYTES = 32 << 20
 # the cache leaves of a run that ride through its scan as carry, whole and
 # depth-stacked, and are written where they lie
 _CARRIED = {ATTENTION: ("cached_k", "cached_v"), MAMBA: ("state",),
@@ -335,6 +350,30 @@ class HybridLM:
 
     def decode_apply(self, params, cache, tokens, paged=None):
         return hybrid_apply(self, params, cache, tokens, paged=paged)
+
+    def decode_context_rungs(self, max_len: int,
+                             rows: int) -> tuple[int, ...] | None:
+        """What `DecodeServer` asks of any model: the context lengths its
+        per-row decode step can read of a cache of ``rows`` slots x
+        ``max_len`` tokens (`models.transformer.context_rungs`; the step
+        reads the first that holds the deepest cursor it is handed, so the
+        pool hands a dead row 0 and counts `decode_context_*`), or None
+        where the step reads what it reads whatever the cursors (it is
+        handed them as they are). Here: a stack whose token-axis caches
+        are all plain attention's (`_attend_live`); a block-sparse layer
+        reads its own selection. Eight tiles, or as many more as keep a
+        staged tile within `_STAGED_TILE_BYTES`."""
+        if not self.has(ATTENTION) or self.has(SPARSE):
+            return None
+        per_token = (rows * self.num_kv_heads * self.head_dim
+                     * jnp.dtype(self.dtype).itemsize)
+        tiles = 8
+        while True:
+            rungs = context_rungs(max_len, tiles)
+            if (rungs[0] * per_token <= _STAGED_TILE_BYTES
+                    or len(rungs) < tiles):     # the least tile: no finer
+                return rungs
+            tiles *= 2
 
 
 # -- pieces ----------------------------------------------------------------
@@ -675,11 +714,63 @@ def _attend(q, kc, vc, pos, scale):
     return jnp.einsum("bkgts,bskd->btkgd", w.astype(vc.dtype), vc)
 
 
+def _attend_live(q, kc, vc, i, pos, scale, rungs):
+    """`_attend` for rows that sit each at its own depth (``q`` [B, T, K,
+    G, d], ``pos`` [B, T]), over layer ``i`` of the run's carried stacks
+    ``kc``/``vc`` [L, B, S, K, d] read where they lie: the first of
+    ``rungs`` (`context_rungs`) that holds the deepest row's new tokens, a
+    tile at a time with a running softmax, as
+    `MultiHeadAttention._decode_step` reads its cache. A masked position
+    weighs exactly 0, so the tiles left unread change nothing, and the
+    sum's order differs from `_attend`'s one softmax by float rounding.
+    The cursors it is handed set the bound: the caller hands a dead row 0
+    (`engine.serve_lm._build_decode`). A loop, not a `lax.switch` over
+    the rungs: the compiler copied the whole leaf into every branch
+    (PERF.md section 6, PR 32)."""
+    b, t, kvh, g, d = q.shape
+    tile, top = rungs[0], rungs[-1]
+
+    def one_tile(j, carry):
+        m, l, acc = carry
+        # the last rung may be no whole tile: its slice starts early, and
+        # leaves what the tile before it covered
+        lo = jnp.minimum(j * tile, top - tile)
+        k_t, v_t = (jax.lax.squeeze(jax.lax.dynamic_slice(
+            leaf, (i, 0, lo, 0, 0), (1, b, tile, kvh, d)), (0,))
+            for leaf in (kc, vc))
+        s = jnp.einsum("btkgd,bskd->bkgts", q, k_t,
+                       preferred_element_type=jnp.float32) * scale
+        at = lo + jnp.arange(tile)
+        seen = (at[None, None, :] <= pos[:, :, None]) & (at >= j * tile)
+        s = jnp.where(seen[:, None, None], s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # a row with nothing live so far has m_new -inf
+        base = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        p = jnp.exp(s - base[..., None])
+        keep = jnp.exp(m - base)
+        return (m_new, l * keep + jnp.sum(p, axis=-1),
+                acc * keep[..., None]
+                + jnp.einsum("bkgts,bskd->bkgtd", p.astype(v_t.dtype), v_t,
+                             preferred_element_type=jnp.float32))
+
+    stat = (b, kvh, g, t)
+    _m, l, acc = jax.lax.fori_loop(
+        0, jnp.sum(jnp.max(pos[:, 0]) + t > jnp.asarray(rungs[:-1])) + 1,
+        one_tile, (jnp.full(stat, -jnp.inf, jnp.float32),
+                   jnp.zeros(stat, jnp.float32),
+                   jnp.zeros(stat + (d,), jnp.float32)))
+    return jnp.transpose(acc / l[..., None], (0, 3, 1, 2, 4)).astype(vc.dtype)
+
+
 def _attention_mix(model: HybridLM, p, kv, i, hn, pos):
     """Plain attention of layer ``i`` over the normed ``hn``; the run's
     stacked K/V ``kv`` ride through the scan as carry: the new tokens' rows
     are written where they lie, and the layer's slice is read from there
-    (as `scanned_apply` does)."""
+    (as `scanned_apply` does). The per-row (decode) shape reads the live
+    context (`_attend_live`) where the cache has more than one rung; the
+    scalar-cursor shapes (prefill, a chunk, `engine.generate`'s step, the
+    oracle the pool's streams are held to) read the whole axis: a prefill
+    cache is as long as its bucket."""
     b, t, _ = hn.shape
     kvh, d = model.num_kv_heads, model.head_dim
 
@@ -700,17 +791,23 @@ def _attention_mix(model: HybridLM, p, kv, i, hn, pos):
                                 model.decode_per_row, layer=i),
           "cached_v": _write_kv(kv["cached_v"], _proj(hn, p["wv"]), p0,
                                 model.decode_per_row, layer=i)}
-    kc, vc = (jax.lax.dynamic_index_in_dim(kv[k], i, 0, keepdims=False)
-              for k in ("cached_k", "cached_v"))
     scale = d ** -0.5 if model.attn_scale is None else model.attn_scale
-    if t > _QUERY_TILE:
-        # a long chunk a tile of queries at a time: the float32 scores of
-        # 2048 queries over 4096 keys would be a gigabyte
-        o = jax.lax.map(lambda qp: _attend(qp[0], kc, vc, qp[1], scale),
-                        (_cut(q, _QUERY_TILE), _cut(pos, _QUERY_TILE)))
-        o = jnp.moveaxis(o, 0, 1).reshape((b, -1) + q.shape[2:])[:, :t]
+    rungs = (model.decode_context_rungs(model.max_decode_len, b)
+             if model.decode_per_row else None)
+    if rungs is not None and len(rungs) > 1:
+        o = _attend_live(q, kv["cached_k"], kv["cached_v"], i, pos, scale,
+                         rungs)
     else:
-        o = _attend(q, kc, vc, pos, scale)
+        kc, vc = (jax.lax.dynamic_index_in_dim(kv[k], i, 0, keepdims=False)
+                  for k in ("cached_k", "cached_v"))
+        if t > _QUERY_TILE:
+            # a long chunk a tile of queries at a time: the float32 scores
+            # of 2048 queries over 4096 keys would be a gigabyte
+            o = jax.lax.map(lambda qp: _attend(qp[0], kc, vc, qp[1], scale),
+                            (_cut(q, _QUERY_TILE), _cut(pos, _QUERY_TILE)))
+            o = jnp.moveaxis(o, 0, 1).reshape((b, -1) + q.shape[2:])[:, :t]
+        else:
+            o = _attend(q, kc, vc, pos, scale)
     out = jnp.einsum("bthk,hkd->btd",
                      o.reshape(b, t, model.num_heads, d), p["wo"])
     return out, kv

@@ -101,14 +101,14 @@ def rope(x: jnp.ndarray, *, base: float = 10000.0,
 _MIN_TILE = 128
 
 
-def context_rungs(max_decode_len: int) -> tuple[int, ...]:
+def context_rungs(max_decode_len: int, tiles: int = 8) -> tuple[int, ...]:
     """The context lengths the per-row decode step can read, ascending:
-    whole tiles of an eighth of ``max_decode_len`` (of `_MIN_TILE` at
-    least), the last ``max_decode_len`` itself. A step reads the first
-    rung that holds every row it is handed
+    whole tiles of one ``tiles``-th (an eighth) of ``max_decode_len`` (of
+    `_MIN_TILE` at least), the last ``max_decode_len`` itself. A step
+    reads the first rung that holds every row it is handed
     (`MultiHeadAttention._decode_step`), a tile at a time; a cache of
     one rung is read whole, with no loop."""
-    tile = max(-(-max_decode_len // 8), _MIN_TILE)
+    tile = max(-(-max_decode_len // tiles), _MIN_TILE)
     return (*range(tile, max_decode_len, tile), max_decode_len)
 
 
@@ -555,6 +555,18 @@ class TransformerLM(nn.Module):
     scan_layers: bool = False
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
+
+    def decode_context_rungs(self, max_len: int,
+                             rows: int) -> tuple[int, ...] | None:
+        """What `DecodeServer` asks of any model: the context lengths its
+        per-row decode step can read of a cache of ``rows`` slots x
+        ``max_len`` tokens (the step reads the first that holds the
+        deepest cursor it is handed, so the pool hands a dead row 0 and
+        counts `decode_context_*`), or None where the step reads what it
+        reads whatever the cursors. Every block's attention here is
+        `MultiHeadAttention._decode_step`: always its ladder, whatever
+        the rows."""
+        return context_rungs(max_len)
 
     @nn.compact
     def __call__(self, tokens):

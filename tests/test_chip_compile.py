@@ -322,8 +322,26 @@ def test_granite_dispatch_updates_states_and_kv_in_place(one_chip):
     states = 2 * slots * 128 * 64 * 128 * 4
     assert mem.alias_size_in_bytes >= _nbytes(cache)
     assert mem.temp_size_in_bytes < states // 4
-    assert not re.findall(r"= f32\[2,48,128,64,128\]\S* copy\(",
-                          compiled.as_text())
+    text = compiled.as_text()
+    assert not re.findall(r"= f32\[2,48,128,64,128\]\S* copy\(", text)
+    # the attention layer reads its K/V a tile of the context ladder at a
+    # time (ISSUE 37): no [48, 4864, 8, 128] slice or copy of it. Sixteen
+    # tiles, not eight: a tile of 608 tokens is 60 MB of K and 60 of V,
+    # and only one of the two was staged in the chip's fast memory
+    # (`S(1)`); at 304 both are
+    assert srv._dec.decode_context_rungs(max_len, slots)[0] == 304
+    assert _kv_reads(text, slots, 8) == [304]
+    staged = re.findall(r"= bf16\[1,48,304,8,128\]\{[^}]*\} fusion\(", text)
+    assert len(staged) == 2 and all("S(1)" in s for s in staged)
+
+
+def _kv_reads(text: str, rows: int, kv_heads: int) -> list[int]:
+    """The token-axis lengths of every slice or copy of ``rows`` slots' K
+    or V (`[rows, n, kv_heads, 128]`, a leading 1 aside) in a compiled
+    program: what a layer stages of its cache to attend over it."""
+    return sorted({int(n) for n in re.findall(
+        rf"= bf16\[(?:1,)?{rows},(\d+),{kv_heads},128\]\S* "
+        r"(?:copy|dynamic-slice)\(", text)})
 
 
 def _falcon_pool(slots: int, max_len: int):
@@ -351,8 +369,9 @@ def test_falcon_h1_dispatch_updates_kv_and_states_in_place(one_chip):
     of BOTH mixers at the published widths, 32 slots x 4096 (a toy pool
     whose `_dec` is widened after the build): the new cache IS the donated
     one (K/V 1.6 GB and float32 states 0.8 GB, both the carry of the one
-    scan), the program's own memory holds no second copy of either, and no
-    whole stacked leaf is copied."""
+    scan), the program's own memory holds no second copy of either, no
+    whole stacked leaf is copied, and a layer's K/V is read a tile of the
+    context ladder at a time (ISSUE 37)."""
     slots, max_len = 32, 4096
     cfg, fam, srv = _falcon_pool(slots, max_len)
     srv._dec = dataclasses.replace(
@@ -367,15 +386,18 @@ def test_falcon_h1_dispatch_updates_kv_and_states_in_place(one_chip):
     states = 6 * slots * 32 * 128 * 256 * 4
     assert _nbytes(cache) > 2.4e9 and states > 0.8e9
     assert mem.alias_size_in_bytes >= _nbytes(cache)
-    # 0.79 GB of temporaries, none a cache: the in-projection's stack
+    # 0.72 GB of temporaries, none a cache: the in-projection's stack
     # [6, 5120, 9248] re-laid out once a dispatch (0.57 GB: 9248 is no
     # multiple of 128 and the chip holds the argument with 5120 minor) and
-    # one layer's K or V [32, 4096, 4, 128] with the heads before the tokens
-    # (0.13 GB: PERF.md section 7)
-    assert mem.temp_size_in_bytes < 1e9 < _nbytes(cache) // 2
+    # a layer's slice of it (0.09 GB). Until ISSUE 37 0.79 GB: one layer's K
+    # or V [32, 4096, 4, 128] was staged whole (0.13 GB), where now a tile
+    # [32, 512, 4, 128] of each is (0.017 GB; PERF.md section 7)
+    assert mem.temp_size_in_bytes < 0.75e9 < _nbytes(cache) // 2
     text = compiled.as_text()
     assert not re.findall(r"= f32\[6,32,32,128,256\]\S* copy\(", text)
     assert not re.findall(r"= bf16\[6,32,4096,4,128\]\S* copy\(", text)
+    assert srv._dec.decode_context_rungs(max_len, slots)[0] == 512
+    assert _kv_reads(text, slots, 4) == [512]
 
 
 @pytest.mark.parametrize("bucket, tokens", [(2048, 2048), (2048, 256)],

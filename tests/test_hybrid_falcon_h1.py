@@ -14,12 +14,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import ladder_cases
 from benchmark import manifest
 from idunno_tpu.engine.generate import decode_model, init_cache
 from idunno_tpu.engine.serve_lm import DecodeServer, _prefill
 from idunno_tpu.models import hybrid
 from idunno_tpu.models.hybrid import UnsupportedStack
-from idunno_tpu.models.transformer import decode_apply
+from idunno_tpu.models.transformer import context_rungs, decode_apply
 
 # tiny widths with the published multipliers; a scan chunk of 16 tokens, so
 # that 100 tokens cross several; two groups of two state-space heads
@@ -294,6 +295,80 @@ def test_one_shot_prefill_hands_back_the_last_real_row(fam, weights, built,
     cache["valid"] = jnp.int32(true_len)
     lg, _c = decode_apply(dec, params, cache, jnp.asarray(pad))
     assert lg.shape == (1, 1, 512)
+
+
+# -- the decode step reads the live context (ISSUE 37) -------------------------
+
+@pytest.mark.parametrize("case", ladder_cases.CASES,
+                         ids=lambda c: c.__name__)
+def test_the_pool_reads_the_live_context(built, case):
+    """`ladder_cases`' cases over three layers of both mixers with RoPE
+    and the key multiplier: the pool bounds the read by the live rows'
+    cursors and serves `generate`'s streams."""
+    case(built, ladder_cases.hybrid_pool)
+
+
+@pytest.mark.parametrize("top, t", [(512, 1), (576, 1), (576, 3)],
+                         ids=["whole-tiles", "last-rung-no-whole-tile",
+                              "chunk-of-3"])
+def test_the_ladder_is_attend_over_the_whole_axis(top, t):
+    """`_attend_live` over layer 1 of a carried stack against `_attend` over
+    that layer's slice: rows on every rung (576 = four tiles of 128 and a
+    last rung of 64, read as the tile [448, 576) less what the tile before
+    it covered) agree to float32 rounding. The tiles past the rung that
+    holds the deepest row are not read: NaNs there reach no output."""
+    rng = np.random.default_rng(top + t)
+    rungs = context_rungs(top)
+    assert rungs[0] == 128 and (top - rungs[-2]) in (64, 128)
+    b, kvh, g, d = 5, 2, 3, 16
+    kc, vc = (jnp.asarray(rng.standard_normal((2, b, top, kvh, d)),
+                          jnp.float32) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((b, t, kvh, g, d)), jnp.float32)
+    for depths in ([0, 127, 128, 300, top - t], [0, 5, 100, 130, 255]):
+        pos = jnp.asarray(depths)[:, None] + jnp.arange(t)[None, :]
+        want = hybrid._attend(q, kc[1], vc[1], pos, 0.25)
+        got = hybrid._attend_live(q, kc, vc, 1, pos, 0.25, rungs)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert np.abs(np.asarray(got - want)).max() < 1e-5
+    read = rungs[int(np.searchsorted(rungs, 255 + t))]
+    assert read < top
+    holed = [x.at[:, :, read:].set(jnp.nan) for x in (kc, vc)]
+    alone = hybrid._attend_live(q, *holed, 1, pos, 0.25, rungs)
+    assert np.array_equal(np.asarray(alone), np.asarray(got))
+
+
+def test_a_cache_of_one_rung_takes_the_whole_axis_path(built, monkeypatch):
+    """Per-row rows over a cache no longer than the least tile (every toy
+    pool): no loop; from two rungs on, the loop, and the same logits."""
+    model, params = built
+    calls = []
+    live = hybrid._attend_live
+    monkeypatch.setattr(hybrid, "_attend_live",
+                        lambda *a: calls.append(a[-1]) or live(*a))
+    logits = {}
+    for max_len in (128, 160):
+        dec = dataclasses.replace(model, decode=True, decode_per_row=True,
+                                  max_decode_len=max_len)
+        cache = dec.init_cache(2)
+        cache["cursors"] = jnp.asarray([3, 90], jnp.int32)
+        logits[max_len], _c = decode_apply(dec, params, cache,
+                                           jnp.asarray([[5], [9]]))
+    assert calls == [(128, 160)]          # one trace: the scan's one body
+    assert np.abs(np.asarray(logits[128] - logits[160])).max() < TOL
+    assert model.decode_context_rungs(512, 2) == (128, 256, 384, 512)
+
+
+def test_the_ladder_has_more_tiles_where_an_eighth_is_too_much_to_stage(built):
+    """A row's K (or V) token is 2 heads x 16 x 4 bytes here. Eight tiles
+    while every slot's tile of K is within `_STAGED_TILE_BYTES`, then
+    twice as many, and so on down to the least tile."""
+    model, _params = built
+    assert hybrid._STAGED_TILE_BYTES == 512 * 512 * 128
+    assert model.decode_context_rungs(4096, 512)[0] == 512
+    assert model.decode_context_rungs(4096, 513)[0] == 256
+    assert model.decode_context_rungs(4096, 1024)[:2] == (256, 512)
+    assert model.decode_context_rungs(4096, 1 << 20)[0] == 128
+    assert model.decode_context_rungs(4096, 1 << 20)[-1] == 4096
 
 
 # -- what the stack refuses, and how it is described --------------------------

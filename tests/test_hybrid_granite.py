@@ -11,6 +11,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import ladder_cases
 from benchmark import manifest
 from idunno_tpu.engine.generate import decode_model, init_cache
 from idunno_tpu.engine.serve_lm import DecodeServer, _prefill
@@ -286,6 +287,16 @@ def test_a_masked_token_is_routed_nowhere():
 
 
 # -- what the stack refuses, and what it is not asked -------------------------
+
+@pytest.mark.parametrize("case", ladder_cases.CASES,
+                         ids=lambda c: c.__name__)
+def test_the_pool_reads_the_live_context(built, case):
+    """`ladder_cases`' cases (ISSUE 37) over two plain attention layers
+    without positions among four state-space layers, routed experts in
+    every one: the pool bounds the attention's read by the live rows'
+    cursors and serves `generate`'s streams."""
+    case(built, ladder_cases.hybrid_pool)
+
 
 @pytest.mark.parametrize("kw, what", [
     (dict(n_model=2), "n_model"),

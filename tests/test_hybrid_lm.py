@@ -255,3 +255,19 @@ def test_the_dense_pool_counts_nothing_new():
     assert not {"recurrent_state_bytes", "sparse_tokens_attended",
                 "prefix_skipped_recurrent"} & set(st)
     assert st["prefix_cache"]["lookups"] == 1
+
+
+def test_a_block_sparse_stack_is_handed_the_cursors_as_they_are(built):
+    """No layer of this stack takes the context ladder (ISSUE 37: a
+    block-sparse layer reads its own selection, a linear one no token
+    axis): the model answers the pool's question with None, the pool
+    counts no `decode_context_*` and hands the dispatch every cursor as it
+    is, a dead row's too."""
+    model, _params = built
+    assert model.decode_context_rungs(512, 2) is None
+    srv = _server(built)
+    assert srv._ladder is None
+    srv.submit(_tokens(20, 41), max_new=6)
+    srv.run_until_drained()
+    assert not {"decode_context_read", "decode_context_held"} & set(
+        srv.stats())

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import ladder_cases
 from idunno_tpu.engine.generate import generate
 from idunno_tpu.engine.serve_lm import DecodeServer
 from idunno_tpu.models.transformer import TransformerLM
@@ -1192,78 +1193,17 @@ def test_rows_on_every_rung_serve_generates_streams(lm, kind):
     assert {c.id: c.tokens for c in done} == want
 
 
-def test_slot_reused_after_a_deep_request_serves_a_fresh_pools_stream(lm):
-    """A dead slot is handed cursor 0, so the step writes its discarded
-    K/V at position 0 of that slot and the slot's old rows stay as the
-    deep request left them. The next request admitted there must draw
-    what a fresh pool draws, while the other slot decodes on."""
-    model, params, srv = _ladder_pool(lm, "native", slots=2)
-    rng = np.random.default_rng(9)
-    deep = [int(t) for t in rng.integers(0, VOCAB, size=310)]
-    other = [int(t) for t in rng.integers(0, VOCAB, size=5)]
-    late = [int(t) for t in rng.integers(0, VOCAB, size=6)]
-    rid_deep = srv.submit(deep, max_new=10)
-    rid_other = srv.submit(other, max_new=60)
-    done = {}
-    while rid_deep not in done:                   # the deep row retires...
-        srv.step()
-        done.update((c.id, c) for c in srv.poll())
-    for _ in range(3):                            # ...its slot idles, dead,
-        srv.step()                                # beside a live row
-    rid_late = srv.submit(late, max_new=20)       # and is taken again
-    done.update((c.id, c) for c in srv.run_until_drained())
-    for rid, (p, m) in {rid_deep: (deep, 10), rid_other: (other, 60),
-                        rid_late: (late, 20)}.items():
-        assert done[rid].tokens == expected(model, params, p, m)
-    fresh = _ladder_pool(lm, "native", slots=2)[2]
-    fresh.submit(late, max_new=20)
-    assert fresh.run_until_drained()[0].tokens == done[rid_late].tokens
-
-
-def test_a_row_that_ends_mid_dispatch_stops_holding_the_bound(lm):
-    """Four steps a dispatch; a row at depth 300 with two tokens left and a
-    row at depth 5: the first two steps read the third rung (384), the
-    last two the first (128): the dead row's stale cursor no longer
-    counts. Then the shallow row alone: every step the first rung."""
-    model, params, srv = _ladder_pool(lm, "native", slots=2)
-    rng = np.random.default_rng(3)
-    deep = [int(t) for t in rng.integers(0, VOCAB, size=300)]
-    srv.submit(deep, max_new=3)        # one token at admission, two left
-    srv.submit([7, 8, 9, 10, 11], max_new=12)
-    srv.step()                         # an empty pool: admissions alone
-    assert srv.stats()["dispatches"] == 0
-    srv.step()
-    s = srv.stats()
-    assert s["dispatches"] == 1
-    assert s["decode_context_held"] == 4 * 512 * 2
-    assert s["decode_context_read"] == (2 * 384 + 2 * 128) * 2
-    srv.step()
-    s = srv.stats()
-    assert s["decode_context_read"] == (2 * 384 + 6 * 128) * 2
-    done = srv.run_until_drained()
-    assert sorted(len(c.tokens) for c in done) == [17, 303]
-
-
-def test_context_counters_read_one_for_a_row_at_the_end_of_the_cache(lm):
-    """A pool of short rows reads a share well under 1; one row at
-    ``max_len - decode_steps`` puts every step on the last rung: 1.0. A
-    stack that brings its own step has no such counters."""
-    model, params, srv = _ladder_pool(lm, "native")
-    for n in (3, 5, 8):
-        srv.submit(list(range(1, n + 1)), max_new=9)
-    srv.run_until_drained()
-    s = srv.stats()
-    assert s["decode_context_read"] / s["decode_context_held"] == 0.25
-
-    model, params, srv = _ladder_pool(lm, "native", prompt_len=507,
-                                      prompt_buckets=(8, 507))
-    rng = np.random.default_rng(1)
-    srv.submit([int(t) for t in rng.integers(0, VOCAB, size=507)],
-               max_new=5)                        # cursor 507 = 512 - 4 - 1
-    srv.run_until_drained()
-    s = srv.stats()
-    assert s["dispatches"] == 1
-    assert s["decode_context_read"] == s["decode_context_held"] > 0
+@pytest.mark.parametrize("case", ladder_cases.CASES[1:],
+                         ids=lambda c: c.__name__)
+def test_the_bound_follows_the_live_rows(lm, case):
+    """`ladder_cases`' pool cases over the native pool (the first is
+    `test_rows_on_every_rung_serve_generates_streams`, which also runs
+    the int8 and radix pools): a row that ends mid-dispatch stops holding
+    the bound, a slot a deep request left serves a fresh pool's stream, a
+    row at the end of the cache reads all of it. A stack whose model
+    answers `decode_context_rungs` with None has no such counters
+    (`tests/test_hybrid_lm.py`)."""
+    case(lm, lambda built, **kw: _ladder_pool(built, "native", **kw)[2])
 
 
 # -- the decode dispatch goes first (ISSUE 35) ---------------------------------
