@@ -1589,10 +1589,14 @@ class DecodeServer:
     def _remaining_cursors(self) -> tuple[np.ndarray, np.ndarray]:
         """Host view of (remaining, cursors) — one stacked D2H transfer,
         cached until `_rc_invalidate` (every device-side mutation site:
-        dispatch, admission, cancel, stop-truncation)."""
+        dispatch, admission, cancel, stop-truncation). A fetch inside a
+        traced step is an `lm.readback` span: the wait for the chip and
+        the copy."""
         if self._rc_cache is None:
-            self._rc_cache = np.asarray(
-                jnp.stack([self._remaining, self._cursors]))
+            with (self._span("lm.readback") if self._step_span is not None
+                  else NO_SPAN):
+                self._rc_cache = np.asarray(
+                    jnp.stack([self._remaining, self._cursors]))
         return self._rc_cache[0], self._rc_cache[1]
 
     def _rc_invalidate(self) -> None:
@@ -1602,34 +1606,38 @@ class DecodeServer:
         if not self._live:
             return
         remaining, cursors = self._remaining_cursors()
-        for slot in [s for s, r in enumerate(remaining)
-                     if r == 0 and s in self._live]:
-            req = self._live.pop(slot)
-            total = int(cursors[slot]) + 1
-            row = np.asarray(self._tokens[slot])[:total]
-            was_cancelled = req.id in self._cancelled
-            self._cancelled.discard(req.id)
-            lps = None
-            if self.track_logprobs:
-                lp_row = np.asarray(self._logprobs[slot])[:total]
-                lps = [float(x) for x in lp_row[len(req.tokens):]]
-            done = Completion(
-                id=req.id, tokens=[int(t) for t in row],
-                prompt_len=len(req.tokens),
-                service_s=self.clock() - req.t_admit,
-                cancelled=was_cancelled, logprobs=lps,
-                cold_start=req.cold, t_submit=req.t_submit,
-                t_admit=req.t_admit, t_first=req.t_first,
-                n_first=req.n_first, t_last=req.t_last)
-            self._done.append(done)
-            self._retired.append((done, req))   # `_stamp` ends the step
-            if not was_cancelled:
-                self._stats["completed"] += 1
-            self._stats["tokens_generated"] += total - len(req.tokens)
-            if self._radix is not None:       # unpin the request's chain
-                chain = self._held.pop(req.id, None)
-                if chain:
-                    self._radix.release(chain)
+        finished = [s for s, r in enumerate(remaining)
+                    if r == 0 and s in self._live]
+        if not finished:
+            return
+        with self._span("lm.retire", rows=len(finished)):
+            for slot in finished:
+                req = self._live.pop(slot)
+                total = int(cursors[slot]) + 1
+                row = np.asarray(self._tokens[slot])[:total]
+                was_cancelled = req.id in self._cancelled
+                self._cancelled.discard(req.id)
+                lps = None
+                if self.track_logprobs:
+                    lp_row = np.asarray(self._logprobs[slot])[:total]
+                    lps = [float(x) for x in lp_row[len(req.tokens):]]
+                done = Completion(
+                    id=req.id, tokens=[int(t) for t in row],
+                    prompt_len=len(req.tokens),
+                    service_s=self.clock() - req.t_admit,
+                    cancelled=was_cancelled, logprobs=lps,
+                    cold_start=req.cold, t_submit=req.t_submit,
+                    t_admit=req.t_admit, t_first=req.t_first,
+                    n_first=req.n_first, t_last=req.t_last)
+                self._done.append(done)
+                self._retired.append((done, req))  # `_stamp` ends the step
+                if not was_cancelled:
+                    self._stats["completed"] += 1
+                self._stats["tokens_generated"] += total - len(req.tokens)
+                if self._radix is not None:   # unpin the request's chain
+                    chain = self._held.pop(req.id, None)
+                    if chain:
+                        self._radix.release(chain)
 
     def _child_span(self, parent, name: str, t_start, **attrs) -> None:
         """A child of a traced admission's `lm.prefill`, from ``t_start``
@@ -2076,14 +2084,18 @@ class DecodeServer:
         and nothing is in flight then) and at its end ("dispatch": the
         step's one wait for the chip, covering the dispatch and every
         admission program queued behind it, or an empty pool's admission
-        alone). Each wait is an `lm.step.sync` span."""
+        alone). Each wait is an `lm.step.sync` span; the counters are an
+        `lm.count` inside it (the wait an `lm.readback` in that, where a
+        counter fetches first)."""
         blocks = self._rc_cache is None and bool(self._live)
         with self._span("lm.step.sync", after=after) if blocks else NO_SPAN:
             if self._in_flight is not None:
-                if self._sparse:
-                    self._count_attended()
-                if self._ladder is not None:
-                    self._count_context(self._in_flight)
+                if self._sparse or self._ladder is not None:
+                    with self._span("lm.count"):
+                        if self._sparse:
+                            self._count_attended()
+                        if self._ladder is not None:
+                            self._count_context(self._in_flight)
                 self._apply_stops()
             self._retire_finished()
 
@@ -2150,11 +2162,12 @@ class DecodeServer:
         # costs it nothing, and its programs (prefill, block writes, the
         # slot splice and per-slot sets, which take the dispatch's outputs
         # as they are handed on) run behind it in the order enqueued
-        if self._pending is not None:
-            # one chunk of the pending long admission a step: resident rows
-            # advance between chunks
-            self._advance_prefill()
-        self._admit()
+        with self._span("lm.step.admit"):
+            if self._pending is not None:
+                # one chunk of the pending long admission a step: resident
+                # rows advance between chunks
+                self._advance_prefill()
+            self._admit()
         # the step's one wait; max_new == 1 admissions retire here
         self._retire_synced("dispatch")
         if st is not None:
@@ -2173,33 +2186,37 @@ class DecodeServer:
         its prefill's one token) and the last of each request the step
         retired; of those, the first ``early`` retired before the dispatch
         (cancelled) and keep the last stamp they had. No device read: the
-        cursors are the copy the last `_retire_finished` fetched."""
-        now = self.clock()
-        if self._live:
-            remaining = self._remaining_cursors()[0]
-            for slot, req in self._live.items():
-                req.t_last = now
-                if req.t_first is None:
-                    req.t_first = now
-                    req.n_first = req.max_new - int(remaining[slot])
-        for i, (done, req) in enumerate(self._retired):
-            generated = len(done.tokens) - done.prompt_len
-            if req.t_first is None:       # admitted and retired in one step
-                req.t_first = req.t_last = now
-                req.n_first = generated
-            elif i >= early:
-                req.t_last = now
-            done.t_first, done.n_first = req.t_first, req.n_first
-            done.t_last = req.t_last
-            if req.t_decode0 is not None:     # traced, and it was dispatched
-                self.spans.record(
-                    "lm.decode", trace=req.trace[0], parent=req.trace[1],
-                    t_start=req.t_decode0, t_end=req.t_last,
-                    attrs={"id": req.id, "tokens": generated,
-                           "steps": self._stats["dispatches"]
-                           - req.dispatch0,
-                           "t_first": req.t_first, "n_first": req.n_first})
-        self._retired.clear()
+        cursors are the copy the last `_retire_finished` fetched. An
+        `lm.stamp` span."""
+        with self._span("lm.stamp",
+                        rows=len(self._live) + len(self._retired)):
+            now = self.clock()
+            if self._live:
+                remaining = self._remaining_cursors()[0]
+                for slot, req in self._live.items():
+                    req.t_last = now
+                    if req.t_first is None:
+                        req.t_first = now
+                        req.n_first = req.max_new - int(remaining[slot])
+            for i, (done, req) in enumerate(self._retired):
+                generated = len(done.tokens) - done.prompt_len
+                if req.t_first is None:   # admitted and retired in one step
+                    req.t_first = req.t_last = now
+                    req.n_first = generated
+                elif i >= early:
+                    req.t_last = now
+                done.t_first, done.n_first = req.t_first, req.n_first
+                done.t_last = req.t_last
+                if req.t_decode0 is not None:   # traced, and dispatched
+                    self.spans.record(
+                        "lm.decode", trace=req.trace[0], parent=req.trace[1],
+                        t_start=req.t_decode0, t_end=req.t_last,
+                        attrs={"id": req.id, "tokens": generated,
+                               "steps": self._stats["dispatches"]
+                               - req.dispatch0,
+                               "t_first": req.t_first,
+                               "n_first": req.n_first})
+            self._retired.clear()
 
     def run_until_drained(self, max_steps: int = 10_000) -> list[Completion]:
         """Drive `step` until queue and slots are empty; returns every

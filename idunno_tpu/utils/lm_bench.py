@@ -269,7 +269,8 @@ def run_lm_bench(platform: str, device_kind: str, n_devices: int,
         dispatches, seconds/dispatch, compile seconds). With
         ``trace_name`` and BENCH_TRACE=1, one extra post-timing dispatch
         runs under the profiler into ``.trace/<trace_name>`` (the decode
-        trace→apportion→fix loop; parse with tools/parse_trace.py)."""
+        trace→apportion→fix loop; `benchmark/trace.py` reduces such a
+        trace)."""
         srv = DecodeServer(m, p, slots=slots or cfg["slots"],
                            prompt_len=cfg["prompt_len"],
                            max_len=cfg["max_len"],

@@ -1,120 +1,23 @@
-"""Tracing and device-side step timing.
+"""Profiler traces.
 
 The reference has no tracer — its only timing is coarse host wall-clock
 around the whole per-task loop (`alexnet_resnet.py:16,91-92`) and around
 dispatch (`mp4_machinelearning.py:792-804`). On TPU, host wall-clock lies
 twice over: dispatch is async (the Python call returns before the chip
-runs) and the first call includes compilation. This module provides the
-honest primitives the serving metrics (`idunno_tpu.serve.metrics`) and
-benchmarks build on:
+runs) and the first call includes compilation. What remains here:
 
-- ``device_timed``: wrap a jitted callable so each call blocks until the
-  device result is ready and reports true execution seconds, separately
-  flagging warm-up (compile) calls.
-- ``StepTimer``: accumulate step durations and expose the reference's
-  stats tuple (avg/P25/P50/P75/stddev — the honest version of the c2
-  command, `mp4_machinelearning.py:1232-1254`, without the fudging).
 - ``trace``: context manager around ``jax.profiler`` emitting a TensorBoard
   trace directory for the wrapped region (XLA per-op device timeline).
-- ``annotate``: named region inside a trace (shows up on the timeline).
+
+The benchmark reads its traces itself (`benchmark/trace.py`); the program's
+named regions are its spans (`utils/spans.py`, `engine/serve_lm.py:
+loop_span`), each also a `jax.profiler.TraceAnnotation`.
 """
 from __future__ import annotations
 
 import contextlib
-import statistics
-import time
-from dataclasses import dataclass, field
-from typing import Any, Callable
 
 import jax
-
-
-@dataclass
-class TimedCall:
-    seconds: float
-    compiled: bool       # False on the first (trace+compile) call
-
-
-def device_timed(fn: Callable[..., Any]) -> Callable[..., tuple[Any, TimedCall]]:
-    """Wrap ``fn`` (typically jitted) → ``(out, TimedCall)`` per call.
-
-    Blocks on the result tree, so ``seconds`` covers actual device
-    execution, not async dispatch.
-
-    Compile detection: when ``fn`` is a jitted function exposing
-    ``_cache_size`` the flag is exact — a call that grew the jit cache was a
-    trace+compile call, which also survives cache clears and static-kwarg
-    rehashing. Otherwise it falls back to a first-time-seen-shapes
-    HEURISTIC: wrapping the same fn twice, clearing jax caches, or anything
-    else that recompiles without changing arg shapes will mislabel a compile
-    call as warm.
-    """
-    cache_size = getattr(fn, "_cache_size", None)
-    seen_shapes: set[tuple] = set()
-
-    def wrapped(*args, **kwargs):
-        if callable(cache_size):
-            before = cache_size()
-        else:
-            key = tuple(
-                (getattr(a, "shape", None), str(getattr(a, "dtype", None)))
-                for a in jax.tree.leaves((args, kwargs)))
-            first = key not in seen_shapes
-            seen_shapes.add(key)
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        jax.block_until_ready(out)
-        seconds = time.perf_counter() - t0
-        if callable(cache_size):
-            first = cache_size() > before
-        return out, TimedCall(seconds, compiled=not first)
-
-    return wrapped
-
-
-@dataclass
-class StepTimer:
-    """Step-duration accumulator with the reference's stats shape."""
-
-    durations_s: list[float] = field(default_factory=list)
-
-    def record(self, seconds: float) -> None:
-        self.durations_s.append(seconds)
-
-    @contextlib.contextmanager
-    def measure(self, result_to_block: Any | None = None):
-        t0 = time.perf_counter()
-        out: dict[str, Any] = {}
-        yield out
-        if "result" in out:
-            jax.block_until_ready(out["result"])
-        elif result_to_block is not None:
-            jax.block_until_ready(result_to_block)
-        self.record(time.perf_counter() - t0)
-
-    def stats(self) -> dict[str, float] | None:
-        """avg / quartiles / stddev over recorded steps (None if empty)."""
-        d = sorted(self.durations_s)
-        if not d:
-            return None
-        n = len(d)
-
-        def pct(p: float) -> float:
-            if n == 1:
-                return d[0]
-            pos = p * (n - 1)
-            lo = int(pos)
-            hi = min(lo + 1, n - 1)
-            return d[lo] + (d[hi] - d[lo]) * (pos - lo)
-
-        return {
-            "count": float(n),
-            "average": sum(d) / n,
-            "p25": pct(0.25),
-            "p50": pct(0.50),
-            "p75": pct(0.75),
-            "stddev": statistics.pstdev(d) if n > 1 else 0.0,
-        }
 
 
 @contextlib.contextmanager
@@ -127,7 +30,3 @@ def trace(log_dir: str):
     finally:
         jax.profiler.stop_trace()
 
-
-def annotate(name: str):
-    """Named sub-region for the profiler timeline."""
-    return jax.profiler.TraceAnnotation(name)

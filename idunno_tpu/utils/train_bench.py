@@ -82,8 +82,8 @@ def _timed_steps(step_fn, state, args: tuple, iters: int,
     synced by a D2H read of the loss). Returns (median_s, compile_s, last_loss).
     With ``trace_name`` and BENCH_TRACE=1 one extra post-timing step runs
     under the profiler into ``.trace/<trace_name>`` (the apportionment
-    evidence behind the train-MFU analysis; parse with
-    tools/parse_trace.py)."""
+    evidence behind the train-MFU analysis; `benchmark/trace.py` reduces
+    such a trace)."""
     t0 = time.perf_counter()
     state, metrics = step_fn(state, *args)
     loss = float(np.asarray(metrics["loss"]))

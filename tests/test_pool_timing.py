@@ -289,7 +289,10 @@ def test_loop_iterations_tile_and_children_lie_inside_parents(lm):
                 (s["name"], p["name"])
     parents = {"loop.drain": "loop.iter", "lm.step": "loop.iter",
                "loop.publish": "loop.iter", "loop.idle_wait": "loop.iter",
-               "lm.decode_step": "lm.step", "lm.step.sync": "lm.step"}
+               "lm.decode_step": "lm.step", "lm.step.sync": "lm.step",
+               "lm.readback": "lm.step", "lm.count": "lm.step",
+               "lm.retire": "lm.step", "lm.stamp": "lm.step",
+               "lm.step.admit": "lm.step"}
     assert {s["name"] for s in spans} == set(parents) | {"loop.iter"}
     for s in spans:
         if s["name"] != "loop.iter":

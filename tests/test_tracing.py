@@ -1,50 +1,16 @@
-"""Device timing + profiler trace utilities."""
+"""Profiler trace utilities."""
 import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from idunno_tpu.utils.tracing import (
-    StepTimer, annotate, device_timed, trace)
-
-
-def test_device_timed_flags_compile_call():
-    fn = device_timed(jax.jit(lambda x: (x @ x).sum()))
-    x = jnp.ones((64, 64))
-    out1, t1 = fn(x)
-    out2, t2 = fn(x)
-    assert not t1.compiled and t2.compiled
-    assert float(out1) == float(out2)
-    assert t1.seconds > 0 and t2.seconds > 0
-    # new shape -> new compile flag
-    _, t3 = fn(jnp.ones((32, 32)))
-    assert not t3.compiled
-
-
-def test_step_timer_stats():
-    st = StepTimer()
-    for v in [1.0, 2.0, 3.0, 4.0]:
-        st.record(v)
-    s = st.stats()
-    assert s["count"] == 4 and s["average"] == 2.5
-    assert s["p25"] == 1.75 and s["p50"] == 2.5 and s["p75"] == 3.25
-    np.testing.assert_allclose(s["stddev"], np.std([1, 2, 3, 4]))
-    assert StepTimer().stats() is None
-
-
-def test_step_timer_measure_blocks_on_result():
-    st = StepTimer()
-    f = jax.jit(lambda x: x * 2)
-    with st.measure() as out:
-        out["result"] = f(jnp.ones((8,)))
-    assert len(st.durations_s) == 1 and st.durations_s[0] > 0
+from idunno_tpu.utils.tracing import trace
 
 
 def test_trace_writes_profile(tmp_path):
     log_dir = str(tmp_path / "prof")
     with trace(log_dir):
-        with annotate("matmul-region"):
+        with jax.profiler.TraceAnnotation("matmul-region"):
             x = jnp.ones((128, 128))
             jax.block_until_ready(jax.jit(lambda a: a @ a)(x))
     found = []
@@ -92,25 +58,6 @@ def test_profile_control_verb(tmp_path):
     finally:
         stop.set()
         t.join(timeout=5)
-
-
-def test_device_timed_exact_compile_detection_survives_rewrap():
-    """ADVICE round-1 #4: with a jitted fn, compile detection keys on the
-    jit cache, so a second wrapper over the same (already warm) fn must not
-    mislabel its first call as a compile."""
-    import jax
-    import jax.numpy as jnp
-    from idunno_tpu.utils.tracing import device_timed
-
-    f = jax.jit(lambda x: x * 2)
-    w1 = device_timed(f)
-    _, t1 = w1(jnp.ones(4))      # trace+compile
-    _, t2 = w1(jnp.ones(4))      # warm
-    _, t3 = w1(jnp.ones(8))      # new shape -> compile
-    w2 = device_timed(f)         # rewrap same fn
-    _, t4 = w2(jnp.ones(4))      # cache already warm -> NOT a compile
-    assert (t1.compiled, t2.compiled, t3.compiled, t4.compiled) == (
-        False, True, False, True)
 
 
 # == distributed request tracing (utils/spans.py, ISSUE 6) ================
