@@ -666,7 +666,12 @@ class Shell:
                     + (" context_read=%.2f" % (
                         stats["decode_context_read"]
                         / stats["decode_context_held"])
-                       if stats.get("decode_context_held") else ""))
+                       if stats.get("decode_context_held") else "")
+                    # the share of the slots' scan states the decode steps
+                    # moved (the live rows': 1.00 = every slot every step)
+                    + (" state_moved=%.2f" % (
+                        stats["state_rows_moved"] / stats["state_rows_held"])
+                       if stats.get("state_rows_held") else ""))
 
         def prefix_line(stats: dict) -> str:
             pc = stats.get("prefix_cache")

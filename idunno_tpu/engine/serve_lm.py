@@ -465,14 +465,26 @@ def _set_rows(arrays: tuple, at: tuple, values: tuple) -> tuple:
     return tuple(a.at[i].set(v) for a, i, v in zip(arrays, at, values))
 
 
-@jax.jit
-def _expert_counters(cache: Any) -> tuple[list, list]:
-    """Copies of an expert stack's counters, a run of layers each: what
-    `DecodeServer.stats()` reads while the cache they came from is already
-    donated to the next dispatch."""
+# the slot cache's counters (`models/hybrid.py`): leaves without a slot axis
+# that a dispatch adds to
+_COUNTER_KEYS = ("expert_load", "expert_steps", "state_rows")
+
+
+def _counter_keys(cache: Any) -> tuple[str, ...]:
+    """The counters a slot cache holds: an expert stack's, a state-space
+    stack's, or none."""
     runs = [v for k, v in cache.items() if k.startswith("run")]
-    return ([jnp.copy(r["expert_load"]) for r in runs],
-            [jnp.copy(r["expert_steps"]) for r in runs])
+    return tuple(k for k in _COUNTER_KEYS if any(k in r for r in runs))
+
+
+@jax.jit
+def _counters(cache: Any) -> dict:
+    """Copies of the slot cache's counters, a list over the runs of layers
+    that hold each: what `DecodeServer.stats()` reads while the cache they
+    came from is already donated to the next dispatch."""
+    runs = [v for k, v in cache.items() if k.startswith("run")]
+    return {key: [jnp.copy(r[key]) for r in runs if key in r]
+            for key in _counter_keys(cache)}
 
 
 class DecodeServer:
@@ -660,6 +672,11 @@ class DecodeServer:
         self.eos_id = eos_id
 
         self._dec = self._per_row_decode(model, max_len)
+        if self._recurrent:
+            # a hybrid stack's decode step runs `ops/ssd_decode.py`: under
+            # the interpreter off the chip, as the paged kernel above
+            self._dec = dataclasses.replace(
+                self._dec, interpret=self._paged_interpret)
         self._prefill_model = model
 
         # mesh sharding: the pool's slot dimension spreads over the mesh's
@@ -855,11 +872,14 @@ class DecodeServer:
         if self._sparse:
             self._stats.update(sparse_tokens_attended=0,
                                sparse_tokens_in_context=0)
-        # an expert stack's counters (`expert_load`, `expert_steps`: the
-        # slot cache's leaves without a slot axis) as the last dispatch left
-        # them: a copy on the device, because the cache itself is donated to
-        # the next dispatch while another thread may be asking `stats()`
-        self._expert_counts = None
+        # the slot cache's counters (an expert stack's `expert_load`,
+        # `expert_steps`; a state-space stack's `state_rows`: leaves without
+        # a slot axis) as the last dispatch left them: a copy on the device,
+        # because the cache itself is donated to the next dispatch while
+        # another thread may be asking `stats()`
+        self._counted = self._recurrent and bool(_counter_keys(self._cache))
+        self._counts_copy = (_counters(self._cache) if self._counted
+                             else None)
         # the context lengths the decode step can read, as the model says
         # (`decode_context_rungs`: `MultiHeadAttention._decode_step`, a
         # hybrid stack's `_attend_live`), None for a model whose step reads
@@ -966,8 +986,9 @@ class DecodeServer:
                     cache, jnp.where(remaining > 0, cursors, 0)
                     if bound_by_live else cursors)
                 # an expert stack routes a dead row nowhere and counts
-                # the live ones (`models/hybrid.py`); no other cache has
-                # the leaf
+                # the live ones, a state-space layer moves the live rows'
+                # states alone (`models/hybrid.py`); no other cache has the
+                # leaf
                 cache = _set_leaf(cache, "live", remaining > 0)
                 tok = jnp.take_along_axis(tokens, cursors[:, None], axis=1)
                 # decode_apply: the scanned step (one lax.scan over the
@@ -1213,31 +1234,40 @@ class DecodeServer:
                    kv_cache_bytes=self._kv_cache_bytes, config=config)
         if self._recurrent:
             out["recurrent_state_bytes"] = self.model.state_bytes(self.slots)
-        if self._expert_counts is not None:
-            out.update(self._expert_stats())
+        if self._counts_copy is not None:
+            out.update(self._counter_stats())
         if self._radix is not None:
             out["prefix_cache"] = self.prefix_cache_stats()
         return out
 
-    def _expert_stats(self) -> dict:
-        """An expert stack's counters over the decode steps so far, read
-        from the device now: the token-picks that fell on the experts held
-        here and those offered (live tokens x experts a token, a layer);
-        the picks on the busiest held expert of each layer, summed, and the
-        mean over a layer's held experts, summed; the held experts that
-        took a pick, summed over steps and layers, and how many there were
-        to take one."""
-        load, steps = (np.concatenate([np.asarray(x, np.int64) for x in c])
-                       for c in self._expert_counts)     # [layers, ...]
-        held = load.shape[1]
-        return {
-            "expert_tokens_routed": int(load.sum()),
-            "expert_tokens_offered": int(
-                steps[:, 2].sum() * self.model.experts_per_token),
-            "expert_load_max": int(load.max(axis=1).sum()),
-            "expert_load_mean": float(load.mean(axis=1).sum()),
-            "experts_touched": int(steps[:, 0].sum()),
-            "experts_touchable": int(steps[:, 1].sum() * held)}
+    def _counter_stats(self) -> dict:
+        """The slot cache's counters over the decode steps so far, read
+        from the device now. An expert stack's: the token-picks that fell
+        on the experts held here and those offered (live tokens x experts a
+        token, a layer); the picks on the busiest held expert of each
+        layer, summed, and the mean over a layer's held experts, summed;
+        the held experts that took a pick, summed over steps and layers,
+        and how many there were to take one. A state-space stack's: the
+        rows whose scan state a step moved and the rows the slots hold, a
+        Mamba-2 layer a step."""
+        c = {k: np.concatenate([np.asarray(x, np.int64) for x in v])
+             for k, v in self._counts_copy.items()}      # [layers, ...]
+        out = {}
+        if "expert_load" in c:
+            load, steps = c["expert_load"], c["expert_steps"]
+            out.update(
+                expert_tokens_routed=int(load.sum()),
+                expert_tokens_offered=int(
+                    steps[:, 2].sum() * self.model.experts_per_token),
+                expert_load_max=int(load.max(axis=1).sum()),
+                expert_load_mean=float(load.mean(axis=1).sum()),
+                experts_touched=int(steps[:, 0].sum()),
+                experts_touchable=int(steps[:, 1].sum() * load.shape[1]))
+        if "state_rows" in c:
+            moved, held = c["state_rows"].sum(axis=0)
+            out.update(state_rows_moved=int(moved),
+                       state_rows_held=int(held))
+        return out
 
     def prefix_cache_stats(self) -> dict:
         """Radix prefix-cache gauges (only meaningful on kv_block_size
@@ -2155,8 +2185,8 @@ class DecodeServer:
                     self._counts, *pg)
             self._stats["dispatches"] += 1
             self._dispatched_ever = True
-            if "live" in self._cache:     # an expert stack: see __init__
-                self._expert_counts = _expert_counters(self._cache)
+            if self._counted:             # see __init__
+                self._counts_copy = _counters(self._cache)
             self._rc_invalidate()         # the dispatch advances the rows
         # the chip is busy with the dispatch: the admission's host work
         # costs it nothing, and its programs (prefill, block writes, the

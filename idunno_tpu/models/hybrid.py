@@ -73,12 +73,15 @@ leading, the slot axis second): `cached_k`/`cached_v` [L, B, T, kvh, d],
 state, the window nor the pooled keys. A stack with `last_logits` computes
 a prefill's logits at the last real position alone ([B, 1, vocab]: a
 2048-token chunk's float32 logits over 261120 rows would be 2.1 GB). A
-per-row (decode) cache of a ``moe`` stack also carries `live` [B] (the rows
-that hold a request: the dispatch sets it, a dead row is routed nowhere)
-and, a run, the counters
+per-row (decode) cache of a ``moe`` stack or of one with a ``mamba2`` layer
+also carries `live` [B] (the rows that hold a request: the dispatch sets it;
+a dead row is routed nowhere, and its scan state is neither read nor
+written: `ops/ssd_decode.py`) and the counters: a run of a ``moe`` stack
 `expert_load` [L, held] and `expert_steps` [L, 3] (int32: picks on each
 held expert; held experts touched, steps and tokens, summed over the steps
-that had a live row). They have no slot axis and are read when
+that had a live row), a run with a ``mamba2`` layer `state_rows` [L, 2]
+(int32: the rows whose scan state a step moved, and the rows the slots
+hold, summed over the steps). They have no slot axis and are read when
 `DecodeServer.stats()` is.
 """
 from __future__ import annotations
@@ -92,6 +95,7 @@ import numpy as np
 
 from idunno_tpu.models.moe import routed_experts
 from idunno_tpu.models.transformer import context_rungs, rope
+from idunno_tpu.ops.ssd_decode import ssd_decode
 
 SPARSE, LINEAR = "minicpm4", "lightning-attn"
 MAMBA, ATTENTION = "mamba2", "attention"
@@ -187,6 +191,9 @@ class HybridLM:
     decode: bool = False
     decode_per_row: bool = False
     max_decode_len: int = 0
+    # the Pallas kernels of `ops/` under the interpreter (off the chip:
+    # `DecodeServer` sets it as it does for the paged kernel)
+    interpret: bool = False
     # what `DecodeServer` asks of any model
     causal: bool = True
     scan_layers: bool = True
@@ -315,7 +322,7 @@ class HybridLM:
         cache: dict = {}
         if self.decode_per_row:
             cache["cursors"] = jnp.zeros((batch,), jnp.int32)
-            if counted:
+            if counted or self.has(MAMBA):
                 cache["live"] = jnp.ones((batch,), jnp.bool_)
         else:
             cache["cursor"] = jnp.zeros((), jnp.int32)
@@ -337,6 +344,8 @@ class HybridLM:
                            conv=jnp.zeros(
                     (n, batch, self.ssm_conv - 1, self.ssm_conv_width),
                     self.dtype))
+                if self.decode_per_row:
+                    run["state_rows"] = jnp.zeros((n, 2), jnp.int32)
             if kind == LINEAR:
                 d = self.lightning_head_dim
                 run = {"state": jnp.zeros(
@@ -649,14 +658,31 @@ def _gated_norm(model: HybridLM, y, scale):
                 model.dtype).reshape(y.shape)
 
 
-def _mamba_mix(model: HybridLM, p, c, held, i, hn, mask):
+def _ssd_live(states, i, live, xs, dt, a, bm, cm, interpret: bool):
+    """`_ssd`'s single-token step for the live rows of layer ``i`` of the
+    run's stacked ``states`` [L, B, H, P, N], in place (`ops/ssd_decode`):
+    (y [B, 1, G, E, P], states)."""
+    b, _t, g, e, pd = xs.shape
+    h = g * e
+    states, y = ssd_decode(
+        states, i, live, dt.reshape(b, h), xs.reshape(b, h, pd), bm[:, 0],
+        cm[:, 0], a.reshape(h), interpret=interpret)
+    return y.reshape(b, 1, g, e, pd), states
+
+
+def _mamba_mix(model: HybridLM, p, c, held, i, hn, mask, live=None):
     """The state-space mixer of layer ``i`` over the normed ``hn``; the
     run's stacked float32 states (``held``) ride through the scan as
     carry (updated where they lie; as the scan's ``xs``/``ys`` every
     layer's state was copied out and back a step: 5.5 ms of a 28 ms step at
     granite-4.0-h-small's widths). ``mask`` [B, T]: the real tokens (a
     prefix of a row, or none of it). What it leaves out enters neither the
-    window nor the state."""
+    window nor the state. A per-row decode step handed ``live`` [B] moves
+    the live rows' states alone (`_ssd_live`); every other shape (prefill,
+    a chunk, `engine.generate`'s step, the oracle the pool's streams are
+    held to) runs `_ssd` over the layer's whole slice. Where ``c`` holds
+    `state_rows` the layer adds the rows whose state it moved and those
+    the slots hold."""
     b, t, _ = hn.shape
     h, pd, n, g = (model.ssm_heads, model.ssm_head_dim, model.ssm_state,
                    model.ssm_groups)
@@ -684,22 +710,33 @@ def _mamba_mix(model: HybridLM, p, c, held, i, hn, mask):
         dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)), 0.0)
     a = -jnp.exp(p["A_log"].astype(jnp.float32)).reshape(g, h // g)
     states = held["state"]
-    state = jax.lax.dynamic_index_in_dim(
-        states, i, 0, keepdims=False).reshape(b, g, h // g, pd, n)
-    y, state = _ssd(xs, dt.reshape(b, t, g, h // g), a, bm, cm, state,
-                    model.ssm_chunk)
-    states = jax.lax.dynamic_update_index_in_dim(
-        states, state.reshape(states.shape[1:]), i, 0)
+    new = {"conv": window}
+    if live is not None and t == 1:
+        y, states = _ssd_live(states, i, live, xs,
+                              dt.reshape(b, t, g, h // g), a, bm, cm,
+                              model.interpret)
+        moved = jnp.sum(live, dtype=jnp.int32)
+    else:
+        state = jax.lax.dynamic_index_in_dim(
+            states, i, 0, keepdims=False).reshape(b, g, h // g, pd, n)
+        y, state = _ssd(xs, dt.reshape(b, t, g, h // g), a, bm, cm, state,
+                        model.ssm_chunk)
+        states = jax.lax.dynamic_update_index_in_dim(
+            states, state.reshape(states.shape[1:]), i, 0)
+        moved = jnp.int32(b)
+    if "state_rows" in c:
+        new["state_rows"] = c["state_rows"] + jnp.stack([moved,
+                                                         jnp.int32(b)])
     y = y + p["D"].astype(jnp.float32).reshape(g, h // g)[..., None] * xs
     y = y.reshape(b, t, di) * jax.nn.silu(z.astype(jnp.float32))
     out = _gated_norm(model, y, p["norm"]) @ p["w_out"]
-    return out, {"state": states}, {"conv": window}
+    return out, {"state": states}, new
 
 
-def _mamba_layer(model: HybridLM, p, c, held, i, x, mask):
+def _mamba_layer(model: HybridLM, p, c, held, i, x, mask, live):
     """Layer ``i`` of a state-space run: the mixer over its own norm."""
     hn = _rms(x, p["ln1"], model.eps, model.dtype)
-    return _mamba_mix(model, p, c, held, i, hn, mask)
+    return _mamba_mix(model, p, c, held, i, hn, mask, live)
 
 
 # -- plain attention ---------------------------------------------------------
@@ -819,14 +856,14 @@ def _attention_layer(model: HybridLM, p, kv, i, x, pos):
     return (*_attention_mix(model, p, kv, i, hn, pos), {})
 
 
-def _parallel_layer(model: HybridLM, p, c, held, i, x, pos, mask):
+def _parallel_layer(model: HybridLM, p, c, held, i, x, pos, mask, live):
     """Layer ``i`` of a run whose layers hold BOTH mixers: each reads the
     one normed input times its `*_in_mult`, and their out-projections,
     times `*_out_mult`, are summed. ``held`` carries the run's K/V and
     its states alike."""
     hn = _rms(x, p["ln1"], model.eps, model.dtype)
     ssm, state, new = _mamba_mix(model, p, c, held, i,
-                                 hn * model.ssm_in_mult, mask)
+                                 hn * model.ssm_in_mult, mask, live)
     att, kv = _attention_mix(model, p, held, i, hn * model.attn_in_mult,
                              pos)
     mix = ssm * model.ssm_out_mult + att * model.attn_out_mult
@@ -944,8 +981,9 @@ def hybrid_apply(model: HybridLM, params, cache, tokens, paged=None):
         valid = cache["valid"]
     pos = p0[:, None] + jnp.arange(t)[None, :]
     # the real tokens: before `valid` in a prefill, a live row's in decode
-    mask = (jnp.broadcast_to(cache["live"][:, None], pos.shape)
-            if "live" in cache else pos < valid)
+    live = cache.get("live") if model.decode_per_row else None
+    mask = (pos < valid if live is None
+            else jnp.broadcast_to(live[:, None], pos.shape))
     a = model.residual_scale
     x = (params["embed"][tokens] * model.scale_emb).astype(model.dtype)
     new_cache = dict(cache)
@@ -968,10 +1006,10 @@ def hybrid_apply(model: HybridLM, params, cache, tokens, paged=None):
                                          valid)
             elif kind == MAMBA:
                 mix, held, new = _mamba_layer(model, p_l, c_l, held, i, h,
-                                              mask)
+                                              mask, live)
             elif kind == PARALLEL:
                 mix, held, new = _parallel_layer(model, p_l, c_l, held, i,
-                                                 h, pos, mask)
+                                                 h, pos, mask, live)
             else:
                 mix, held, new = _attention_layer(model, p_l, held, i, h,
                                                   pos)

@@ -324,6 +324,10 @@ def test_granite_dispatch_updates_states_and_kv_in_place(one_chip):
     assert mem.temp_size_in_bytes < states // 4
     text = compiled.as_text()
     assert not re.findall(r"= f32\[2,48,128,64,128\]\S* copy\(", text)
+    # the mamba2 run's decode step is the live-rows kernel, in place: no
+    # layer's slice of every slot's state [48, 128, 64, 128] is formed
+    assert _live_state_steps(text, "2,48,128,64,128") == 1
+    assert not re.findall(r"= f32\[(?:1,)?48,128,64,128\]", text)
     # the attention layer reads its K/V a tile of the context ladder at a
     # time (ISSUE 37): no [48, 4864, 8, 128] slice or copy of it. Sixteen
     # tiles, not eight: a tile of 608 tokens is 60 MB of K and 60 of V,
@@ -333,6 +337,14 @@ def test_granite_dispatch_updates_states_and_kv_in_place(one_chip):
     assert _kv_reads(text, slots, 8) == [304]
     staged = re.findall(r"= bf16\[1,48,304,8,128\]\{[^}]*\} fusion\(", text)
     assert len(staged) == 2 and all("S(1)" in s for s in staged)
+
+
+def _live_state_steps(text: str, states: str) -> int:
+    """How many `ops/ssd_decode.py` kernels of a compiled program hand
+    back the whole stacked state ``f32[<states>]`` (aliased in to out)."""
+    return len(re.findall(
+        rf"%ssd_decode\S* = \(f32\[{states}\]\S* f32\[[\d,]+\]\S*\) "
+        r"custom-call\(", text))
 
 
 def _kv_reads(text: str, rows: int, kv_heads: int) -> list[int]:
@@ -396,6 +408,10 @@ def test_falcon_h1_dispatch_updates_kv_and_states_in_place(one_chip):
     text = compiled.as_text()
     assert not re.findall(r"= f32\[6,32,32,128,256\]\S* copy\(", text)
     assert not re.findall(r"= bf16\[6,32,4096,4,128\]\S* copy\(", text)
+    # the state-space half is the live-rows kernel, in place: no layer's
+    # slice of every slot's state [32, 32, 128, 256] is updated or read out
+    assert _live_state_steps(text, "6,32,32,128,256") == 1
+    assert not re.findall(r"= f32\[(?:1,)?32,32,128,256\]", text)
     assert srv._dec.decode_context_rungs(max_len, slots)[0] == 512
     assert _kv_reads(text, slots, 4) == [512]
 

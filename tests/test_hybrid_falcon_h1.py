@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 import ladder_cases
+import live_state_cases
 from benchmark import manifest
 from idunno_tpu.engine.generate import decode_model, init_cache
 from idunno_tpu.engine.serve_lm import DecodeServer, _prefill
@@ -348,7 +349,7 @@ def test_a_cache_of_one_rung_takes_the_whole_axis_path(built, monkeypatch):
     logits = {}
     for max_len in (128, 160):
         dec = dataclasses.replace(model, decode=True, decode_per_row=True,
-                                  max_decode_len=max_len)
+                                  max_decode_len=max_len, interpret=True)
         cache = dec.init_cache(2)
         cache["cursors"] = jnp.asarray([3, 90], jnp.int32)
         logits[max_len], _c = decode_apply(dec, params, cache,
@@ -433,3 +434,14 @@ def test_a_layer_of_both_mixers_is_both_kinds():
                         **base)
     with pytest.raises(ValueError, match="unknown mixer"):
         hybrid.HybridLM(mixers=("mamba2+attention",) * 2, **base)
+
+
+# -- the decode step moves the live rows' scan states alone ------------------
+
+@pytest.mark.parametrize("case", live_state_cases.CASES,
+                         ids=lambda c: c.__name__)
+def test_the_pool_moves_live_rows_states(built, case):
+    """`live_state_cases`' cases over Falcon-H1's three layers of both
+    mixers: dead rows in a dispatch, slots reused after they went dead, and
+    the counters' arithmetic."""
+    case(built, ladder_cases.hybrid_pool)

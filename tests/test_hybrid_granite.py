@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 import ladder_cases
+import live_state_cases
 from benchmark import manifest
 from idunno_tpu.engine.generate import decode_model, init_cache
 from idunno_tpu.engine.serve_lm import DecodeServer, _prefill
@@ -346,3 +347,14 @@ def test_a_stack_is_checked_for_the_kinds_it_has():
                           max_decode_len=50, **base)
     assert dec.init_cache(2)["run0"]["cached_k"].shape == (1, 2, 50, 2, 8)
     assert dec.state_bytes(3) == 0
+
+
+# -- the decode step moves the live rows' scan states alone ------------------
+
+@pytest.mark.parametrize("case", live_state_cases.CASES,
+                         ids=lambda c: c.__name__)
+def test_the_pool_moves_live_rows_states(built, case):
+    """`live_state_cases`' cases over granite's four Mamba-2 layers beside
+    two of attention: dead rows in a dispatch, slots reused after they went
+    dead, and the counters' arithmetic."""
+    case(built, ladder_cases.hybrid_pool)
