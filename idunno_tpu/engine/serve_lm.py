@@ -43,7 +43,8 @@ import numpy as np
 from idunno_tpu.engine.generate import decode_model, init_cache
 from idunno_tpu.engine.kv_blocks import (KV_LEAF_KEYS, SLOT_LEAF_KEYS,
                                          concat_kv_prefix)
-from idunno_tpu.models.hybrid import SPARSE, UnsupportedStack
+from idunno_tpu.models.hybrid import (SPARSE, UnsupportedStack,
+                                      reads_every_held_expert)
 from idunno_tpu.models.transformer import (TransformerLM, decode_apply,
                                            scan_compatible,
                                            stack_block_params)
@@ -873,10 +874,11 @@ class DecodeServer:
             self._stats.update(sparse_tokens_attended=0,
                                sparse_tokens_in_context=0)
         # the slot cache's counters (an expert stack's `expert_load`,
-        # `expert_steps`; a state-space stack's `state_rows`: leaves without
-        # a slot axis) as the last dispatch left them: a copy on the device,
-        # because the cache itself is donated to the next dispatch while
-        # another thread may be asking `stats()`
+        # `expert_steps`; a state-space or Gated DeltaNet stack's
+        # `state_rows`: leaves without a slot axis) as the last dispatch
+        # left them: a copy on the device, because the cache itself is
+        # donated to the next dispatch while another thread may be asking
+        # `stats()`
         self._counted = self._recurrent and bool(_counter_keys(self._cache))
         self._counts_copy = (_counters(self._cache) if self._counted
                              else None)
@@ -986,9 +988,9 @@ class DecodeServer:
                     cache, jnp.where(remaining > 0, cursors, 0)
                     if bound_by_live else cursors)
                 # an expert stack routes a dead row nowhere and counts
-                # the live ones, a state-space layer moves the live rows'
-                # states alone (`models/hybrid.py`); no other cache has the
-                # leaf
+                # the live ones, a state-space or Gated DeltaNet layer moves
+                # the live rows' states alone (`models/hybrid.py`); no other
+                # cache has the leaf
                 cache = _set_leaf(cache, "live", remaining > 0)
                 tok = jnp.take_along_axis(tokens, cursors[:, None], axis=1)
                 # decode_apply: the scanned step (one lax.scan over the
@@ -1247,9 +1249,12 @@ class DecodeServer:
         token, a layer); the picks on the busiest held expert of each
         layer, summed, and the mean over a layer's held experts, summed;
         the held experts that took a pick, summed over steps and layers,
-        and how many there were to take one. A state-space stack's: the
-        rows whose scan state a step moved and the rows the slots hold, a
-        Mamba-2 layer a step."""
+        how many there were to take one, and the held experts whose weights
+        the steps' branch read: every held expert where a decode step's
+        rows take the all-experts branch (`hybrid.reads_every_held_expert`,
+        fixed by the slot count), those touched where they are grouped. A
+        stack with Mamba-2 or Gated DeltaNet layers: the rows whose state a
+        step moved and the rows the slots hold, such a layer a step."""
         c = {k: np.concatenate([np.asarray(x, np.int64) for x in v])
              for k, v in self._counts_copy.items()}      # [layers, ...]
         out = {}
@@ -1263,6 +1268,9 @@ class DecodeServer:
                 expert_load_mean=float(load.mean(axis=1).sum()),
                 experts_touched=int(steps[:, 0].sum()),
                 experts_touchable=int(steps[:, 1].sum() * load.shape[1]))
+            out["experts_read"] = out[
+                "experts_touchable" if reads_every_held_expert(
+                    self._dec, self.slots) else "experts_touched"]
         if "state_rows" in c:
             moved, held = c["state_rows"].sum(axis=0)
             out.update(state_rows_moved=int(moved),

@@ -2,7 +2,7 @@
 
 `TransformerLM` is one block repeated, one cache shape a layer. This module
 serves stacks whose layers differ in kind (`mixers`, one name a layer) and
-whose caches differ with them. Five kinds of mixer:
+whose caches differ with them. Six kinds of mixer:
 
   ``minicpm4``        block-sparse softmax attention (InfLLM-v2): grouped
                       query heads without RoPE over `cached_k`/`cached_v`,
@@ -25,13 +25,17 @@ whose caches differ with them. Five kinds of mixer:
                       [heads, P, N] a row (in chunks of `ssm_chunk` tokens
                       in prefill, one update a decode step), a gated
                       RMSNorm, the out-projection; no token axis.
-  ``attention``       plain grouped-query softmax attention over
-                      `cached_k`/`cached_v`: no q/k norm, no gate, no
-                      selection, scale `attn_scale`; no positional
-                      embedding, or (`attn_rope`) rotary positions at
-                      `rope_theta` over the whole head; the keys times
-                      `key_mult`. The per-row decode step reads the LIVE
-                      context: the first rung of `context_rungs` that
+  ``attention``       grouped-query softmax attention over
+                      `cached_k`/`cached_v`, no selection, scale
+                      `attn_scale`; no positional embedding, or
+                      (`attn_rope`) rotary positions at `rope_theta` over
+                      the whole head or its first `rotary_dims` channels;
+                      the keys times `key_mult`; optionally (Qwen3-Next's
+                      gated attention) RMSNorm of each head's q and k
+                      (`attn_qk_norm`) and a sigmoid gate an output
+                      element, the second half of each head's q-projection
+                      (`attn_gate`). The per-row decode step reads the
+                      LIVE context: the first rung of `context_rungs` that
                       holds the deepest cursor it is handed, a tile at a
                       time over the carried K/V (`_attend_live`).
   ``attention+mamba2`` both of the last two in ONE layer, side by side on
@@ -42,12 +46,27 @@ whose caches differ with them. Five kinds of mixer:
                       axis) AND `state` and `conv` (none). A name with a
                       `+` is the kinds it joins: `has("mamba2")` and
                       `has("attention")` hold of such a stack.
+  ``gated-deltanet``  the gated delta rule (Qwen3-Next's linear
+                      attention): in-projections grouped by key head, a
+                      depthwise causal convolution over q, k and v whose
+                      last `ssm_conv - 1` inputs are the row's `conv`
+                      window, l2-normed q and k (a value head reads its key
+                      head), `S_t = exp(g_t) S_{t-1}` then `S_t += k_t
+                      (beta_t (v_t - S_t^T k_t))^T`, `o_t = S_t^T q_t` over
+                      a float32 `state` [value heads, dk, dv] a row (in
+                      its chunked form over chunks of `_DELTA_CHUNK`
+                      tokens in prefill, one update a decode step), RMSNorm of
+                      each head's output times silu(z), the
+                      out-projection; no token axis.
 
 and two kinds of feed-forward, one a stack (`ffn`): ``dense``, a gated SiLU
 MLP (its gate and its output times `mlp_mults`), and ``moe``, routed experts
 of which this chip holds a share (`models.moe.routed_experts`: dropless
 top-k over the whole router, the held experts' terms alone) plus a shared
-expert every token takes.
+expert every token takes, times sigmoid(x wsg) where `shared_gate`
+(Qwen3-Next). A step multiplies every token by every held expert only where
+its tokens are few (`_GROUP_FROM`) and each held expert expects a pick;
+otherwise tokens are grouped by expert.
 
 The first two mixers sit in MiniCPM's pre-norm block (RMSNorm, bias-free
 projections, q/k RMSNorm, sigmoid output gate); every layer is `h += a *
@@ -73,15 +92,16 @@ leading, the slot axis second): `cached_k`/`cached_v` [L, B, T, kvh, d],
 state, the window nor the pooled keys. A stack with `last_logits` computes
 a prefill's logits at the last real position alone ([B, 1, vocab]: a
 2048-token chunk's float32 logits over 261120 rows would be 2.1 GB). A
-per-row (decode) cache of a ``moe`` stack or of one with a ``mamba2`` layer
-also carries `live` [B] (the rows that hold a request: the dispatch sets it;
-a dead row is routed nowhere, and its scan state is neither read nor
-written: `ops/ssd_decode.py`) and the counters: a run of a ``moe`` stack
-`expert_load` [L, held] and `expert_steps` [L, 3] (int32: picks on each
-held expert; held experts touched, steps and tokens, summed over the steps
-that had a live row), a run with a ``mamba2`` layer `state_rows` [L, 2]
-(int32: the rows whose scan state a step moved, and the rows the slots
-hold, summed over the steps). They have no slot axis and are read when
+per-row (decode) cache of a ``moe`` stack or of one with a ``mamba2`` or
+``gated-deltanet`` layer also carries `live` [B] (the rows that hold a
+request: the dispatch sets it; a dead row is routed nowhere, and its state
+is neither read nor written: `ops/ssd_decode.py`, `_delta_live`) and the
+counters: a run of a ``moe`` stack `expert_load` [L, held] and
+`expert_steps` [L, 3] (int32: picks on each held expert; held experts
+touched, steps and tokens, summed over the steps that had a live row), a
+run with a ``mamba2`` or ``gated-deltanet`` layer `state_rows` [L, 2]
+(int32: the rows whose state a step moved, and the rows the slots hold,
+summed over the steps). They have no slot axis and are read when
 `DecodeServer.stats()` is.
 """
 from __future__ import annotations
@@ -100,14 +120,21 @@ from idunno_tpu.ops.ssd_decode import ssd_decode
 SPARSE, LINEAR = "minicpm4", "lightning-attn"
 MAMBA, ATTENTION = "mamba2", "attention"
 PARALLEL = ATTENTION + "+" + MAMBA     # both mixers of one layer, summed
+DELTA = "gated-deltanet"
 DENSE, MOE = "dense", "moe"
 _HI = jax.lax.Precision.HIGHEST
 _NO_LIMIT = np.iinfo(np.int32).max
 _LIN_BLOCK = 256        # tokens a lightning sub-block (intra-chunk product)
+_L2_EPS = 1e-6          # gated-deltanet: the l2 norm of its queries and keys
+_DELTA_CHUNK = 64       # and the tokens a chunk of its prefill's chunked form
 _KEY_TILE = 1024        # keys a tile of the chunked sparse prefill
 _QUERY_TILE = 512       # queries a tile of the plain attention's prefill
 # below this many tokens an expert layer multiplies every token by every
-# held expert; from it on, tokens are grouped by expert (`routed_experts`)
+# held expert, where each held expert expects a pick (tokens x experts a
+# token >= the router's width: granite-4.0-h-small's 48 decode rows x 10 >=
+# 72); from it on, or where most held experts would be read for no pick
+# (Qwen3-Next's 16 rows x 10 of 512 touch some 69 of 256 held), tokens are
+# grouped by expert (`routed_experts`, `reads_every_held_expert`)
 _GROUP_FROM = 256
 # a trip of the decode step's context ladder (`_attend_live`) stages one
 # tile of K and one of V, every slot's, before the products read them (the
@@ -121,7 +148,7 @@ _STAGED_TILE_BYTES = 32 << 20
 # the cache leaves of a run that ride through its scan as carry, whole and
 # depth-stacked, and are written where they lie
 _CARRIED = {ATTENTION: ("cached_k", "cached_v"), MAMBA: ("state",),
-            PARALLEL: ("cached_k", "cached_v", "state")}
+            PARALLEL: ("cached_k", "cached_v", "state"), DELTA: ("state",)}
 # the parameters of a run that its scan does not slice a layer: the routed
 # experts, which the grouped product reads from the whole stack
 _WHOLE = ("w1", "w2")
@@ -138,7 +165,8 @@ class HybridLM:
     vocab: int
     dim: int
     mlp_dim: int
-    mixers: tuple            # SPARSE / LINEAR / MAMBA / ATTENTION / PARALLEL
+    mixers: tuple            # SPARSE / LINEAR / MAMBA / ATTENTION /
+    #                          PARALLEL / DELTA
     layer_ids: tuple         # each layer's PUBLISHED index (its slopes)
     published_depth: int
     num_heads: int           # sparse and attention layers: query heads
@@ -161,6 +189,14 @@ class HybridLM:
     attn_scale: float | None = None    # attention: None = 1 / sqrt(d)
     attn_rope: bool = False  # attention: rotary positions at `rope_theta`
     key_mult: float = 1.0    # attention: the keys' multiplier
+    # attention (Qwen3-Next's gated attention): RMSNorm of each head's
+    # queries and keys (`qn`, `kn`), rotary positions over the first
+    # `rotary_dims` channels of a head alone (0: all of them), and a
+    # sigmoid gate an output element, the second half of each head's
+    # query projection (`wq` [dim, heads, 2 head_dim])
+    attn_qk_norm: bool = False
+    rotary_dims: int = 0
+    attn_gate: bool = False
     # a PARALLEL layer's branch multipliers, on the normed input and on the
     # out-projection's output of each mixer
     attn_in_mult: float = 1.0
@@ -178,12 +214,21 @@ class HybridLM:
     #                          and the gated norm is over a group's channels
     ssm_conv: int = 4        # taps of the depthwise convolution
     ssm_chunk: int = 256     # tokens a chunk of the prefill scan
+    # gated-deltanet: key heads and value heads (value head j reads key
+    # head j // (value heads / key heads)) and their widths; its
+    # convolution has `ssm_conv` taps
+    delta_key_heads: int = 0
+    delta_value_heads: int = 0
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
     ffn: str = DENSE         # DENSE (width `mlp_dim`) or MOE
     experts: int = 0         # moe: the router's width (all the experts)
     experts_per_token: int = 0
     experts_held: tuple = (0, 0)       # (first, count) held by this chip
     shared_dim: int = 0      # the shared expert's width; `mlp_dim` is a
     #                          routed expert's
+    shared_gate: bool = False          # the shared expert times
+    #                                    sigmoid(x wsg)
     last_logits: bool = False          # a prefill's logits: the last real
     #                                    position's alone, [B, 1, vocab]
     dtype: Any = jnp.float32
@@ -203,7 +248,8 @@ class HybridLM:
     def __post_init__(self):
         if len(self.mixers) != len(self.layer_ids):
             raise ValueError("one published index a layer")
-        bad = set(self.mixers) - {SPARSE, LINEAR, MAMBA, ATTENTION, PARALLEL}
+        bad = set(self.mixers) - {SPARSE, LINEAR, MAMBA, ATTENTION, PARALLEL,
+                                  DELTA}
         if bad:
             raise ValueError(f"unknown mixer kinds {sorted(bad)}")
         if self.ffn not in (DENSE, MOE):
@@ -218,6 +264,15 @@ class HybridLM:
         if self.has(MAMBA) and self.ssm_heads % self.ssm_groups:
             raise ValueError("state-space heads must be a multiple of "
                              "their groups")
+        if self.has(DELTA) and not (
+                0 < self.delta_key_heads
+                and self.delta_value_heads % self.delta_key_heads == 0
+                and self.delta_value_heads and self.delta_key_dim
+                and self.delta_value_dim):
+            raise ValueError("gated-deltanet value heads must be a "
+                             "multiple of its key heads, and widths given")
+        if self.rotary_dims % 2 or self.rotary_dims > self.head_dim:
+            raise ValueError("rotary_dims is an even part of head_dim")
         if len(self.ssm_mults) not in (0, 5) or len(self.mlp_mults) != 2:
             raise ValueError("ssm_mults is one multiplier a segment (gate, "
                              "x, B, C, step sizes), mlp_mults two")
@@ -294,10 +349,17 @@ class HybridLM:
         return (self.ssm_heads * self.ssm_head_dim
                 + 2 * self.ssm_groups * self.ssm_state)
 
+    @property
+    def delta_conv_width(self) -> int:
+        """Channels of a gated-deltanet layer's convolution: q, k and v."""
+        return (2 * self.delta_key_heads * self.delta_key_dim
+                + self.delta_value_heads * self.delta_value_dim)
+
     def state_bytes(self, batch: int) -> int:
         """Bytes of recurrent state ``batch`` rows hold: every leaf without
         a token axis (the float32 states, the convolution windows)."""
         per_row = 0
+        item = jnp.dtype(self.dtype).itemsize
         for kind in self.mixers:
             if kind == LINEAR:
                 per_row += 4 * self.lightning_heads * self.lightning_head_dim ** 2
@@ -305,7 +367,12 @@ class HybridLM:
                 per_row += (4 * self.ssm_heads * self.ssm_head_dim
                             * self.ssm_state
                             + (self.ssm_conv - 1) * self.ssm_conv_width
-                            * jnp.dtype(self.dtype).itemsize)
+                            * item)
+            elif kind == DELTA:
+                per_row += (4 * self.delta_value_heads * self.delta_key_dim
+                            * self.delta_value_dim
+                            + (self.ssm_conv - 1) * self.delta_conv_width
+                            * item)
         return batch * per_row
 
     def init_cache(self, batch: int) -> dict:
@@ -322,7 +389,7 @@ class HybridLM:
         cache: dict = {}
         if self.decode_per_row:
             cache["cursors"] = jnp.zeros((batch,), jnp.int32)
-            if counted or self.has(MAMBA):
+            if counted or self.has(MAMBA) or self.has(DELTA):
                 cache["live"] = jnp.ones((batch,), jnp.bool_)
         else:
             cache["cursor"] = jnp.zeros((), jnp.int32)
@@ -350,6 +417,15 @@ class HybridLM:
                 d = self.lightning_head_dim
                 run = {"state": jnp.zeros(
                     (n, batch, self.lightning_heads, d, d), jnp.float32)}
+            if kind == DELTA:
+                run = {"state": jnp.zeros(
+                    (n, batch, self.delta_value_heads, self.delta_key_dim,
+                     self.delta_value_dim), jnp.float32),
+                       "conv": jnp.zeros(
+                    (n, batch, self.ssm_conv - 1, self.delta_conv_width),
+                    self.dtype)}
+                if self.decode_per_row:
+                    run["state_rows"] = jnp.zeros((n, 2), jnp.int32)
             if counted:
                 run["expert_load"] = jnp.zeros((n, self.experts_held[1]),
                                                jnp.int32)
@@ -739,6 +815,178 @@ def _mamba_layer(model: HybridLM, p, c, held, i, x, mask, live):
     return _mamba_mix(model, p, c, held, i, hn, mask, live)
 
 
+# -- the delta rule (gated-deltanet) ------------------------------------------
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
+                             + _L2_EPS)
+
+
+def _delta_step(state, q, k, v, g, beta):
+    """One token of the gated delta rule: ``state`` [..., H, dk, dv], ``q``
+    and ``k`` [..., H, dk], ``v`` [..., H, dv], ``g`` and ``beta`` [..., H],
+    float32. The state decays, is read along k, takes back the correction
+    `beta (v - S^T k)` along k, and is read out along q: (state, o [...,
+    H, dv])."""
+    state = state * jnp.exp(g)[..., None, None]
+    err = v - jnp.sum(state * k[..., :, None], axis=-2)
+    state = state + k[..., :, None] * (beta[..., None] * err)[..., None, :]
+    return state, jnp.sum(state * q[..., :, None], axis=-2)
+
+
+def _delta_chunks(q, k, v, g, beta, state, size: int):
+    """The delta rule over [B, T, H, ...] in its chunked form, chunks of
+    ``size`` tokens (the tail padded with g = 0 and beta = 0: it neither
+    decays the state nor adds to it). Inside a chunk, with `c` the
+    cumulative g and `D_ij = exp(c_i - c_j)` (i >= j): `T = (I + tril(diag(
+    beta) K K^T * D, -1))^-1`, by forward substitution over the rows of
+    every chunk at once; `W = T (beta K exp(c))`, `U = T (beta V)`. Then one
+    scan over the chunks: `V' = U - W S`, `O = exp(c) Q S + tril(Q K^T *
+    D) V'`, `S = exp(c_last) S + (exp(c_last - c) K)^T V'`. Float32, the
+    products at full precision: (o [B, T, H, dv], state)."""
+    b, t, h, _dk = q.shape
+    q, k, v, g, beta = (_cut(x, size) for x in (q, k, v, g, beta))
+    cum = jnp.cumsum(g, axis=2)                                # [N, B, C, H]
+    ch = jnp.moveaxis(cum, -1, 2)                              # [N, B, H, C]
+    tri = jnp.arange(size)[:, None] >= jnp.arange(size)[None, :]
+    below = jnp.arange(size)[:, None] > jnp.arange(size)[None, :]
+    decay = jnp.where(tri, jnp.exp(jnp.where(
+        tri, ch[..., :, None] - ch[..., None, :], 0.0)), 0.0)  # [N,B,H,C,C]
+    kk = jnp.einsum("nbihd,nbjhd->nbhij", k, k, precision=_HI)
+    a = jnp.where(below, -jnp.moveaxis(beta, -1, 2)[..., None] * kk * decay,
+                  0.0)
+
+    def substitute(i, a):
+        # row i of the inverse from the rows above it: `a` is zero on and
+        # above the diagonal, so the sum reaches the finished rows alone
+        r = jax.lax.dynamic_index_in_dim(a, i, axis=-2, keepdims=False)
+        r = r + jnp.sum(r[..., :, None] * a, axis=-2)
+        return jax.lax.dynamic_update_index_in_dim(a, r, i, axis=-2)
+
+    inv = jax.lax.fori_loop(1, size, substitute, a) + jnp.eye(size)
+    w = jnp.einsum("nbhij,nbjhd->nbihd", inv,
+                   k * (beta * jnp.exp(cum))[..., None], precision=_HI)
+    u = jnp.einsum("nbhij,nbjhe->nbihe", inv, v * beta[..., None],
+                   precision=_HI)
+    qk = jnp.einsum("nbihd,nbjhd->nbhij", q, k, precision=_HI) * decay
+    last = cum[:, :, -1]                                       # [N, B, H]
+    kd = k * jnp.exp(last[:, :, None] - cum)[..., None]
+
+    def chunk(state, xs):
+        w, u, qk, qc, cum, kd, last = xs
+        fresh = u - jnp.einsum("bihd,bhde->bihe", w, state, precision=_HI)
+        o = (jnp.einsum("bihd,bhde->bihe", qc * jnp.exp(cum)[..., None],
+                        state, precision=_HI)
+             + jnp.einsum("bhij,bjhe->bihe", qk, fresh, precision=_HI))
+        state = (state * jnp.exp(last)[..., None, None]
+                 + jnp.einsum("bjhd,bjhe->bhde", kd, fresh, precision=_HI))
+        return state, o
+
+    state, o = jax.lax.scan(chunk, state, (w, u, qk, q, cum, kd, last))
+    o = jnp.moveaxis(o, 0, 1).reshape((b, -1) + o.shape[3:])
+    return o[:, :t], state
+
+
+def _delta_live(states, i, live, q, k, v, g, beta):
+    """`_delta_step` for the live rows of layer ``i`` of the run's stacked
+    ``states`` [L, B, H, dk, dv], one row at a time where it lies: a dead
+    row's state is neither read nor written. ``q`` .. ``beta`` [B, H, ...]
+    as `_delta_step`'s. (states, o [B, H, dv]), o zero on a dead row."""
+    ids = jnp.argsort(~live, stable=True)
+    shape = (1, 1) + states.shape[2:]
+
+    def row(j, carry):
+        states, out = carry
+        s = ids[j]
+        at = (i, s, 0, 0, 0)
+        state, o = _delta_step(
+            jax.lax.dynamic_slice(states, at, shape)[0, 0],
+            *(jax.lax.dynamic_index_in_dim(x, s, 0, keepdims=False)
+              for x in (q, k, v, g, beta)))
+        return (jax.lax.dynamic_update_slice(states, state[None, None], at),
+                jax.lax.dynamic_update_index_in_dim(out, o, s, 0))
+
+    return jax.lax.fori_loop(
+        0, jnp.sum(live, dtype=jnp.int32), row,
+        (states, jnp.zeros(v.shape, jnp.float32)))
+
+
+def _delta_mix(model: HybridLM, p, c, held, i, hn, mask, live=None):
+    """The gated-deltanet mixer of layer ``i`` over the normed ``hn``
+    (Qwen3-Next's `linear_attention`). `[q k v z] = hn W_qkvz` and `[b a] =
+    hn W_ba`, each grouped by key head as published (a key head's `[q dk |
+    k dk | v r dv | z r dv]` and `[b r | a r]`, r value heads a key head);
+    a causal depthwise convolution over `[q | k | v]` (`ssm_conv` taps, no
+    bias) whose last inputs are the row's `conv` window, SiLU; `q / sqrt(dk)`
+    and `k` l2-normed, a value head reading its key head; `beta =
+    sigmoid(b)`, `g = -exp(A_log) softplus(a + dt_bias)`; the delta rule
+    over a float32 `state` [H, dk, dv] a row (`_delta_chunks` over a
+    prefill, `_delta_step` a decode step); RMSNorm of each head's output
+    times `norm`, times silu(z); the out-projection. The run's stacked
+    states ride through its scan as carry, as `_mamba_mix`'s. What ``mask``
+    leaves out enters neither the window nor the state; a per-row decode
+    step handed ``live`` moves the live rows' states alone (`_delta_live`),
+    and where ``c`` holds `state_rows` the layer adds the rows whose state
+    it moved and those the slots hold, as `_mamba_mix` does."""
+    b, t, _ = hn.shape
+    kh, vh = model.delta_key_heads, model.delta_value_heads
+    dk, dv, taps = model.delta_key_dim, model.delta_value_dim, model.ssm_conv
+    r = vh // kh
+    f32 = jnp.float32
+    q, k, v, z = jnp.split(_proj(hn, p["w_qkvz"]),
+                           (dk, 2 * dk, 2 * dk + r * dv), axis=-1)
+    ba = _proj(hn, p["w_ba"]).astype(f32)                     # [B,T,KH,2r]
+    xc = jnp.concatenate([x.reshape(b, t, -1) for x in (q, k, v)], axis=-1)
+    seq = jnp.concatenate([c["conv"], xc.astype(c["conv"].dtype)], axis=1)
+    conv = jax.nn.silu(sum(
+        seq[:, j:j + t].astype(f32) * p["conv_w"][j].astype(f32)
+        for j in range(taps)))
+    # the window the next token sees: the last inputs before the padding
+    kept = jnp.sum(mask, axis=1)
+    window = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(
+        s, n, taps - 1, axis=0))(seq, kept)
+    q, k, v = jnp.split(conv, (kh * dk, 2 * kh * dk), axis=-1)
+    q = jnp.repeat(_l2norm(q.reshape(b, t, kh, dk)) * dk ** -0.5, r, axis=2)
+    k = jnp.repeat(_l2norm(k.reshape(b, t, kh, dk)), r, axis=2)
+    v = v.reshape(b, t, vh, dv)
+    keep = mask[..., None]
+    beta = jnp.where(keep, jax.nn.sigmoid(ba[..., :r].reshape(b, t, vh)),
+                     0.0)
+    g = jnp.where(keep, -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+        ba[..., r:].reshape(b, t, vh) + p["dt_bias"].astype(f32)), 0.0)
+    states = held["state"]
+    new = {"conv": window}
+    if live is not None and t == 1:
+        states, o = _delta_live(states, i, live, q[:, 0], k[:, 0], v[:, 0],
+                                g[:, 0], beta[:, 0])
+        o = o[:, None]
+        moved = jnp.sum(live, dtype=jnp.int32)
+    else:
+        state = jax.lax.dynamic_index_in_dim(states, i, 0, keepdims=False)
+        if t == 1:
+            state, o = _delta_step(state, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                   beta[:, 0])
+            o = o[:, None]
+        else:
+            o, state = _delta_chunks(q, k, v, g, beta, state,
+                                     _DELTA_CHUNK)
+        states = jax.lax.dynamic_update_index_in_dim(states, state, i, 0)
+        moved = jnp.int32(b)
+    if "state_rows" in c:
+        new["state_rows"] = c["state_rows"] + jnp.stack([moved,
+                                                         jnp.int32(b)])
+    o = _rms(o, p["norm"], model.eps, f32) * jax.nn.silu(
+        z.reshape(b, t, vh, dv).astype(f32))
+    out = jnp.einsum("bthk,hkd->btd", o.astype(model.dtype), p["w_out"])
+    return out, {"state": states}, new
+
+
+def _delta_layer(model: HybridLM, p, c, held, i, x, mask, live):
+    """Layer ``i`` of a gated-deltanet run: the mixer over its own norm."""
+    hn = _rms(x, p["ln1"], model.eps, model.dtype)
+    return _delta_mix(model, p, c, held, i, hn, mask, live)
+
+
 # -- plain attention ---------------------------------------------------------
 
 def _attend(q, kc, vc, pos, scale):
@@ -800,7 +1048,9 @@ def _attend_live(q, kc, vc, i, pos, scale, rungs):
 
 
 def _attention_mix(model: HybridLM, p, kv, i, hn, pos):
-    """Plain attention of layer ``i`` over the normed ``hn``; the run's
+    """Attention of layer ``i`` over the normed ``hn`` (with the stack's
+    q/k norm, partial rotary positions and output gate where it sets
+    them); the run's
     stacked K/V ``kv`` ride through the scan as carry: the new tokens' rows
     are written where they lie, and the layer's slice is read from there
     (as `scanned_apply` does). The per-row (decode) shape reads the live
@@ -814,16 +1064,30 @@ def _attention_mix(model: HybridLM, p, kv, i, hn, pos):
     def turned(x):
         if not model.attn_rope:
             return x
+        rd = model.rotary_dims
+        if rd and rd < d:
+            # rotary positions over the head's first `rotary_dims` channels
+            return jnp.concatenate([rope(
+                x[..., :rd], base=model.rope_theta,
+                positions=pos.astype(jnp.float32)), x[..., rd:]], axis=-1)
         return rope(x, base=model.rope_theta,
                     positions=pos.astype(jnp.float32))
 
-    q = turned(_proj(hn, p["wq"])).reshape(
+    def normed(x, scale):
+        if not model.attn_qk_norm:
+            return x
+        return _rms(x, p[scale], model.eps, model.dtype)
+
+    q = _proj(hn, p["wq"])
+    if model.attn_gate:
+        q, gate = q[..., :d], q[..., d:]
+    q = turned(normed(q, "qn")).reshape(
         b, t, kvh, model.num_heads // kvh, d)
     p0 = pos[:, 0]
     k = _proj(hn, p["wk"])
     if model.key_mult != 1.0:
         k = k * model.key_mult
-    k = turned(k)
+    k = turned(normed(k, "kn"))
     kv = {"cached_k": _write_kv(kv["cached_k"], k, p0,
                                 model.decode_per_row, layer=i),
           "cached_v": _write_kv(kv["cached_v"], _proj(hn, p["wv"]), p0,
@@ -845,8 +1109,10 @@ def _attention_mix(model: HybridLM, p, kv, i, hn, pos):
             o = jnp.moveaxis(o, 0, 1).reshape((b, -1) + q.shape[2:])[:, :t]
         else:
             o = _attend(q, kc, vc, pos, scale)
-    out = jnp.einsum("bthk,hkd->btd",
-                     o.reshape(b, t, model.num_heads, d), p["wo"])
+    o = o.reshape(b, t, model.num_heads, d)
+    if model.attn_gate:
+        o = (o * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(o.dtype)
+    out = jnp.einsum("bthk,hkd->btd", o, p["wo"])
     return out, kv
 
 
@@ -872,6 +1138,16 @@ def _parallel_layer(model: HybridLM, p, c, held, i, x, pos, mask, live):
 
 # -- the stack -------------------------------------------------------------
 
+def reads_every_held_expert(model: HybridLM, tokens: int) -> bool:
+    """Whether an expert layer over ``tokens`` tokens takes
+    `routed_experts`' all-experts branch, which reads every held expert's
+    weights: few tokens (`_GROUP_FROM`), and enough of them that each held
+    expert expects a pick. Otherwise tokens are grouped by expert and the
+    touched experts alone are read."""
+    return (tokens < _GROUP_FROM
+            and tokens * model.experts_per_token >= model.experts)
+
+
 def _ffn(model: HybridLM, p, experts, i, c, x, mask):
     """The layer's feed-forward over the normed ``x``, and what it counted
     (the leaves of ``c`` it updates: an expert stack's, in a decode
@@ -889,10 +1165,14 @@ def _ffn(model: HybridLM, p, experts, i, c, x, mask):
     y, load = routed_experts(
         hn.reshape(b * t, d), p["router"], experts["w1"], experts["w2"],
         top_k=model.experts_per_token, experts_held=model.experts_held,
-        mask=mask.reshape(-1), dense=b * t < _GROUP_FROM, layer=i)
+        mask=mask.reshape(-1), dense=reads_every_held_expert(model, b * t),
+        layer=i)
     up = hn @ p["ws1"]
     shared = (jax.nn.silu(up[..., :model.shared_dim])
               * up[..., model.shared_dim:]) @ p["ws2"]
+    if model.shared_gate:
+        shared = (shared * jax.nn.sigmoid(
+            (hn @ p["wsg"]).astype(jnp.float32))).astype(shared.dtype)
     counted = {}
     if "expert_load" in c:
         any_live = jnp.any(mask).astype(jnp.int32)
@@ -1010,6 +1290,9 @@ def hybrid_apply(model: HybridLM, params, cache, tokens, paged=None):
             elif kind == PARALLEL:
                 mix, held, new = _parallel_layer(model, p_l, c_l, held, i,
                                                  h, pos, mask, live)
+            elif kind == DELTA:
+                mix, held, new = _delta_layer(model, p_l, c_l, held, i, h,
+                                              mask, live)
             else:
                 mix, held, new = _attention_layer(model, p_l, held, i, h,
                                                   pos)

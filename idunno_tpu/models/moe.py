@@ -160,8 +160,11 @@ def routed_experts(x, router, w1, w2, *, top_k: int,
     that fell here, whatever their spread over the experts. ``dense``
     multiplies every token by every held expert and weights by the gates
     (zero where not picked): `held / picks-here` times the operations, one
-    batched product; for a handful of rows, where the experts' bytes bound
-    the layer either way.
+    batched product, and every held expert's weights read: for a handful
+    of rows whose picks touch nearly every held expert anyway (granite's 33
+    live rows x 10 of 72). Where they touch few of them (Qwen3-Next's 16 x
+    10 of 512: some 69 of 256 held) it reads 4-7 times the bytes the picks
+    need (`models/hybrid.py:reads_every_held_expert` chooses).
 
     Returns (y [T, d] in ``x``'s type, load [held] int32: the picks each
     held expert took). This is what an expert-parallel mesh calls a chip

@@ -1,12 +1,13 @@
-"""What a pool over a stack with Mamba-2 layers is held to now that its
-decode step moves the live rows' scan states alone (`ops/ssd_decode.py`,
-`models/hybrid.py:_ssd_live`): the slot cache carries `live`, a dispatch
-whose rows end in its middle and a slot reused after it went dead serve
-`engine.generate`'s streams token for token, and `state_rows_moved` /
-`state_rows_held` count a slot a layer a step, as the step moved them (the
-cache's `state_rows` counters). One body a case,
-``case(built, pool)`` as in `ladder_cases`; run by `test_hybrid_falcon_h1.py`
-and `test_hybrid_granite.py` over their own toy stacks."""
+"""What a pool over a stack with Mamba-2 or Gated DeltaNet layers is held to
+now that its decode step moves the live rows' states alone
+(`ops/ssd_decode.py`, `models/hybrid.py:_ssd_live` and `_delta_live`): the
+slot cache carries `live`, a dispatch whose rows end in its middle and a
+slot reused after it went dead serve `engine.generate`'s streams token for
+token, and `state_rows_moved` / `state_rows_held` count a slot a layer a
+step, as the step moved them (the cache's `state_rows` counters). One body
+a case, ``case(built, pool)`` as in `ladder_cases`; run by
+`test_hybrid_falcon_h1.py`, `test_hybrid_granite.py` and
+`test_hybrid_qwen3_next.py` over their own toy stacks."""
 import dataclasses
 
 import jax.numpy as jnp
@@ -17,8 +18,10 @@ from ladder_cases import _prompt, expected
 
 
 def state_layers(model) -> int:
-    """The stack's Mamba-2 layers, alone or beside attention."""
-    return sum(hybrid.MAMBA in m.split("+") for m in model.mixers)
+    """The stack's Mamba-2 layers, alone or beside attention, and its Gated
+    DeltaNet layers."""
+    return sum(hybrid.MAMBA in m.split("+") or m == hybrid.DELTA
+               for m in model.mixers)
 
 
 def rows_that_end_mid_dispatch_and_reused_slots_serve_generates_streams(
@@ -77,16 +80,21 @@ def the_state_counters_count_a_live_row_a_layer_a_step(built, pool):
 
 def the_counters_read_what_the_step_moved(built, pool):
     """One per-row decode step over three slots, the second dead: each
-    Mamba-2 layer counts 2 rows moved of 3 held where the step takes the
-    live-rows kernel, and 3 of 3 where it is handed no `live` leaf and
-    `_ssd` moves every slot's state."""
+    Mamba-2 (Gated DeltaNet) layer counts 2 rows moved of 3 held where the
+    step takes the live rows alone, and 3 of 3 where it is handed no
+    `live` leaf and moves every slot's state. The dead row's states are
+    the cache's as they came, the live rows' are not."""
     model, params = built
     dec = dataclasses.replace(model, decode=True, decode_per_row=True,
                               max_decode_len=64, interpret=True)
     cache = dec.init_cache(3)
+    runs = [k for k in cache if k.startswith("run")]
+    for r in runs:
+        if "state_rows" in cache[r]:
+            cache[r]["state"] = jnp.full(cache[r]["state"].shape, 0.5,
+                                         jnp.float32)
     cache["live"] = jnp.asarray([True, False, True])
     tok = jnp.asarray([[5], [9], [7]])
-    runs = [k for k in cache if k.startswith("run")]
     for drop, want in ((False, [2, 3]), (True, [3, 3])):
         c = {k: v for k, v in cache.items() if not (drop and k == "live")}
         _logits, new = dec.decode_apply(params, c, tok)
@@ -94,6 +102,11 @@ def the_counters_read_what_the_step_moved(built, pool):
                 if "state_rows" in new[r]]
         assert sum(len(r) for r in rows) == state_layers(model)
         assert all(r.tolist() == [want] * len(r) for r in rows)
+        for r in runs:
+            if "state_rows" in new[r]:
+                same = np.all(np.asarray(new[r]["state"]) == 0.5,
+                              axis=(0, 2, 3, 4))
+                assert same.tolist() == [False, not drop, False]
 
 
 CASES = [rows_that_end_mid_dispatch_and_reused_slots_serve_generates_streams,

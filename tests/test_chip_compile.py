@@ -347,12 +347,13 @@ def _live_state_steps(text: str, states: str) -> int:
         r"custom-call\(", text))
 
 
-def _kv_reads(text: str, rows: int, kv_heads: int) -> list[int]:
+def _kv_reads(text: str, rows: int, kv_heads: int,
+              head_dim: int = 128) -> list[int]:
     """The token-axis lengths of every slice or copy of ``rows`` slots' K
-    or V (`[rows, n, kv_heads, 128]`, a leading 1 aside) in a compiled
+    or V (`[rows, n, kv_heads, head_dim]`, a leading 1 aside) in a compiled
     program: what a layer stages of its cache to attend over it."""
     return sorted({int(n) for n in re.findall(
-        rf"= bf16\[(?:1,)?{rows},(\d+),{kv_heads},128\]\S* "
+        rf"= bf16\[(?:1,)?{rows},(\d+),{kv_heads},{head_dim}\]\S* "
         r"(?:copy|dynamic-slice)\(", text)})
 
 
@@ -444,6 +445,83 @@ def test_falcon_h1_prefill_at_published_widths(one_chip, bucket, tokens):
     assert mem.temp_size_in_bytes < 3e9
     assert mem.alias_size_in_bytes >= _nbytes(cache)
     assert mem.output_size_in_bytes < _nbytes(cache) + 2 * 261120 * 4
+
+
+def _qwen3_next():
+    from benchmark import manifest
+
+    man = manifest.Manifest()
+    cfg = man.config(man.cell("qwen3-next-80b-a3b-instruct.long-context"))
+    return cfg, man.family(cfg)
+
+
+def test_qwen3_next_dispatch_updates_states_and_kv_in_place(one_chip):
+    """`DecodeServer._build_decode`'s `jit_run` over Qwen3-Next's four layers
+    (three Gated DeltaNet, one gated attention) at the published widths, 16
+    slots x 33792 (a toy pool whose `_dec` is widened after the build): the
+    new cache IS the donated one (K/V 1.1 GB, float32 delta-rule states 0.1
+    GB), no whole stacked state is copied and no layer's slice of every
+    slot's state is formed (the live rows' are stepped one at a time where
+    they lie), the experts are grouped (16 rows x 10 picks of 512), and the
+    attention layer reads its K/V a tile of the context ladder at a time,
+    each tile staged in the chip's fast memory."""
+    from benchmark import system
+
+    cfg, fam = _qwen3_next()
+    toy = system.model_config(cfg, True, fam)
+    model, params, _kw = fam.program.build(
+        toy, fam.weights.make_weights(toy, 1))
+    slots, max_len = 16, 33792
+    srv = DecodeServer(model, params, slots=slots, prompt_len=512,
+                       max_len=max_len, decode_steps=4,
+                       prompt_buckets=(512,), kv_block_size=64,
+                       kv_cache_blocks=4)
+    srv._dec = dataclasses.replace(
+        fam.program.model_of(cfg), decode=True, decode_per_row=True,
+        max_decode_len=max_len)
+    wide = _described(fam.program.program_params(jax.eval_shape(
+        lambda: fam.weights.make_weights(cfg, 1))), one_chip)
+    cache = _described(jax.eval_shape(
+        lambda: srv._dec.init_cache(slots)), one_chip)
+    compiled = _compile_run(srv, wide, cache, one_chip)
+    mem = compiled.memory_analysis()
+    assert 1.2e9 < _nbytes(cache) <= mem.alias_size_in_bytes
+    # 0.21 GB of temporaries: a tile of K and of V staged, the expert rows
+    assert mem.temp_size_in_bytes < 0.3e9
+    text = compiled.as_text()
+    assert not re.findall(r"= f32\[3,16,32,128,128\]\S* copy\(", text)
+    assert not re.findall(r"= f32\[(?:1,)?16,32,128,128\]", text)
+    assert "ragged-dot" in text
+    assert srv._dec.decode_context_rungs(max_len, slots)[0] == 1056
+    assert _kv_reads(text, slots, 2, head_dim=256) == [1056]
+    staged = re.findall(r"= bf16\[1,16,1056,2,256\]\{[^}]*\} fusion\(",
+                        text)
+    assert len(staged) == 2 and all("S(1)" in s for s in staged)
+
+
+def test_qwen3_next_prefill_chunk_at_published_widths(one_chip):
+    """A 4096-token chunk of a 32768-token prefill of Qwen3-Next's four
+    layers at the published widths (the cell's longest bucket and its
+    `prefill_chunk`): the chunked delta rule over [32, 128, 128] states,
+    attention a tile of 512 queries over the bucket's keys (float32 scores
+    [16, 512, 32768]: 1.1 GB), the experts grouped, and the head over ONE
+    position: temporaries under 1.5 GB beside 8 GB of weights, the row's
+    cache updated where it lies."""
+    cfg, fam = _qwen3_next()
+    dec = dataclasses.replace(fam.program.model_of(cfg), decode=True,
+                              max_decode_len=32768)
+    wide = _described(fam.program.program_params(jax.eval_shape(
+        lambda: fam.weights.make_weights(cfg, 1))), one_chip)
+    cache = _described(jax.eval_shape(lambda: dec.init_cache(1)), one_chip)
+    compiled = jax.jit(dec.decode_apply, donate_argnums=(1,)).lower(
+        wide, cache, jax.ShapeDtypeStruct((1, 4096), jnp.int32,
+                                          sharding=one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert 7.9e9 < mem.argument_size_in_bytes < 8.1e9
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert mem.alias_size_in_bytes >= _nbytes(cache)
+    assert mem.output_size_in_bytes < _nbytes(cache) + 2 * 151936 * 4
+    assert "ragged-dot" in compiled.as_text()
 
 
 # -- the decode programs over the slot cache, at the benchmark's widths ------
