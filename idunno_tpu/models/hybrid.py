@@ -127,6 +127,7 @@ _NO_LIMIT = np.iinfo(np.int32).max
 _LIN_BLOCK = 256        # tokens a lightning sub-block (intra-chunk product)
 _L2_EPS = 1e-6          # gated-deltanet: the l2 norm of its queries and keys
 _DELTA_CHUNK = 64       # and the tokens a chunk of its prefill's chunked form
+_TRI_BLOCK = 16         # rows a diagonal block of that chunk's inverse
 _KEY_TILE = 1024        # keys a tile of the chunked sparse prefill
 _QUERY_TILE = 512       # queries a tile of the plain attention's prefill
 # below this many tokens an expert layer multiplies every token by every
@@ -834,13 +835,54 @@ def _delta_step(state, q, k, v, g, beta):
     return state, jnp.sum(state * q[..., :, None], axis=-2)
 
 
+def _unit_lower_inverse(a):
+    """`(I - a)^-1` of every strictly lower ``a`` [..., C, C] (zero on and
+    above the diagonal), by blocks of `_TRI_BLOCK` rows (the whole C where
+    the block does not divide it): the diagonal blocks by forward
+    substitution, a row a trip over all of them at once, then block row i
+    from those above it, `T_ij = T_ii sum_{j<=m<i} a_im T_mj`. The matrices
+    ride on the LAST axis, so a row of 16 is a row of whole vectors, not 16
+    of a vector's 128 lanes, and the products between blocks are float32
+    sums of elementwise products (as batched 16 x 16 matrix products the
+    inverse took a TPU v5e 25 times as long)."""
+    size = a.shape[-1]
+    bs = min(_TRI_BLOCK, size)
+    bs = bs if size % bs == 0 else size
+    m = int(np.prod(a.shape[:-2]))
+    x = jnp.moveaxis(a.reshape((m, size, size)), 0, -1)       # [C, C, M]
+    diag = jnp.concatenate([x[o:o + bs, o:o + bs]
+                            for o in range(0, size, bs)], -1)  # [b, b, nb M]
+
+    def substitute(i, d):
+        # row i of the block's inverse from the rows above it: `d` is zero
+        # on and above the diagonal, so the sum reaches the finished rows
+        r = jax.lax.dynamic_index_in_dim(d, i, 0, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(
+            d, r + jnp.sum(r[:, None] * d, axis=0), i, 0)
+
+    def times(p, q):                       # [i, k, M] by [k, j, M]
+        return jnp.sum(p[:, :, None] * q[None], axis=1)
+
+    diag = (jax.lax.fori_loop(1, bs, substitute, diag)
+            + jnp.eye(bs, dtype=a.dtype)[..., None])
+    inv = diag[..., :m]
+    for o in range(bs, size, bs):
+        tii = diag[..., o // bs * m:(o // bs + 1) * m]
+        row = times(tii, times(x[o:o + bs, :o], inv))
+        inv = jnp.concatenate([
+            jnp.pad(inv, ((0, 0), (0, bs), (0, 0))),
+            jnp.concatenate([row, tii], 1)], 0)
+    return jnp.moveaxis(inv, -1, 0).reshape(a.shape)
+
+
 def _delta_chunks(q, k, v, g, beta, state, size: int):
     """The delta rule over [B, T, H, ...] in its chunked form, chunks of
     ``size`` tokens (the tail padded with g = 0 and beta = 0: it neither
     decays the state nor adds to it). Inside a chunk, with `c` the
     cumulative g and `D_ij = exp(c_i - c_j)` (i >= j): `T = (I + tril(diag(
-    beta) K K^T * D, -1))^-1`, by forward substitution over the rows of
-    every chunk at once; `W = T (beta K exp(c))`, `U = T (beta V)`. Then one
+    beta) K K^T * D, -1))^-1`, by blocks (`_unit_lower_inverse`: forward
+    substitution inside the 16 x 16 diagonal blocks of every chunk at once,
+    products between them); `W = T (beta K exp(c))`, `U = T (beta V)`. Then one
     scan over the chunks: `V' = U - W S`, `O = exp(c) Q S + tril(Q K^T *
     D) V'`, `S = exp(c_last) S + (exp(c_last - c) K)^T V'`. Float32, the
     products at full precision: (o [B, T, H, dv], state)."""
@@ -853,17 +895,8 @@ def _delta_chunks(q, k, v, g, beta, state, size: int):
     decay = jnp.where(tri, jnp.exp(jnp.where(
         tri, ch[..., :, None] - ch[..., None, :], 0.0)), 0.0)  # [N,B,H,C,C]
     kk = jnp.einsum("nbihd,nbjhd->nbhij", k, k, precision=_HI)
-    a = jnp.where(below, -jnp.moveaxis(beta, -1, 2)[..., None] * kk * decay,
-                  0.0)
-
-    def substitute(i, a):
-        # row i of the inverse from the rows above it: `a` is zero on and
-        # above the diagonal, so the sum reaches the finished rows alone
-        r = jax.lax.dynamic_index_in_dim(a, i, axis=-2, keepdims=False)
-        r = r + jnp.sum(r[..., :, None] * a, axis=-2)
-        return jax.lax.dynamic_update_index_in_dim(a, r, i, axis=-2)
-
-    inv = jax.lax.fori_loop(1, size, substitute, a) + jnp.eye(size)
+    inv = _unit_lower_inverse(jnp.where(
+        below, -jnp.moveaxis(beta, -1, 2)[..., None] * kk * decay, 0.0))
     w = jnp.einsum("nbhij,nbjhd->nbihd", inv,
                    k * (beta * jnp.exp(cum))[..., None], precision=_HI)
     u = jnp.einsum("nbhij,nbjhe->nbihe", inv, v * beta[..., None],

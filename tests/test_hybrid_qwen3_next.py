@@ -188,9 +188,20 @@ def test_prefill_then_decode_through_the_slot_cache(fam, weights, built):
 
 # -- the delta rule: chunked form against the token-at-a-time reference ------
 
-@pytest.mark.parametrize("t, real, size", [(200, 163, 64), (130, 97, 16),
-                                           (50, 50, 64)])
-def test_the_chunked_delta_rule_is_the_recurrence(fam, t, real, size):
+def _hard_keys(rng, shape):
+    """Keys that share one direction (cosines near 1 between any two): with
+    no decay and beta near 1 the chunk's triangle is close to all ones
+    below the diagonal, its inverse's rows alternate in sign, and each row
+    of the substitution cancels nearly all of what it sums."""
+    x = rng.standard_normal(shape[-1]) + 0.05 * rng.standard_normal(shape)
+    return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+
+@pytest.mark.parametrize("t, real, size, hard", [
+    (200, 163, 64, False), (130, 97, 16, False), (50, 50, 64, False),
+    (200, 163, 64, True)],
+    ids=["200-163-64", "130-97-16", "50-50-64", "200-163-64-hard"])
+def test_the_chunked_delta_rule_is_the_recurrence(fam, t, real, size, hard):
     """`hybrid._delta_chunks` over ``t`` tokens in chunks of ``size`` (the
     last ``t - real`` padding: g = 0 and beta = 0, as the mixer sets them),
     from a state that is not zero: the reference's token at a time
@@ -198,7 +209,7 @@ def test_the_chunked_delta_rule_is_the_recurrence(fam, t, real, size):
     the last real token. The reference starts from zeros, so a leading
     token writes the start (beta 1, a unit key: S0 = k0 v0^T), and dk
     trailing ones read the state out (q a unit vector, beta 0, g 0: row i
-    of S)."""
+    of S). ``hard``: no decay, beta near 1, keys of one direction."""
     rng = np.random.default_rng(t + real)
     h, dk, dv = 3, 16, 8
     f32 = jnp.float32
@@ -213,6 +224,10 @@ def test_the_chunked_delta_rule_is_the_recurrence(fam, t, real, size):
     g = jnp.where(real_, -jnp.asarray(rng.uniform(0, 3, (t, h)), f32), 0.0)
     beta = jnp.where(real_, jax.nn.sigmoid(jnp.asarray(
         rng.standard_normal((t, h)), f32)), 0.0)
+    if hard:
+        k = jnp.asarray(_hard_keys(rng, (t, h, dk)), f32)
+        g = jnp.zeros((t, h), f32)
+        beta = jnp.where(real_, jnp.full((t, h), 0.999, f32), 0.0)
     k0, v0 = unit((1, h, dk)), jnp.asarray(rng.standard_normal((1, h, dv)),
                                            f32)
     start = k0[0][..., :, None] * v0[0][..., None, :]
@@ -231,6 +246,80 @@ def test_the_chunked_delta_rule_is_the_recurrence(fam, t, real, size):
     assert np.abs(np.asarray(state[0] - jnp.moveaxis(ref[real + 1:], 0, 1))
                   ).max() < 1e-5
     assert np.abs(np.asarray(ref)).max() > 0.1
+
+
+def _triangle(size, hard):
+    """`-tril(diag(beta) K K^T * D, -1)` of 6 chunks x 4 heads of ``size``
+    tokens, keys of 128, float64, as `_delta_chunks` forms it: decaying
+    keys of random directions, or the hard case (`_hard_keys`, g = 0, beta
+    0.999)."""
+    n, h, dk = 6, 4, 128
+    rng = np.random.default_rng(size)
+    if hard:
+        k = _hard_keys(rng, (n, h, size, dk))
+        beta, g = np.full((n, h, size), 0.999), np.zeros((n, h, size))
+    else:
+        k = rng.standard_normal((n, h, size, dk))
+        k /= np.linalg.norm(k, axis=-1, keepdims=True)
+        beta = 1 / (1 + np.exp(-rng.standard_normal((n, h, size))))
+        g = -rng.uniform(0, 3, (n, h, size))
+    c = np.cumsum(g, axis=-1)
+    below = np.tril(np.ones((size, size), bool), -1)
+    d = np.exp(np.where(below, c[..., :, None] - c[..., None, :], 0.0))
+    kk = np.einsum("nhid,nhjd->nhij", k, k)
+    return np.where(below, -beta[..., None] * kk * d, 0.0)
+
+
+@pytest.mark.parametrize("hard", [False, True], ids=["decaying", "hard"])
+@pytest.mark.parametrize("size", [16, 32, 64])
+def test_the_blocked_inverse_is_float64s(size, hard):
+    """`hybrid._unit_lower_inverse` (diagonal blocks by substitution, the
+    rest by products; one block at 16) in float32 against float64
+    `numpy.linalg.inv` of `I - a`, to 1e-5 on an inverse of entries up to
+    1; the hard case's entries reach 1 with alternating signs."""
+    a = _triangle(size, hard)
+    ref = np.linalg.inv(np.eye(size) - a)
+    got = np.asarray(jax.jit(hybrid._unit_lower_inverse)(
+        jnp.asarray(a, jnp.float32)))
+    assert got.shape == a.shape
+    assert np.abs(got - ref).max() < 1e-5
+    assert np.abs(np.tril(ref, -1)).max() > (0.5 if hard else 0.05)
+
+
+def _loop_carries(jaxpr):
+    """The shapes each loop of ``jaxpr`` (its inner jaxprs too) carries from
+    one trip to the next: a `while`'s, and a `scan`'s, which is what a
+    `fori_loop` with static bounds becomes."""
+    for eqn in jaxpr.eqns:
+        p = eqn.params
+        if eqn.primitive.name == "while":
+            yield [tuple(v.aval.shape) for v in
+                   p["body_jaxpr"].jaxpr.invars[p["body_nconsts"]:]]
+        elif eqn.primitive.name == "scan":
+            at = p["num_consts"]
+            yield [tuple(v.aval.shape) for v in
+                   p["jaxpr"].jaxpr.invars[at:at + p["num_carry"]]]
+        for sub in jax.core.jaxprs_in_params(p):
+            yield from _loop_carries(sub)
+
+
+def test_no_loop_carries_a_whole_chunk_inverse():
+    """`hybrid._delta_chunks` at the Qwen3-Next cell's geometry (a 4096-token
+    chunk, 32 value heads, keys and values of 128, chunks of 64) loops
+    over no [.., 64, 64] array: a loop that carried every chunk's inverse
+    would pass over all of it (33.5 MB) once a row. The substitution's
+    loop carries the diagonal 16 x 16 blocks, 8.4 MB; the scan over the
+    chunks carries the state."""
+    f32 = jnp.float32
+    t, h, d = 4096, 32, 128
+    spec = [jax.ShapeDtypeStruct(s, f32) for s in (
+        (1, t, h, d), (1, t, h, d), (1, t, h, d), (1, t, h), (1, t, h),
+        (1, h, d, d))]
+    closed = jax.make_jaxpr(hybrid._delta_chunks, static_argnums=(6,))(
+        *spec, hybrid._DELTA_CHUNK)
+    carries = [[s for s in c if s] for c in _loop_carries(closed.jaxpr)]
+    assert sorted(carries) == [[(1, h, d, d)], [(16, 16, 64 * h * 4)]]
+    assert not [s for c in carries for s in c if s[-2:] == (64, 64)]
 
 
 # -- gated attention ---------------------------------------------------------
